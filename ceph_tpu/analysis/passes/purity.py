@@ -21,7 +21,7 @@ SCOPE_DIRS = ("ops/", "codec/", "parallel/")
 
 _TIME_FNS = {"time", "monotonic", "perf_counter", "perf_counter_ns",
              "time_ns", "process_time"}
-_JIT_WRAPPERS = {"jit", "shard_map", "_shard_map", "pmap"}
+_JIT_WRAPPERS = {"jit", "shard_map", "pmap"}
 
 
 def _in_scope(rel: str) -> bool:

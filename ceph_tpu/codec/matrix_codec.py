@@ -64,15 +64,14 @@ _PLATFORM: str | None = None
 
 def _on_tpu() -> bool:
     """True when the default jax backend is a TPU (cached; backend init is
-    expensive and the answer cannot change within a process)."""
+    expensive and the answer cannot change within a process).  A failed
+    backend query raises: answering "cpu" would silently drop the Pallas
+    kernel on a chip whose runtime did not come up."""
     global _PLATFORM
     if _PLATFORM is None:
-        try:
-            import jax
+        import jax
 
-            _PLATFORM = jax.devices()[0].platform
-        except Exception:
-            _PLATFORM = "cpu"
+        _PLATFORM = jax.devices()[0].platform
     return _PLATFORM == "tpu"
 
 

@@ -228,6 +228,11 @@ class OSDMonitor:
                     if m.osds[osd].weight == 0:
                         m.set_osd_weight(osd, WEIGHT_ONE)
             self.failure_reports.pop(osd, None)
+            # a boot ends the down episode: a bounce back down before the
+            # next down->out sweep must start a fresh clock, not inherit
+            # this episode's (which outed a fast flapper early)
+            self._down_since.pop(osd, None)
+            self._hold_logged.discard(osd)
             return f"osd.{osd} boot"
 
         self._queue(mutate, None)

@@ -25,6 +25,7 @@ owns the verdict.
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 
@@ -33,6 +34,50 @@ from ceph_tpu.common.lockdep import make_lock
 
 class DeviceTimeout(RuntimeError):
     """A guarded device call exceeded its per-launch deadline."""
+
+
+class _LaunchWorkers:
+    """A few long-lived daemon threads that run every guarded device call.
+
+    Device work stays on the same OS threads for the life of the process
+    instead of a fresh thread per launch; with a thread per launch, one
+    v5e chip run of the 11-OSD smoke cluster died with SIGSEGV during
+    degraded reads (PR 21; cause not isolated).  A worker stuck past its
+    deadline is abandoned and a replacement is started, so a wedged
+    device never starves the pool."""
+
+    WORKERS = 4
+
+    def __init__(self) -> None:
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        for _ in range(self.WORKERS):
+            self.spawn()
+
+    def spawn(self) -> None:
+        threading.Thread(
+            target=self._run, daemon=True, name="ec-launch-worker"
+        ).start()
+
+    def _run(self) -> None:
+        _ON_WORKER.active = True
+        while True:
+            self._jobs.get()()
+
+    def submit(self, job) -> None:
+        self._jobs.put(job)
+
+
+_ON_WORKER = threading.local()
+_WORKERS: _LaunchWorkers | None = None
+_WORKERS_LOCK = make_lock("launch_workers")
+
+
+def _launch_workers() -> _LaunchWorkers:
+    global _WORKERS
+    with _WORKERS_LOCK:
+        if _WORKERS is None:
+            _WORKERS = _LaunchWorkers()
+        return _WORKERS
 
 
 def _default_probe() -> None:
@@ -88,17 +133,21 @@ class DeviceGuard:
     # -- deadline-bounded execution ------------------------------------------
 
     def call(self, fn, what: str = "launch", timeout_ms: int | None = None):
-        """Run `fn` under the per-launch deadline (or an explicit
-        `timeout_ms` override).  Deadline <= 0 runs inline (watchdog
-        off).  On timeout the worker thread is abandoned (daemon; its
-        eventual result is discarded) and DeviceTimeout raises — the
-        caller falls back to the host oracle, which never touches the
-        wedged runtime."""
+        """Run `fn` on a launch worker under the per-launch deadline (or
+        an explicit `timeout_ms` override).  Deadline <= 0 runs inline
+        (watchdog off), as does a call made from a worker, which is
+        already under its caller's deadline.  On timeout the worker is
+        abandoned (daemon; its eventual result is discarded), a
+        replacement starts, and DeviceTimeout raises — the caller falls
+        back to the host oracle, which never touches the wedged
+        runtime."""
         t_ms = self.timeout_ms if timeout_ms is None else timeout_ms
-        if t_ms <= 0:
+        if t_ms <= 0 or getattr(_ON_WORKER, "active", False):
             return fn()
         box: list = []
         err: list[BaseException] = []
+        done = threading.Event()
+        abandoned = threading.Event()
         # carry contextvars (the tracing span scope) onto the worker so a
         # guarded dispatch records its codec spans in the caller's trace
         import contextvars
@@ -106,15 +155,20 @@ class DeviceGuard:
         ctx = contextvars.copy_context()
 
         def run() -> None:
+            if abandoned.is_set():
+                return  # timed out while queued: the caller fell back
             try:
                 box.append(ctx.run(fn))
             except BaseException as e:  # re-raised on the calling thread
                 err.append(e)
+            finally:
+                done.set()
 
-        th = threading.Thread(target=run, daemon=True, name="ec-launch-watchdog")
-        th.start()
-        th.join(t_ms / 1000.0)
-        if th.is_alive():
+        workers = _launch_workers()
+        workers.submit(run)
+        if not done.wait(t_ms / 1000.0):
+            abandoned.set()
+            workers.spawn()
             # annotate the launch's flight record (ISSUE 8): the deadline
             # verdict belongs to THIS launch's timeline, not just the
             # process-wide degraded gauge

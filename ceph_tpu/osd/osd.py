@@ -556,6 +556,7 @@ class OSD(Dispatcher):
         # heartbeat state: peer -> last reply rx time
         self._hb_last_rx: dict[int, float] = {}
         self._hb_first_tx: dict[int, float] = {}
+        self._hb_resumed = 0.0  # when our own last loop stall ended
         self._reported_failed: set[int] = set()
         self._last_failure_report: dict[int, float] = {}
         # laggy-OSD detection (ISSUE 17): per-peer RTT EWMA fed by ping
@@ -940,8 +941,10 @@ class OSD(Dispatcher):
                     pg.on_new_interval(epoch, acting)
                 elif key in self.pgs:
                     # no longer in the acting set: drop the in-memory PG
-                    # (data stays on disk, as the reference keeps strays)
-                    del self.pgs[key]
+                    # (data stays on disk, as the reference keeps strays);
+                    # its in-flight scrub ends aborted, as on any new
+                    # interval, so the scrub's caller is not left waiting
+                    self.pgs.pop(key).scrubber.reset()
 
     def _get_pg(self, pgid) -> PG | None:
         pg = self.pgs.get((pgid.pool, pgid.ps))
@@ -1422,7 +1425,15 @@ class OSD(Dispatcher):
     async def _heartbeat_loop(self) -> None:
         interval = self.conf.get("osd_heartbeat_interval")
         while self._running:
+            slept = time.monotonic()
             await asyncio.sleep(interval)
+            if time.monotonic() - slept > 2 * interval:
+                # our own event loop stalled (every daemon of an
+                # in-process cluster shares it; a device compile can hold
+                # it for seconds): the peers' silence over that window is
+                # ours, so their grace restarts now, as an unhealthy OSD
+                # sends no failure reports (OSD::heartbeat_check)
+                self._hb_resumed = time.monotonic()
             if not self.up:
                 # Mon may have missed our boot (election in progress) or the
                 # subscription connection reset: renew both (OSD::tick).
@@ -1493,7 +1504,7 @@ class OSD(Dispatcher):
             first = self._hb_first_tx.get(peer)
             if first is None:
                 continue
-            last = self._hb_last_rx.get(peer, first)
+            last = max(self._hb_last_rx.get(peer, first), self._hb_resumed)
             failed_for = now - last
             if failed_for > grace and now - first > grace:
                 if peer not in self._reported_failed:

@@ -43,23 +43,6 @@ from ceph_tpu.ops.xor_mm import xor_matmul
 from .mesh import LANE_AXIS, POD_AXIS, STRIPE_AXIS
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map across jax versions: top-level with `check_vma` on
-    new jax, `jax.experimental.shard_map` with the old `check_rep`
-    spelling on 0.4.x (which has no `jax.shard_map` at all)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def _stripe_axes(mesh: Mesh):
     """Mesh axes the stripe dim shards over: pods join the stripe axis so
     bulk bytes never cross the DCN boundary."""
@@ -173,7 +156,7 @@ def _plan_encode_executable(mesh: Mesh, plan: CodingPlan):
     def build():
         # check_vma=False: the body is a pallas_call, which can't declare
         # its varying-mesh-axes; operands/results are explicitly sharded.
-        local = _shard_map(
+        local = jax.shard_map(
             plan, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
         )
         return jax.jit(local)
@@ -252,7 +235,7 @@ def _plan_scrub_executable(mesh: Mesh, plan: CodingPlan, k: int):
         return count, mismatch
 
     def build():
-        local_sm = _shard_map(
+        local_sm = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=spec,
@@ -290,7 +273,7 @@ def _packed_shard_executable(mesh: Mesh, packed: PackedPlan, donate: bool):
 
     def build():
         if donate:
-            local = _shard_map(
+            local = jax.shard_map(
                 # `out` is dead — it exists only to be donated; XLA
                 # aliases each device's result tile into it
                 lambda out, data: _packed_code_impl(
@@ -300,7 +283,7 @@ def _packed_shard_executable(mesh: Mesh, packed: PackedPlan, donate: bool):
                 check_vma=False,
             )
             return jax.jit(local, donate_argnums=(0,))
-        local = _shard_map(
+        local = jax.shard_map(
             lambda data: _packed_code_impl(data, packed.sched, packed.k, packed.m),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
         )

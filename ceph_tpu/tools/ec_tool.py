@@ -173,4 +173,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -1,21 +1,20 @@
 """perf_compare — round-over-round performance trajectory gating
 (ISSUE 14 layer 4).
 
-The bench driver commits one ``BENCH_r<NN>.json`` per round, but until
-now nothing ever COMPARED rounds: a TPU round that hit 23.4 GB/s and a
-follow-up that silently fell to 2 GB/s looked equally "green".  This
-tool is the comparator:
+A bench round is one ``BENCH_r<NN>.json`` record (``{n, rc, parsed}``)
+in a rounds directory (the repo root by default).  The old rounds were
+deleted in PR 21, so the root holds none until a new record lands; the
+tests keep a synthetic corpus.  A TPU round that hit 23.4 GB/s and a
+follow-up that silently fell to 2 GB/s must not look equally "green".
+This tool is the comparator:
 
 - ``load_rounds()`` parses the committed corpus (tolerating the legacy
   single-metric shape of early rounds and the rich multi-metric shape
   bench.py emits now) into flat per-round metric slices;
 - ``compare()`` diffs a current round against the trailing rounds'
   same-platform best (throughput metrics are judged tpu-vs-tpu /
-  cpu-vs-cpu — a CPU fallback round is a fallback, not a regression of
-  the TPU story) and emits a machine-readable ``regressions`` slice
-  that ``bench.py`` and ``tools/chaos.py`` fold into their tracked
-  JSON, so the next TPU round is automatically judged against
-  23.4 GB/s instead of silently resetting the story;
+  cpu-vs-cpu) and emits a machine-readable ``regressions`` slice that
+  ``tools/chaos.py`` folds into its JSON;
 - ``--check`` validates the committed corpus (schema, parseability,
   finite numbers) with NO device and NO jax import — the tier-1 CI
   gate against malformed bench JSON or silent schema drift.

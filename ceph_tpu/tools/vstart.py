@@ -367,4 +367,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

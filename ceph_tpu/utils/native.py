@@ -1,7 +1,7 @@
 """ctypes loader for the native runtime library (native/).
 
-Builds libceph_tpu_native.so on first use if the toolchain is available and
-the artifact is missing/stale; callers degrade gracefully to pure-Python
+Runs `make` on first use (it rebuilds libceph_tpu_native.so when a source
+changed) if the toolchain is available; callers degrade gracefully to pure-Python
 fallbacks when neither a binary nor a compiler exists.
 """
 
@@ -42,17 +42,11 @@ def load() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        sources_newer = False
-        if _LIB_PATH.exists():
-            lib_mtime = _LIB_PATH.stat().st_mtime
-            sources_newer = any(
-                src.stat().st_mtime > lib_mtime
-                for src in _NATIVE_DIR.glob("*.cc")
-            )
-        if (not _LIB_PATH.exists() or sources_newer) and not _build():
-            if not _LIB_PATH.exists():
-                _load_failed = True
-                return None
+        # make decides what is stale, so the library always matches the
+        # committed sources
+        if not _build() and not _LIB_PATH.exists():
+            _load_failed = True
+            return None
         try:
             lib = ctypes.CDLL(str(_LIB_PATH))
             lib.ceph_tpu_crc32c.restype = ctypes.c_uint32

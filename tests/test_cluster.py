@@ -221,6 +221,24 @@ class TestErasureCodedCluster:
 
         asyncio.run(run())
 
+    def test_shared_loop_stall_marks_no_osd_down(self):
+        """Every daemon of an in-process cluster shares one event loop;
+        a stall of it (a device compile) silences all peers at once.
+        The OSDs must blame the stall, not each other: no markdown."""
+        import time
+
+        async def run():
+            monmap, mons, osds = await start_cluster(1, 3)
+            await asyncio.sleep(0.5)  # heartbeats established
+            epoch = mons[0].osdmon.osdmap.epoch
+            time.sleep(2.0)  # blocks the loop: > 3x the 0.6 s grace
+            await asyncio.sleep(1.5)  # let every OSD tick past the stall
+            assert all(mons[0].osdmon.osdmap.is_up(o.whoami) for o in osds)
+            assert mons[0].osdmon.osdmap.epoch == epoch
+            await stop_cluster(mons, osds)
+
+        asyncio.run(run())
+
 
 class TestAdminSocketIntrospection:
     def test_daemon_dumps_perf_config_and_traces(self, tmp_path):

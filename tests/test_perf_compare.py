@@ -1,13 +1,15 @@
 """Round-over-round trajectory gating (ISSUE 14, tools/perf_compare.py):
-the committed BENCH_r*.json corpus stays schema-valid in tier-1 (pure
-parsing, no device), and the comparator judges a round against the
-trailing same-platform best — the 23.4 GB/s story cannot silently
-reset."""
+a bench-round corpus stays schema-valid (pure parsing, no device), and
+the comparator judges a round against the trailing same-platform best —
+a recorded TPU number cannot silently reset.  The corpus here is a small
+synthetic one written per test, shaped like the driver's round records."""
 
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 from ceph_tpu.tools.perf_compare import (
     check_corpus,
@@ -20,42 +22,55 @@ from ceph_tpu.tools.perf_compare import (
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# round -> (platform, encode GB/s): CPU rounds around one TPU round
+_CORPUS = {1: ("cpu", 0.069), 2: ("cpu", 1.8), 3: ("tpu", 23.374),
+           4: ("cpu", 0.048), 5: ("cpu", 0.036)}
 
-class TestCommittedCorpus:
-    """The tier-1 CI gate: a malformed bench JSON or a silent schema
-    drift in the committed rounds fails the suite."""
+
+@pytest.fixture
+def corpus(tmp_path):
+    for n, (platform, gbps) in _CORPUS.items():
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps({
+            "n": n, "rc": 0,
+            "parsed": {"metric": "rs_8_3_encode_GBps_per_chip",
+                       "value": gbps, "unit": "GB/s",
+                       "platform": platform},
+        }))
+    return str(tmp_path)
+
+
+class TestCorpus:
+    """The schema gate: a malformed bench JSON or a silent schema drift
+    in a round corpus is reported."""
 
     def test_default_rounds_dir_is_the_repo_root(self):
         assert default_rounds_dir() == REPO
 
-    def test_check_passes_over_committed_rounds(self):
-        problems = check_corpus(REPO)
+    def test_check_passes_over_rounds(self, corpus):
+        problems = check_corpus(corpus)
         assert problems == [], problems
 
-    def test_committed_rounds_load_with_known_trajectory(self):
-        rounds = load_rounds(REPO)
-        assert [r["round"] for r in rounds] == sorted(
-            r["round"] for r in rounds
-        )
-        assert len(rounds) >= 5
-        # the round-3 TPU measurement is the story perf_compare exists
-        # to defend: it must parse out of the committed corpus
+    def test_rounds_load_with_known_trajectory(self, corpus):
+        rounds = load_rounds(corpus)
+        assert [r["round"] for r in rounds] == sorted(_CORPUS)
+        # the TPU measurement is the story perf_compare exists to
+        # defend: it must parse out of the corpus
         by_round = {r["round"]: r for r in rounds}
         assert by_round[3]["platform"] == "tpu"
         assert by_round[3]["metrics"][
             "rs_8_3_encode_GBps_per_chip"] > 20.0
 
-    def test_cli_check_exits_zero(self):
+    def test_cli_check_exits_zero(self, corpus):
         proc = subprocess.run(
             [sys.executable, "-m", "ceph_tpu.tools.perf_compare",
-             "--check", "--rounds-dir", REPO],
+             "--check", "--rounds-dir", corpus],
             capture_output=True, cwd=REPO,
             env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         assert proc.returncode == 0, proc.stdout.decode()
         payload = json.loads(proc.stdout.decode())
         assert payload["ok"] is True
-        assert payload["checked"] >= 5
+        assert payload["checked"] == len(_CORPUS)
         assert payload["trajectory"]
 
     def test_check_fails_on_malformed_round(self, tmp_path):
@@ -239,13 +254,13 @@ class TestCompare:
         assert "rs_8_3_encode_GBps_per_chip" not in out["baselines"]
         assert out["flagged"] == []
 
-    def test_compare_round_against_committed_corpus(self):
-        """The bench.py fold path over the real committed files: a
-        hypothetical collapsed TPU round flags against round 3."""
+    def test_compare_round_against_corpus(self, corpus):
+        """The one-call fold over a round corpus on disk: a collapsed
+        TPU round flags against round 3."""
         out = compare_round(
             {"metric": "rs_8_3_encode_GBps_per_chip", "value": 1.0,
              "platform": "tpu"},
-            REPO,
+            corpus,
         )
         assert out["rounds_compared"]
         assert any(

@@ -225,6 +225,21 @@ class TestMonFlapDampening:
         osdmon._tick_down_out()
         assert not osdmon.osdmap.osds[1].in_
 
+    def test_boot_restarts_the_down_clock(self):
+        """A bounce (down, boot, down) between two sweeps must not
+        inherit the first episode's down-clock."""
+        from ceph_tpu.msg.messages import MOSDBoot
+
+        mon, osdmon = _mon_with_osds(conf=self._conf())
+        _mark_down(osdmon, 1)
+        osdmon._down_since[1] = time.monotonic() - 3.0  # past the 2 s grace
+        osdmon.prepare_boot(MOSDBoot(osd=1, addr="a1b", epoch=0))
+        assert 1 not in osdmon._down_since
+        _mark_down(osdmon, 1)
+        osdmon._tick_down_out()
+        assert osdmon.osdmap.osds[1].in_, "fresh down episode outed at once"
+        assert osdmon.auto_outs_total == 0
+
     def test_churn_cap_bounds_auto_outs_per_tick(self):
         mon, osdmon = _mon_with_osds(
             n=6, conf=self._conf(mon_osd_flap_max_auto_out_per_tick=2)

@@ -7,6 +7,7 @@ via the host oracle, the backend marks DEGRADED (gauge + health check),
 and a probe self-heals it back to device dispatch."""
 
 import itertools
+import threading
 import time
 
 import numpy as np
@@ -214,6 +215,48 @@ class TestDeviceGuardUnit:
     def test_call_inline_when_disabled(self):
         g = DeviceGuard(timeout_ms=0, probe_interval_ms=0)
         assert g.call(lambda: 42) == 42
+
+    def test_nested_call_runs_on_the_callers_worker(self):
+        g = DeviceGuard(timeout_ms=2000, probe_interval_ms=0)
+        outer = []
+
+        def inner():
+            return threading.current_thread()
+
+        def body():
+            outer.append(threading.current_thread())
+            return g.call(inner)
+
+        assert g.call(body) is outer[0]
+        assert outer[0] is not threading.current_thread()
+
+    def test_workers_keep_serving_after_timeouts(self):
+        g = DeviceGuard(timeout_ms=50, probe_interval_ms=0)
+        release = threading.Event()
+        try:
+            for _ in range(6):  # more stuck calls than the pool has workers
+                with pytest.raises(DeviceTimeout):
+                    g.call(release.wait)
+            assert g.call(lambda: 7, timeout_ms=2000) == 7
+        finally:
+            release.set()
+
+    def test_call_abandoned_while_queued_never_runs(self):
+        from ceph_tpu.ops.guard import _launch_workers
+
+        g = DeviceGuard(timeout_ms=1, probe_interval_ms=0)
+        release = threading.Event()
+        ran = []
+        try:
+            for _ in range(64):  # every idle worker blocks
+                _launch_workers().submit(release.wait)
+            with pytest.raises(DeviceTimeout):
+                g.call(lambda: ran.append(1))
+        finally:
+            release.set()
+        assert g.call(lambda: 7, timeout_ms=2000) == 7
+        time.sleep(0.1)
+        assert ran == [], "a call abandoned before it started still ran"
 
     def test_call_reraises_worker_exception(self):
         g = DeviceGuard(timeout_ms=1000, probe_interval_ms=0)

@@ -143,6 +143,36 @@ class TestScrubRobustness:
 
         asyncio.run(run())
 
+    def test_scrub_aborts_when_primary_drops_the_pg(self):
+        """A primary that leaves the acting set drops the PG; the scrub
+        it was running ends aborted instead of never reporting."""
+
+        async def run():
+            objs = {"a": b"A" * 8192}
+            monmap, mons, osds, client, ioctx = await make_ec_cluster(objs)
+            osd, pg = find_primary_pg(osds)
+            pg.scrubber.gather_timeout = 60.0  # only the drop may end it
+            victim = next(o for o in osds if o.whoami != osd.whoami
+                          and o.whoami in pg.acting())
+            await victim.stop()  # the gather can never complete
+            done = asyncio.get_event_loop().create_future()
+            assert pg.scrub(deep=True, on_done=lambda r: done.set_result(r))
+            real = osd.osdmap.pg_to_up_acting_osds
+
+            def without_me(pool, ps):
+                up, upp, acting, actp = real(pool, ps)
+                acting = [a for a in acting if a != osd.whoami]
+                return up, upp, acting, actp
+
+            osd.osdmap.pg_to_up_acting_osds = without_me
+            osd._advance_pgs()
+            res = await asyncio.wait_for(done, 5.0)
+            assert res.aborted and not res.clean
+            await client.shutdown()
+            await stop_cluster(mons, osds)
+
+        asyncio.run(run())
+
     def test_replicated_repair_pulls_good_copy(self):
         """A corrupt PRIMARY copy in a size-3 replicated pool is repaired
         from a replica, not re-pushed (majority picks the good copy)."""
