@@ -727,36 +727,6 @@ def measure(clog) -> dict:
         pad_waste=waste_result, csum=csum_result, offload=offload_result,
         stages=stages,
     )
-    if os.environ.get("BENCH_TRACE"):
-        # One traced encode OUTSIDE the measured loop (BENCH_TRACE=1):
-        # per-stage spans (h2d / kernel_launch / kernel_wait+d2h from
-        # codec/tracing.py) so a regression in the headline number is
-        # attributable to a stage, not just observed end to end.
-        from ceph_tpu.common import tracer as tracer_mod
-        from ceph_tpu.common.tracer import Tracer
-
-        clog("BENCH_TRACE: sampling one traced encode")
-        tr = Tracer("bench", enabled=True)
-        root = tr.start_span("bench:encode")
-        root.keyval("batch", 2)
-        with tracer_mod.span_scope(root):
-            traced = ec.encode_array(
-                rng.integers(0, 256, (2, k, chunk), dtype=np.uint8)
-            )
-            with root.child("kernel_wait+d2h"):
-                np.asarray(traced)
-        root.finish()
-        result["trace"] = [
-            {
-                "name": s["name"],
-                "parent_id": s["parent_id"],
-                "span_id": s["span_id"],
-                "ms": None
-                if s["end"] is None
-                else round((s["end"] - s["start"]) * 1e3, 3),
-            }
-            for s in tr.export()
-        ]
     # Flight-recorder summary (ISSUE 8): launch count, mean queue-wait,
     # occupancy over the run.  Bench encodes run OUTSIDE the
     # aggregators, so these are span-less dispatch-shape witnesses

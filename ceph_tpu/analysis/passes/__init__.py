@@ -11,6 +11,7 @@ from .ledger import LedgerDisciplinePass
 from .locks import LockDisciplinePass
 from .options_coherence import OptionsCoherencePass
 from .purity import JitPurityPass
+from .section_await import SectionAwaitPass
 
 ALL_PASSES = [
     DonationLifetimePass(),
@@ -19,6 +20,7 @@ ALL_PASSES = [
     LockDisciplinePass(),
     OptionsCoherencePass(),
     LedgerDisciplinePass(),
+    SectionAwaitPass(),
 ]
 
 PASS_BY_ID = {p.PASS_ID: p for p in ALL_PASSES}

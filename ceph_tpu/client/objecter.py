@@ -27,6 +27,7 @@ import time
 from ..common import tracer as tracer_mod
 from ..common.errs import EAGAIN, ENOENT, ETIMEDOUT
 from ..common.log import dout
+from ..common.tracer import section, sectioned
 from ..mon.client import MonClient
 from ..mon.monmap import MonMap
 from ..msg.messages import (
@@ -118,6 +119,7 @@ class Objecter(Dispatcher):
 
     # -- dispatch --------------------------------------------------------------
 
+    @sectioned("client.reply")
     def ms_dispatch(self, conn: Connection, msg) -> bool:
         if isinstance(msg, MOSDOpReply):
             fut = self._replies.pop(msg.reqid.tid, None)
@@ -252,43 +254,17 @@ class Objecter(Dispatcher):
             if remaining <= 0:
                 self.perf.inc("op_timeout")
                 raise TimeoutError(f"op {reqid.key()} on {oid} timed out")
-            if ps is not None:
-                _up, _upp, _acting, primary = self.osdmap.pg_to_up_acting_osds(
-                    pool_id, ps
-                )
-                pgid = PgId(pool_id, ps, -1)
-            else:
-                pgid, primary = self._calc_target(pool_id, oid)
-            if primary == PG_NONE:
+            with section("client.op"):
+                built = self._build_op(pool_id, oid, ops, ps, snap_seq, snaps,
+                                       snap_id, reqid, span, deadline)
+            if built is None:
                 # No live primary in this interval: wait for the map to move
                 await self._wait_map_change(min(remaining, 0.5))
                 continue
-            info = self.osdmap.osds.get(primary)
-            if info is None or not info.addr:
-                await self._wait_map_change(min(remaining, 0.5))
-                continue
-            msg = MOSDOp(
-                reqid=reqid,
-                pgid=pgid,
-                oid=oid,
-                ops=ops,
-                epoch=self.osdmap.epoch,
-                snap_seq=snap_seq,
-                snaps=list(snaps or []),
-                snap_id=snap_id,
-            )
-            tracer_mod.inject(span, msg)
-            # end-to-end deadline propagation (ISSUE 17): the op's
-            # remaining budget rides the envelope so the OSD can shed
-            # already-expired work at admission and EC sub-reads inherit
-            # the budget instead of pinning shard sources for a reply
-            # nobody is waiting for
-            msg.deadline = deadline
-            fut: asyncio.Future = asyncio.get_event_loop().create_future()
-            self._replies[reqid.tid] = fut
+            msg, fut, primary, addr = built
             try:
                 span.event(lambda: f"sent to osd.{primary}")
-                await self.msgr.send_to(info.addr, msg)
+                await self.msgr.send_to(addr, msg)
                 reply: MOSDOpReply = await asyncio.wait_for(
                     fut, min(remaining, 2.0)
                 )
@@ -327,6 +303,44 @@ class Objecter(Dispatcher):
             span.event("reply received")
             self.perf.inc("op_reply")
             return reply
+
+    def _build_op(self, pool_id, oid, ops, ps, snap_seq, snaps, snap_id,
+                  reqid, span, deadline):
+        """Target the op and build its MOSDOp, registering the reply
+        future: (msg, future, primary, addr), or None while the map has
+        no live primary for it."""
+        if ps is not None:
+            _up, _upp, _acting, primary = self.osdmap.pg_to_up_acting_osds(
+                pool_id, ps
+            )
+            pgid = PgId(pool_id, ps, -1)
+        else:
+            pgid, primary = self._calc_target(pool_id, oid)
+        if primary == PG_NONE:
+            return None
+        info = self.osdmap.osds.get(primary)
+        if info is None or not info.addr:
+            return None
+        msg = MOSDOp(
+            reqid=reqid,
+            pgid=pgid,
+            oid=oid,
+            ops=ops,
+            epoch=self.osdmap.epoch,
+            snap_seq=snap_seq,
+            snaps=list(snaps or []),
+            snap_id=snap_id,
+        )
+        tracer_mod.inject(span, msg)
+        # end-to-end deadline propagation: the op's
+        # remaining budget rides the envelope so the OSD can shed
+        # already-expired work at admission and EC sub-reads inherit
+        # the budget instead of pinning shard sources for a reply
+        # nobody is waiting for
+        msg.deadline = deadline
+        fut: asyncio.Future = asyncio.get_event_loop().create_future()
+        self._replies[reqid.tid] = fut
+        return msg, fut, primary, info.addr
 
     async def _wait_map_change(self, timeout: float) -> None:
         ev = self._map_changed

@@ -43,7 +43,7 @@ def _factory(profile):
     ec = ErasureCodeNative(_get_lib(), technique=technique)
     ec.init(profile)
     # chunk-path calls (the C kernel) get a single `kernel` span; the
-    # inherited device paths get h2d/kernel_launch like the tpu plugin
+    # inherited device paths get codec spans like the tpu plugin
     return instrument_codec(ec, "native")
 
 

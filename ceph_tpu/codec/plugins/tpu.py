@@ -16,8 +16,8 @@ def _factory(profile):
     technique = profile.get("technique") or VANDERMONDE
     ec = ErasureCodeTpuRs(technique=technique)
     ec.init(profile)
-    # H2D / kernel_launch sub-spans on the device paths when an op trace
-    # is active (codec/tracing.py); free when tracing is off
+    # codec sub-spans on the device paths when an op trace is active
+    # (codec/tracing.py); free when tracing is off
     return instrument_codec(ec, "tpu")
 
 

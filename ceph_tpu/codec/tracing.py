@@ -1,21 +1,13 @@
-"""Span instrumentation for codec plugins — stage attribution for encode.
+"""Span instrumentation for codec plugins.
 
-The headline claim (≥40 GB/s/chip RS encode) is only auditable when a
-trace shows where an encode's time actually goes: the host→device
-transfer (H2D), the kernel launch, and — on the reap side, in
-stripe/stripe.py — the kernel wait + device→host copy (D2H).
-`instrument_codec` wraps a codec instance's hot entry points with
-sub-spans attached to the ACTIVE span (common/tracer.py's contextvar),
-so a traced client write's `ec:write` span gains
-
-    codec:<plugin>:encode
-      ├─ h2d            jnp.asarray staging the input onto the device
-      └─ kernel_launch  the async dispatch (returns while the chip works)
-
-children, and the stripe driver's `PendingEncode.result()` adds the
-matching `kernel_wait+d2h` when the parity is materialized.  Host-only
-codecs (the C `native` plugin's chunk interface) get a single `kernel`
-span — the whole call is synchronous host compute.
+`instrument_codec` wraps a codec instance's hot entry points with a
+`codec:<plugin>:<op>` sub-span of the ACTIVE span (common/tracer.py's
+contextvar), so a traced client write's `ec:write` span shows which
+codec call it made, with its shape or erasures.  The wrappers pass the
+caller's input through untouched: staging, launch and the device wait
+happen where they always do, inside the offload runtime, whose layer
+sections (`device.h2d`, `device.dispatch`, `device.block`, `device.d2h`,
+`offload.*_wait`) time them on the launch worker and the caller.
 
 Zero-cost when tracing is off: with no recorded active span each wrapper
 is one contextvar read and a falsy check before tail-calling the
@@ -23,8 +15,6 @@ original.
 """
 
 from __future__ import annotations
-
-import contextlib
 
 from ..common import tracer as tracer_mod
 
@@ -36,20 +26,9 @@ def active_span():
     return sp if sp is not None and sp.recorded else None
 
 
-def wait_span(parent):
-    """Context manager for the reap side of an async launch: times the
-    kernel wait + device→host copy as a `kernel_wait+d2h` child of
-    `parent`, or a no-op when the launch wasn't traced.  One name for
-    both the encode reap (PendingEncode.result) and the decode reap
-    (decode_concat) so trace tooling can match a single span name."""
-    if parent is None:
-        return contextlib.nullcontext()
-    return parent.child("kernel_wait+d2h")
-
-
 def instrument_codec(ec, plugin: str):
     """Wrap the device-path (encode_array/decode_array) and chunk-path
-    (encode_chunks/decode_chunks) entry points of `ec` with codec-stage
+    (encode_chunks/decode_chunks) entry points of `ec` with codec
     sub-spans.  Idempotent; returns `ec` for factory tail-calls."""
     if getattr(ec, "_codec_spans_installed", False):
         return ec
@@ -61,16 +40,9 @@ def instrument_codec(ec, plugin: str):
             parent = active_span()
             if parent is None:
                 return orig_encode_array(data, out=out)
-            import jax.numpy as jnp
-
             with parent.child(f"codec:{plugin}:encode") as sp:
                 sp.keyval("shape", lambda: str(getattr(data, "shape", len(data))))
-                with sp.child("h2d"):
-                    dev = jnp.asarray(data)
-                with sp.child("kernel_launch"):
-                    # async dispatch: this times the launch, not the kernel;
-                    # the reap side (PendingEncode.result) times the wait
-                    return orig_encode_array(dev, out=out)
+                return orig_encode_array(data, out=out)
 
         ec.encode_array = encode_array
 
@@ -81,14 +53,9 @@ def instrument_codec(ec, plugin: str):
             parent = active_span()
             if parent is None:
                 return orig_decode_array(erasures, survivors, out=out)
-            import jax.numpy as jnp
-
             with parent.child(f"codec:{plugin}:decode") as sp:
                 sp.keyval("erasures", lambda: ",".join(map(str, erasures)))
-                with sp.child("h2d"):
-                    dev = jnp.asarray(survivors)
-                with sp.child("kernel_launch"):
-                    return orig_decode_array(erasures, dev, out=out)
+                return orig_decode_array(erasures, survivors, out=out)
 
         ec.decode_array = decode_array
 

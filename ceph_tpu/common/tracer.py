@@ -8,6 +8,12 @@ ZTracer::Trace, src/osd/ECBackend.h:64-87, with events like
 in-process records with parent links, timed events, and keyvals,
 exportable as JSON for offline analysis.
 
+Layer sections (`section()`, bottom of this file) are the other half:
+synchronous slices of one layer's work on one thread, always counted
+into a process-wide table of calls, total and self time, so that a loop
+shared by many in-flight ops still yields each layer's own time.  Op
+spans cover `await`s and so other ops' work; sections never do.
+
 Cross-daemon propagation (the W3C traceparent / jspan-context analog):
 every span carries a 63-bit `trace_id` shared by the whole operation and
 a process-unique `span_id`.  `inject()` copies the pair into a message's
@@ -24,10 +30,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
+import inspect
 import itertools
+import math
 import random
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 
 from .lockdep import make_lock
@@ -485,3 +495,251 @@ def extract(msg) -> TraceContext | None:
         getattr(msg, "span_id", 0),
         getattr(msg, "trace_sampled", 0) or SAMPLED_KEEP,
     )
+
+
+# -- layer sections -------------------------------------------------------------
+#
+# `with section("msgr.encode"):` times one synchronous slice of a layer on
+# the calling thread.  Names are `<layer>.<what>`; a section must not span
+# an `await` (the `section-await` analysis pass holds the tree to that),
+# so its time is its own op's work and never another task's.  On exit the
+# section adds calls, total ns, self ns (total less its children on the
+# same thread) and bytes to its thread's table.  Tables are kept per
+# thread and per second of `time.perf_counter_ns()`, so the hot path
+# takes no lock and a reader can sum any window of whole seconds; seconds
+# older than _RETAIN_SECONDS fold into the thread's base table.
+#
+# `profile_sections(True)` makes each section a `jax.profiler.
+# TraceAnnotation` of the same name as well: on the profiler's clock, on
+# the same thread, so a device trace lines up with the program's layers.
+
+_BUCKET_NS = 1_000_000_000
+_RETAIN_SECONDS = 300
+_FIELDS = ("calls", "total_ns", "self_ns", "bytes")
+
+_LOCAL = threading.local()
+_THREADS: list["_ThreadSections"] = []
+_THREADS_LOCK = make_lock("layer_sections")
+_ANNOTATION = None  # jax.profiler.TraceAnnotation while profile_sections(True)
+_CHILD_NAMES: dict[tuple[str | None, str], str] = {}
+
+
+class _ThreadSections:
+    """One thread's open sections and its per-second tables."""
+
+    __slots__ = ("ident", "stack", "sec", "cur", "recent", "base", "lock")
+
+    def __init__(self) -> None:
+        self.ident = threading.get_ident()
+        self.stack: list[section] = []
+        self.sec = -1
+        self.cur: dict[str, list[int]] = {}
+        self.recent: deque[tuple[int, dict[str, list[int]]]] = deque()
+        self.base: dict[str, list[int]] = {}
+        self.lock = make_lock("layer_sections_thread")
+
+    def roll(self, sec: int) -> None:
+        with self.lock:
+            self.sec = sec
+            self.cur = {}
+            self.recent.append((sec, self.cur))
+            while len(self.recent) > _RETAIN_SECONDS:
+                _merge(self.base, self.recent.popleft()[1])
+
+    def tables(self, lo_sec: int | None = None, hi_sec: int | None = None) -> list[dict]:
+        """Copies of the tables in whole seconds [lo_sec, hi_sec); all of
+        them, base included, when no window is given."""
+        with self.lock:
+            if lo_sec is None:
+                picked = [self.base] + [t for _, t in self.recent]
+            else:
+                picked = [t for s, t in self.recent if lo_sec <= s < hi_sec]
+            # dict(t) first: a one-step copy, while the owner thread may
+            # be adding a name to its current table
+            return [{k: list(v) for k, v in dict(t).items()} for t in picked]
+
+
+def _merge(into: dict[str, list[int]], table: dict[str, list[int]]) -> None:
+    for name, rec in table.items():
+        acc = into.get(name)
+        if acc is None:
+            into[name] = list(rec)
+        else:
+            for i, v in enumerate(rec):
+                acc[i] += v
+
+
+def _thread_sections() -> _ThreadSections:
+    try:
+        return _LOCAL.st
+    except AttributeError:
+        st = _LOCAL.st = _ThreadSections()
+        with _THREADS_LOCK:
+            _THREADS.append(st)
+        return st
+
+
+def _account(st: _ThreadSections, t_end: int, name: str, total: int,
+             own: int, nbytes: int) -> None:
+    sec = t_end // _BUCKET_NS
+    if sec != st.sec:
+        st.roll(sec)
+    rec = st.cur.get(name)
+    if rec is None:
+        rec = st.cur[name] = [0, 0, 0, 0]
+    rec[0] += 1
+    rec[1] += total
+    rec[2] += own
+    rec[3] += nbytes
+
+
+class section:
+    """Context manager timing a synchronous slice of layer work as
+    `name` (`<layer>.<what>`), with `nbytes` of payload.  Set
+    `.nbytes` inside the block when the size is known only there."""
+
+    __slots__ = ("name", "nbytes", "_st", "_t0", "_child", "_ann")
+
+    def __init__(self, name: str, nbytes: int = 0) -> None:
+        self.name = name
+        self.nbytes = nbytes
+
+    # the hot path of every section: the thread state and the
+    # accounting are inlined (see _thread_sections and _account)
+    def __enter__(self) -> "section":
+        try:
+            st = self._st = _LOCAL.st
+        except AttributeError:
+            st = self._st = _thread_sections()
+        st.stack.append(self)
+        self._child = 0
+        ann = _ANNOTATION
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t_end = time.perf_counter_ns()
+        total = t_end - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        st = self._st
+        stack = st.stack
+        stack.pop()
+        if stack:
+            stack[-1]._child += total
+        if t_end // _BUCKET_NS != st.sec:
+            st.roll(t_end // _BUCKET_NS)
+        rec = st.cur.get(self.name)
+        if rec is None:
+            rec = st.cur[self.name] = [0, 0, 0, 0]
+        rec[0] += 1
+        rec[1] += total
+        rec[2] += total - self._child
+        rec[3] += self.nbytes
+
+
+def layer_section(what: str, nbytes: int = 0) -> section:
+    """A section named after the layer of the section open on this
+    thread, `<layer>.<what>` (`misc.<what>` when none is open): for a
+    utility such as crc32c, whose cost belongs to whoever called it."""
+    stack = _thread_sections().stack
+    return section(_child_name(stack[-1] if stack else None, what), nbytes)
+
+
+def _child_name(parent: section | None, what: str) -> str:
+    key = (parent.name if parent is not None else None, what)
+    name = _CHILD_NAMES.get(key)
+    if name is None:
+        layer = key[0].split(".", 1)[0] if key[0] else "misc"
+        name = _CHILD_NAMES[key] = f"{layer}.{what}"
+    return name
+
+
+def layer_call(what: str, nbytes: int, fn, *args):
+    """`fn(*args)` timed as `layer_section(what, nbytes)`, for a hot
+    leaf such as crc32c: with annotations off it is accounted from two
+    clock reads, without a stack entry, at about half the cost."""
+    if _ANNOTATION is not None:
+        with layer_section(what, nbytes):
+            return fn(*args)
+    t0 = time.perf_counter_ns()
+    out = fn(*args)
+    t_end = time.perf_counter_ns()
+    total = t_end - t0
+    st = _thread_sections()
+    parent = st.stack[-1] if st.stack else None
+    if parent is not None:
+        parent._child += total
+    _account(st, t_end, _child_name(parent, what), total, total, nbytes)
+    return out
+
+
+def sectioned(name: str):
+    """Decorator: the whole call of a synchronous function is a section."""
+
+    def wrap(fn):
+        if inspect.iscoroutinefunction(fn) or inspect.isgeneratorfunction(fn):
+            raise TypeError(f"{fn.__qualname__}: a section cannot span a suspension")
+
+        @functools.wraps(fn)
+        def timed(*args, **kwargs):
+            with section(name):
+                return fn(*args, **kwargs)
+
+        return timed
+
+    return wrap
+
+
+def count_section(name: str, ns: int = 0, nbytes: int = 0) -> None:
+    """Add one call of `ns` to `name` as a counter: it has a total and
+    no self time, as it is a wait measured between two points (a queue
+    wait), not a slice this thread spent working."""
+    st = _thread_sections()
+    _account(st, time.perf_counter_ns(), name, ns, 0, nbytes)
+
+
+def profile_sections(on: bool) -> None:
+    """Process-wide switch: while on, every section is also a
+    `jax.profiler.TraceAnnotation` of its name."""
+    global _ANNOTATION
+    if on:
+        import jax
+
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    else:
+        _ANNOTATION = None
+
+
+def _snapshot(threads, lo_sec=None, hi_sec=None) -> dict[str, dict[str, int]]:
+    acc: dict[str, list[int]] = {}
+    for st in threads:
+        for table in st.tables(lo_sec, hi_sec):
+            _merge(acc, table)
+    return {k: dict(zip(_FIELDS, v)) for k, v in sorted(acc.items())}
+
+
+def _threads(thread_id: int | None) -> list[_ThreadSections]:
+    with _THREADS_LOCK:
+        return [st for st in _THREADS if thread_id is None or st.ident == thread_id]
+
+
+def layer_totals(thread_id: int | None = None) -> dict[str, dict[str, int]]:
+    """Snapshot of the section table since the process started:
+    {name: {calls, total_ns, self_ns, bytes}}, over every thread or the
+    thread `thread_id` alone."""
+    return _snapshot(_threads(thread_id))
+
+
+def layer_window(lo: float, hi: float, thread_id: int | None = None):
+    """The section table of the whole seconds inside [lo, hi), seconds
+    on `time.perf_counter()`'s clock: (table, covered_lo, covered_hi).
+    A section counts in the second it ended in."""
+    lo_sec, hi_sec = math.ceil(lo), math.floor(hi)
+    if hi_sec <= lo_sec:
+        return {}, float(lo_sec), float(lo_sec)
+    return _snapshot(_threads(thread_id), lo_sec, hi_sec), float(lo_sec), float(hi_sec)

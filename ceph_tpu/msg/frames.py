@@ -18,7 +18,8 @@ from __future__ import annotations
 import struct
 from dataclasses import dataclass
 
-from ..utils.crc32c import crc32c
+from ..common.tracer import section
+from ..utils.crc32c import crc32c, crc32c_blocks
 
 MAGIC = b"CT"
 VERSION = 2
@@ -55,11 +56,10 @@ class Frame:
         head = struct.pack(
             "<2sBBBB4I", MAGIC, VERSION, self.tag, flags, len(self.segments), *lens
         )
-        out = [head, struct.pack("<I", crc32c(head))]
+        crcs = crc32c_blocks([head, *self.segments] if crc_data else [head])
+        out = [head, struct.pack("<I", crcs[0])]
         out.extend(self.segments)
-        if crc_data:
-            for s in self.segments:
-                out.append(struct.pack("<I", crc32c(s)))
+        out.extend(struct.pack("<I", c) for c in crcs[1:])
         return b"".join(out)
 
 
@@ -85,20 +85,32 @@ def preamble_info(buf: bytes) -> tuple[int, int, list[int]]:
 async def read_frame(reader) -> Frame:
     """Read one frame from an asyncio StreamReader, verifying CRCs."""
     head = await reader.readexactly(PREAMBLE_SIZE)
-    tag, flags, seg_lens = preamble_info(head)
+    with section("msgr.decode"):
+        tag, flags, seg_lens = preamble_info(head)
     segments = [await reader.readexactly(n) if n else b"" for n in seg_lens]
     if flags & FLAG_CRC_DATA:
-        for i, seg in enumerate(segments):
-            (crc,) = struct.unpack("<I", await reader.readexactly(4))
-            if crc32c(seg) != crc:
-                raise FrameError(f"segment {i} crc mismatch")
+        epilogue = await reader.readexactly(4 * len(segments))
+        with section("msgr.decode"):
+            _verify_segments(segments, epilogue)
     return Frame(tag, segments)
+
+
+def _verify_segments(segments: list[bytes], epilogue) -> None:
+    want = [crc for (crc,) in struct.iter_unpack("<I", epilogue)]
+    for i, (got, crc) in enumerate(zip(crc32c_blocks(segments), want)):
+        if got != crc:
+            raise FrameError(f"segment {i} crc mismatch")
 
 
 def frame_from_bytes(buf: bytes) -> Frame:
     """Parse one complete frame from a byte string (the secure/compressed
     on-wire path decrypts whole records, then parses here).  Truncated
     input raises FrameError, never struct.error."""
+    with section("msgr.decode"):
+        return _frame_from_bytes(buf)
+
+
+def _frame_from_bytes(buf: bytes) -> Frame:
     tag, flags, seg_lens = preamble_info(buf[:PREAMBLE_SIZE])
     need = PREAMBLE_SIZE + sum(seg_lens)
     if flags & FLAG_CRC_DATA:
@@ -111,9 +123,5 @@ def frame_from_bytes(buf: bytes) -> Frame:
         segments.append(buf[off : off + n])
         off += n
     if flags & FLAG_CRC_DATA:
-        for i, seg in enumerate(segments):
-            (crc,) = struct.unpack_from("<I", buf, off)
-            off += 4
-            if crc32c(seg) != crc:
-                raise FrameError(f"segment {i} crc mismatch")
+        _verify_segments(segments, buf[off : off + 4 * len(segments)])
     return Frame(tag, segments)

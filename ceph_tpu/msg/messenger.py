@@ -30,6 +30,7 @@ from ..common.fault_injector import (
     faultpoint_delay,
 )
 from ..common.log import dout
+from ..common.tracer import section
 from ..common.throttle import AsyncThrottle
 from .crypto import (
     FLAG_COMPRESSED,
@@ -259,8 +260,9 @@ class Connection:
             self._out_seq += 1
             msg.src = self.msgr.name
             msg.seq = self._out_seq
-            env, payload = encode_message(msg)
-            frame = Frame(TAG_MESSAGE, [env, payload])
+            with section("msgr.encode"):
+                env, payload = encode_message(msg)
+                frame = Frame(TAG_MESSAGE, [env, payload])
             attempt = 0
             give_up_at = asyncio.get_event_loop().time() + _RESEND_WINDOW
             while True:
@@ -286,10 +288,11 @@ class Connection:
                         # dead one — the frame still goes out, late.  The
                         # sleep holds only THIS connection's send lock
                         await asyncio.sleep(delay)
-                    raw = frame.pack(self.msgr.crc_data)
-                    if self._onwire is not None:
-                        raw = self._onwire.wrap(raw)
-                    self._writer.write(raw)
+                    with section("msgr.encode"):
+                        raw = frame.pack(self.msgr.crc_data)
+                        if self._onwire is not None:
+                            raw = self._onwire.wrap(raw)
+                        self._writer.write(raw)
                     await self._writer.drain()
                     return
                 except (ConnectionError, OSError, InjectedFailure):
@@ -347,7 +350,8 @@ class Connection:
                     continue
                 if frame.tag != TAG_MESSAGE:
                     continue
-                msg = decode_message(frame.segments[0], frame.segments[1])
+                with section("msgr.decode"):
+                    msg = decode_message(frame.segments[0], frame.segments[1])
                 await self.msgr._deliver(self, msg)
         except (
             asyncio.IncompleteReadError,

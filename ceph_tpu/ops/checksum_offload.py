@@ -36,7 +36,7 @@ import numpy as np
 
 from ceph_tpu.common.lockdep import make_lock as _lockdep_make_lock
 from ceph_tpu.common.mempool import track_buffer as _hbm_track
-from ceph_tpu.utils.crc32c import _TABLE, crc32c
+from ceph_tpu.utils.crc32c import _TABLE, crc32c, crc32c_blocks
 
 from .dispatch import record_launch
 from .offload_runtime import (
@@ -220,7 +220,7 @@ def checksum_blocks(
     if not chunks:
         return []
     if not offload or sum(len(c) for c in chunks) < CSUM_OFFLOAD_MIN_BYTES:
-        return [crc32c(c) for c in chunks]
+        return crc32c_blocks(chunks)
     agg = default_csum_aggregator()
     by_len: dict[int, list[int]] = {}
     for i, c in enumerate(chunks):

@@ -44,6 +44,7 @@ from collections import OrderedDict
 import numpy as np
 
 from ceph_tpu.common.lockdep import make_lock
+from ceph_tpu.common.tracer import section
 from ceph_tpu.common.mempool import ledger as _hbm_ledger
 
 
@@ -320,12 +321,14 @@ class DeviceChunkCache:
         def _copy_out():
             res: dict[int, np.ndarray] = {}
             n = 0
-            for s, entry in zip(shards, entries):
-                host = np.asarray(entry.buf)
-                if length is not None and host.nbytes > length:
-                    host = host[:length]
-                res[int(s)] = host
-                n += host.nbytes
+            with section("device.d2h") as d2h:
+                for s, entry in zip(shards, entries):
+                    host = np.asarray(entry.buf)
+                    if length is not None and host.nbytes > length:
+                        host = host[:length]
+                    res[int(s)] = host
+                    n += host.nbytes
+                d2h.nbytes = n
             return res, n
 
         t0 = time.monotonic()

@@ -58,6 +58,7 @@ import time
 from collections import deque
 
 from ceph_tpu.common.lockdep import make_lock
+from ceph_tpu.common.tracer import count_section
 
 DEFAULT_CAPACITY = 512
 
@@ -256,6 +257,9 @@ class FlightRecorder:
         if not rec["settle_ts"]:
             rec["settle_ts"] = now
         rec["queue_wait_s"] = max(0.0, rec["dispatch_ts"] - rec["submit_ts"])
+        if rec["submit_ts"]:
+            # cumulative in the layer table too: it outlives ring eviction
+            count_section("offload.queue_wait", int(rec["queue_wait_s"] * 1e9))
         rec["seq"] = next(self._seq)
         busy = rec["h2d_s"] + rec["kernel_s"] + rec["d2h_s"]
         with self._lock:

@@ -30,6 +30,7 @@ import threading
 import time
 
 from ceph_tpu.common.lockdep import make_lock
+from ceph_tpu.common.tracer import section
 
 
 class DeviceTimeout(RuntimeError):
@@ -78,6 +79,22 @@ def _launch_workers() -> _LaunchWorkers:
         if _WORKERS is None:
             _WORKERS = _LaunchWorkers()
         return _WORKERS
+
+
+# the caller's layer section for each kind of guarded call, by the last
+# word of the `what` it gives: the time the caller sat blocked on the
+# device (or on a launch worker running device work for it)
+_WAIT_SECTIONS = {
+    "dispatch": "offload.dispatch_wait",
+    "probe": "offload.dispatch_wait",
+    "materialize": "offload.settle_wait",
+    "put": "offload.cache_wait",
+    "fetch": "offload.cache_wait",
+}
+
+
+def _wait_section(what: str) -> str:
+    return _WAIT_SECTIONS.get(what.rsplit(" ", 1)[-1], "offload.dispatch_wait")
 
 
 def _default_probe() -> None:
@@ -140,7 +157,12 @@ class DeviceGuard:
         abandoned (daemon; its eventual result is discarded), a
         replacement starts, and DeviceTimeout raises — the caller falls
         back to the host oracle, which never touches the wedged
-        runtime."""
+        runtime.  The caller's wait is the layer section
+        `_wait_section(what)`."""
+        with section(_wait_section(what)):
+            return self._call(fn, what, timeout_ms)
+
+    def _call(self, fn, what: str, timeout_ms: int | None):
         t_ms = self.timeout_ms if timeout_ms is None else timeout_ms
         if t_ms <= 0 or getattr(_ON_WORKER, "active", False):
             return fn()

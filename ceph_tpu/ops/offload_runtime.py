@@ -45,6 +45,7 @@ import numpy as np
 from ceph_tpu.common.lockdep import make_lock as _lockdep_make_lock
 from ceph_tpu.common.lockdep import make_rlock as _lockdep_make_rlock
 from ceph_tpu.common.mempool import ledger as _hbm_ledger
+from ceph_tpu.common.tracer import section
 
 
 def _next_pow2(n: int) -> int:
@@ -537,6 +538,10 @@ class LaunchAggregator:
         ticket.  May launch (this or earlier submissions) when a threshold
         trips.  Admission is throttled: past ec_tpu_inflight_max_bytes of
         unsettled work, this call settles older launches first."""
+        with section("offload.submit", shaped.nbytes):
+            return self._enqueue(key, ec, ctx, shaped)
+
+    def _enqueue(self, key, ec, ctx, shaped: np.ndarray) -> AggTicket:
         stripes = shaped.shape[0]
         # HBM pressure hook (ISSUE 13): time-throttled, no locks held —
         # under a target, sustained submission pressure trims the cache
@@ -974,11 +979,18 @@ class LaunchAggregator:
         try:
             faultpoint("codec.launch")
             return guard.call(
-                lambda: self._dispatch(g, data, donate),
+                lambda: self._timed_dispatch(g, data, donate),
                 what=f"{self.WHAT} dispatch",
             )
         except BaseException as e:
             return self._host_fallback(g, data, e)
+
+    def _timed_dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+        """The guarded callable: the launch as the `device.dispatch`
+        section of whichever thread runs it (a launch worker, or the
+        caller with the watchdog off)."""
+        with section("device.dispatch", data.nbytes):
+            return self._dispatch(g, data, donate)
 
     def _host_fallback(self, g: _AggGroup, data: np.ndarray, cause):
         """Re-run a launch on the host oracle.  `cause` is the device
@@ -1089,13 +1101,16 @@ class LaunchAggregator:
                         t0 = time.monotonic()
                         wait = getattr(parity, "block_until_ready", None)
                         if wait is not None:
-                            wait()
+                            with section("device.block"):
+                                wait()
                         t1 = time.monotonic()
-                        out = (
-                            np.array(parity)
-                            if force_copy
-                            else np.asarray(parity)
-                        )
+                        with section("device.d2h") as d2h:
+                            out = (
+                                np.array(parity)
+                                if force_copy
+                                else np.asarray(parity)
+                            )
+                            d2h.nbytes = out.nbytes
                         t2 = time.monotonic()
                         spans["kernel_s"] = t1 - t0
                         spans["complete_ts"] = t1

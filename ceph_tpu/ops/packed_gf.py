@@ -372,6 +372,21 @@ def _packed_code_into(out: jax.Array, data: jax.Array, *, sched, k: int, m: int)
     return _packed_code_impl(data, sched, k, m)
 
 
+# The decode twins: the same program under its own name, so that a
+# device trace tells decode launches from encode launches.  Every name
+# contains `packed`, which the benchmark's kernel reader matches.
+@functools.partial(jax.jit, static_argnames=("sched", "k", "m"))
+def _packed_decode(data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
+    return _packed_code_impl(data, sched, k, m)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("sched", "k", "m"), donate_argnums=(0,)
+)
+def _packed_decode_into(out: jax.Array, data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
+    return _packed_code_impl(data, sched, k, m)
+
+
 def _packed_verify_impl(codeword: jax.Array, sched, k: int, m: int) -> jax.Array:
     """(..., k+m, L) uint8 codeword -> (...,) uint8 per-stripe mismatch
     bitmap: bit j set iff recomputed parity row j differs from the
@@ -551,5 +566,6 @@ class PackedPlan:
             and tuple(getattr(out, "shape", ())) == want_shape
             and getattr(out, "dtype", None) == jnp.uint8
         ):
-            return _packed_code_into(out, data, **kw)
-        return _packed_code(data, **kw)
+            into = _packed_decode_into if self.decode else _packed_code_into
+            return into(out, data, **kw)
+        return (_packed_decode if self.decode else _packed_code)(data, **kw)

@@ -50,7 +50,8 @@ import os
 import struct
 from dataclasses import dataclass, field
 
-from ..utils.crc32c import crc32c
+from ..common.tracer import sectioned
+from ..utils.crc32c import crc32c, crc32c_blocks
 from .kv import FileKV, KeyValueDB, MemKV
 from .objectstore import ObjectStore, StoreError
 from .transaction import OP_WRITE, Transaction
@@ -281,7 +282,7 @@ class BlueStore(ObjectStore):
             from ..ops.checksum_offload import checksum_blocks
 
             return checksum_blocks(stored, offload=True)
-        return [crc32c(s) for s in stored]
+        return crc32c_blocks(stored)
 
     # -- mount / umount --------------------------------------------------------
 
@@ -391,7 +392,7 @@ class BlueStore(ObjectStore):
 
     # -- transaction application ----------------------------------------------
 
-    def queue_transaction(self, txn: Transaction, on_commit=None) -> None:
+    def _commit(self, txn: Transaction) -> None:
         """Stage every op, then commit in BlueStore's order: direct data →
         fsync → one atomic KV batch (the commit point) → deferred WAL
         application → WAL cleanup (BlueStore::_txc_state_proc)."""
@@ -448,8 +449,6 @@ class BlueStore(ObjectStore):
         self._direct, self._deferred = [], []
         self._staged, self._to_release = {}, []
         self._staged_rm = set()
-        if on_commit is not None:
-            on_commit()
 
     def _reload_dirty(self) -> None:
         for ck in self._dirty:
@@ -487,16 +486,18 @@ class BlueStore(ObjectStore):
             return self._compressor.decompress(stored)
         return stored
 
-    def _valid_block(self, o: Onode, bidx: int) -> bytes:
-        """Block content with bytes at logical offsets >= size zeroed —
-        the overlay source for read-modify-write."""
-        data = self._logical_block(o, bidx)
-        end = o.size - bidx * BLOCK
-        if end <= 0:
-            return b"\x00" * BLOCK
-        if end < BLOCK:
-            return data[:end] + b"\x00" * (BLOCK - end)
-        return data
+    def _valid_blocks(self, o: Onode, b_first: int, b_last: int) -> list[bytes]:
+        """Block contents with bytes at logical offsets >= size zeroed —
+        the overlay source for read-modify-write.  The blocks holding
+        bytes below the size are verified with one host crc pass."""
+        live = min(b_last, (o.size - 1) // BLOCK)
+        out = []
+        if live >= b_first:
+            out = self._logical_blocks(o, b_first, live, csum=crc32c_blocks)
+            tail = o.size - live * BLOCK
+            if tail < BLOCK:
+                out[-1] = out[-1][:tail] + b"\x00" * (BLOCK - tail)
+        return out + [b"\x00" * BLOCK] * (b_last - b_first + 1 - len(out))
 
     def _write(
         self, coll: str, oid: str, off: int, data: bytes, csums=None
@@ -514,8 +515,7 @@ class BlueStore(ObjectStore):
         # pre-overlay content of live blocks for the unchanged-skip check.
         images: dict[int, bytearray] = {}
         orig: dict[int, bytes] = {}
-        for b in range(b0, b1 + 1):
-            prev = self._valid_block(o, b)
+        for b, prev in zip(range(b0, b1 + 1), self._valid_blocks(o, b0, b1)):
             if b in o.blocks:
                 orig[b] = prev
             images[b] = bytearray(prev)
@@ -681,6 +681,7 @@ class BlueStore(ObjectStore):
 
     # -- reads -----------------------------------------------------------------
 
+    @sectioned("store.read")
     def read(self, coll: str, oid: str, off: int = 0, length: int = 0) -> bytes:
         self._faultpoint("os.read", coll, oid)
         o = self._peek_onode(coll, oid)
@@ -699,13 +700,14 @@ class BlueStore(ObjectStore):
         return b"".join(parts)
 
     def _logical_blocks(
-        self, o: Onode, b_first: int, b_last: int
+        self, o: Onode, b_first: int, b_last: int, csum=None
     ) -> list[bytes]:
         """`_logical_block` over a contiguous range with ONE batched
         verification-csum pass: when csum offload is armed the whole
         range's stored forms ride the offload runtime (grouped by stored
         length) instead of one host crc per block.  Holes read zeros;
-        a digest mismatch raises the same EIO as the scalar path."""
+        a digest mismatch raises the same EIO as the scalar path.
+        `csum` replaces the batched digest function."""
         out: list[bytes | None] = [None] * (b_last - b_first + 1)
         mapped: list[tuple[int, int, int, int, int, bytes]] = []
         for b in range(b_first, b_last + 1):
@@ -721,7 +723,7 @@ class BlueStore(ObjectStore):
                     stored = stored + b"\x00" * (BLOCK - len(stored))
             mapped.append((b - b_first, b, poff, crc, clen, stored))
         if mapped:
-            digs = self._csum_batch([m[5] for m in mapped])
+            digs = (csum or self._csum_batch)([m[5] for m in mapped])
             for (idx, bidx, poff, crc, clen, stored), dig in zip(mapped, digs):
                 if dig != crc:
                     raise StoreError(

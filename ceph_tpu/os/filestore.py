@@ -65,7 +65,7 @@ class FileStore(ObjectStore):
 
     # -- transaction durability ----------------------------------------------
 
-    def queue_transaction(self, txn: Transaction, on_commit=None) -> None:
+    def _commit(self, txn: Transaction) -> None:
         if txn.ops:
             # pre-journal write-fault seam, matching the other backends
             self._faultpoint("os.write", txn.ops[0].coll, txn.ops[0].oid)
@@ -80,8 +80,6 @@ class FileStore(ObjectStore):
             self._journal.rm("txn", key)  # aborted, not committed
             raise
         self._journal.rm("txn", key)
-        if on_commit is not None:
-            on_commit()
 
     def _resolve_appends(self, txn: Transaction) -> Transaction:
         """Rewrite OP_WRITE_APPEND to absolute-offset OP_WRITE so journal

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+from ..common.tracer import sectioned
 from .objectstore import ObjectStore, StoreError
 
 
@@ -91,6 +92,7 @@ class MemStore(ObjectStore):
 
     # -- reads ---------------------------------------------------------------
 
+    @sectioned("store.read")
     def read(self, coll: str, oid: str, off: int = 0, length: int = 0) -> bytes:
         self._faultpoint("os.read", coll, oid)
         o = self._obj(coll, oid)

@@ -14,6 +14,7 @@ from __future__ import annotations
 import errno as _errno
 from typing import Callable, Iterable
 
+from ..common.tracer import section
 from . import transaction as tx
 from .transaction import Op, Transaction
 
@@ -62,7 +63,17 @@ class ObjectStore:
         option", ObjectStore.h): a mid-transaction error indicates a
         caller bug; ops already applied are NOT rolled back and
         on_commit does not fire.  Durable backends additionally drop the
-        journal entry so the aborted txn never replays."""
+        journal entry so the aborted txn never replays.
+
+        The apply and commit are the `store.txn` layer section; the
+        on_commit callback runs after it, as the caller's own work."""
+        with section("store.txn"):
+            self._commit(txn)
+        if on_commit is not None:
+            on_commit()
+
+    def _commit(self, txn: Transaction) -> None:
+        """Apply and persist `txn` (the backend's commit sequence)."""
         if txn.ops:
             # write-fault seam, checked BEFORE any op lands: an injected
             # media error fails the whole transaction atomically (per-op
@@ -71,8 +82,6 @@ class ObjectStore:
         for op in txn.ops:
             self._apply_op(op)
         self._persist(txn)
-        if on_commit is not None:
-            on_commit()
 
     def _apply_op(self, op: Op) -> None:
         if op.code == tx.OP_TOUCH:

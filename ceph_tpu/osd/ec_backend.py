@@ -42,7 +42,7 @@ from ..codec.interface import EcError, ErasureCodeInterface
 from ..common.errs import ETIMEDOUT
 from ..common.fault_injector import faultpoint, faultpoint_delay
 from ..common import tracer as tracer_mod
-from ..common.tracer import null_span
+from ..common.tracer import null_span, sectioned
 from ..msg.messages import (
     MOSDECSubOpRead,
     MOSDECSubOpReadReply,
@@ -475,6 +475,7 @@ class ECBackend(PGBackend):
 
     # -- message entry point --------------------------------------------------
 
+    @sectioned("ec.sub_op")
     def handle_message(self, msg) -> bool:
         if isinstance(msg, MOSDECSubOpWrite):
             self.handle_sub_write(msg)
@@ -494,6 +495,7 @@ class ECBackend(PGBackend):
 
     # -- write pipeline (§3.1) -----------------------------------------------
 
+    @sectioned("ec.write")
     def submit_transaction(
         self,
         pgt: PGTransaction,
@@ -732,6 +734,7 @@ class ECBackend(PGBackend):
             return
         loop.call_soon(self._drain_encode_pipe)
 
+    @sectioned("ec.reap")
     def _drain_encode_pipe(self) -> None:
         """Dispatch every op whose launch finished, strictly FIFO.  A head
         still computing is re-polled a few times, then reaped blocking —
@@ -758,6 +761,7 @@ class ECBackend(PGBackend):
                 return
             self._dispatch_encoded(self._encode_pipe.pop(0))
 
+    @sectioned("ec.reap")
     def flush_encodes(self) -> None:
         """Drain the whole encode pipeline (the barrier before commit
         checks in synchronous harnesses; EncodePipeline.flush analog).
@@ -1014,6 +1018,7 @@ class ECBackend(PGBackend):
 
     # -- read path (§3.1 reads / §3.2 gather) --------------------------------
 
+    @sectioned("ec.read")
     def objects_read_and_reconstruct(
         self,
         reads: Mapping[str, list[tuple[int, int]]],
@@ -1680,6 +1685,7 @@ class ECBackend(PGBackend):
             "decoding": len(self._decode_pipe),
         }
 
+    @sectioned("ec.recovery")
     def recover_object(
         self, oid: str, missing_on: set[int], on_complete: Callable[[int], None]
     ) -> None:
@@ -1894,6 +1900,7 @@ class ECBackend(PGBackend):
             return
         loop.call_soon(self._drain_decode_pipe)
 
+    @sectioned("ec.recovery")
     def _drain_decode_pipe(self) -> None:
         """Push out every RecoveryOp whose decode finished, strictly FIFO.
         A head still windowed/computing gets the same re-poll grace as the

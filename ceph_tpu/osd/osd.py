@@ -34,7 +34,7 @@ from ..common.clog import ClusterLogClient
 from ..common.config import Config
 from ..common.log import dout
 from ..common.perf_counters import PerfCountersBuilder
-from ..common.tracer import Tracer, null_span
+from ..common.tracer import Tracer, count_section, null_span, section, sectioned
 from ..mon.client import MonClient
 from ..mon.monmap import MonMap
 from ..msg.message import Message
@@ -656,6 +656,10 @@ class OSD(Dispatcher):
                 "ec_dispatch": ec_dispatch.perf_dump(),
                 # offload-runtime service registry slice (ISSUE 20)
                 "offload": _offload_perf_dump(),
+                # process-wide layer sections (common/tracer.py): calls,
+                # total/self ns and bytes per `<layer>.<what>`; asok
+                # only, not on the mgr report
+                "layers": tracer_mod.layer_totals(),
             },
             "dump perf counters",
         )
@@ -1150,6 +1154,7 @@ class OSD(Dispatcher):
 
     # -- client ops through the scheduler --------------------------------------
 
+    @sectioned("osd.dispatch")
     def _enqueue_op(self, conn: Connection, msg: MOSDOp) -> None:
         """enqueue_op (OSD.cc:9431): into the QoS scheduler."""
         from .pg import op_class_of
@@ -1181,8 +1186,10 @@ class OSD(Dispatcher):
         span.keyval("oid", msg.oid)
         span.keyval("reqid", lambda: msg.reqid.key())
         span.event("queued")
+        t_enqueued = time.perf_counter_ns()
 
         def run() -> None:
+            count_section("osd.queue_wait", time.perf_counter_ns() - t_enqueued)
             self.op_tracker.mark_event(token, "dequeued")
             span.event("dequeued")
             with tracer_mod.span_scope(span):
@@ -1214,6 +1221,7 @@ class OSD(Dispatcher):
 
             op_class = op_class_of(msg.ops)
 
+        @sectioned("osd.reply")
         def reply(rep: MOSDOpReply) -> None:
             self.op_tracker.finish(token)
             lat = time.monotonic() - t0
@@ -1318,7 +1326,8 @@ class OSD(Dispatcher):
                 await self._sched_kick.wait()
                 continue
             try:
-                item.run()
+                with section("osd.op"):
+                    item.run()
             except Exception as e:  # an op must not kill the worker
                 dout("osd", 0, f"osd.{self.whoami}: op raised {e!r}")
             await asyncio.sleep(0)

@@ -39,6 +39,7 @@ from ..common.errs import (
     EPERM,
 )
 from ..common.log import dout
+from ..common.tracer import sectioned
 from ..msg.messages import (
     MBackfillReserve,
     MOSDOp,
@@ -567,6 +568,7 @@ class PG(PGListener):
         else:
             self.osd.send_cluster(osd, msg)
 
+    @sectioned("osd.pg")
     def append_log(self, entry: LogEntry) -> None:
         if entry.version > self.pg_log.head:
             self.pg_log.append(entry)
@@ -630,6 +632,7 @@ class PG(PGListener):
 
     # -- client op execution ---------------------------------------------------
 
+    @sectioned("osd.pg")
     def do_op(
         self, msg: MOSDOp, reply: Callable[[MOSDOpReply], None], conn=None
     ) -> None:
@@ -1121,6 +1124,7 @@ class PG(PGListener):
             deadline=getattr(msg, "deadline", 0.0),
         )
 
+    @sectioned("osd.pg")
     def _finish_write(
         self, msg: MOSDOp, reply, rep: MOSDOpReply, remember: bool
     ) -> None:

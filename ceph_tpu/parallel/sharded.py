@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from ceph_tpu.common.mempool import track_buffer
+from ceph_tpu.common.tracer import section
 from ceph_tpu.ops.dispatch import record_launch
 from ceph_tpu.ops.packed_gf import PackedPlan, _packed_code_impl
 from ceph_tpu.ops.pallas_gf import CodingPlan
@@ -329,9 +330,10 @@ def sharded_coder_code(coder, data, mesh: Mesh, out=None) -> jax.Array:
     # HBM ledger (ISSUE 13): the sharded H2D placement is device-resident
     # for the life of the launch — tracked so dump_mempools shows bulk
     # launches' staging alongside the cache/donation/in-flight pools
-    placed = track_buffer(
-        jax.device_put(data, _stripe_sharding(mesh)), "sharded_placement"
-    )
+    with section("device.h2d", data.nbytes):
+        placed = track_buffer(
+            jax.device_put(data, _stripe_sharding(mesh)), "sharded_placement"
+        )
     if coder.plan is not None and L % 128 == 0:
         # trace-time caveat: the CodingPlan wrapper records its own
         # (single) launch while the shard_map body is first traced; the

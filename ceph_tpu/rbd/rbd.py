@@ -32,6 +32,7 @@ import secrets
 from ..client.rados import RadosError
 from ..cls import client as cls_client
 from ..common.errs import EBUSY, EEXIST, EINVAL, ENOENT
+from ..common.tracer import section
 
 DIRECTORY_OID = "rbd_directory"
 CHILDREN_OID = "rbd_children"  # parent "<id>@<snap_id>" -> [child ids]
@@ -324,19 +325,19 @@ class Image:
     async def write(self, off: int, data: bytes) -> None:
         if off + len(data) > self.size:
             raise RbdError(EINVAL, "write past end of image")
-        snapc = self._snapc()
-        cursor = 0
+        with section("client.stripe"):
+            snapc = self._snapc()
+            pieces, cursor = [], 0
+            for objno, obj_off, ln in self._extents(off, len(data)):
+                pieces.append((objno, obj_off, data[cursor : cursor + ln]))
+                cursor += ln
         has_parent = self.header.get("parent") is not None
-        for objno, obj_off, ln in self._extents(off, len(data)):
+        for objno, obj_off, piece in pieces:
             if has_parent:
                 await self._copy_up(objno)
             await self.ioctx.write(
-                self._data_oid(objno),
-                data[cursor : cursor + ln],
-                obj_off,
-                snapc=snapc,
+                self._data_oid(objno), piece, obj_off, snapc=snapc
             )
-            cursor += ln
 
     async def read(self, off: int, length: int, snap_name: str | None = None) -> bytes:
         if off >= self.size:

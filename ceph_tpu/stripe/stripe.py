@@ -18,6 +18,7 @@ from typing import Mapping
 import numpy as np
 
 from ceph_tpu.codec.base import EINVAL, EIO
+from ceph_tpu.common.tracer import section
 from ceph_tpu.codec.interface import EcError, ErasureCodeInterface
 from ceph_tpu.codec.matrix_codec import MatrixCodecMixin
 
@@ -71,6 +72,16 @@ class StripeInfo:
         return stripe, chunk, off
 
 
+def _to_host(buf) -> np.ndarray:
+    """`buf` on the host.  A bare device array (a delta launch, a cache
+    hit) is waited for here, on the caller: that wait is a device wait.
+    An aggregator ticket times its own wait in the offload runtime."""
+    if hasattr(buf, "block_until_ready"):
+        with section("offload.settle_wait", buf.nbytes):
+            return np.asarray(buf)
+    return np.asarray(buf)
+
+
 class PendingEncode:
     """A LAUNCHED stripe encode whose device work may still be running.
 
@@ -88,12 +99,6 @@ class PendingEncode:
         self._k, self._m = k, m
         self._want = want
         self._result: dict[int, np.ndarray] | None = None
-        # the span active at LAUNCH time (codec/tracing.py active_span);
-        # the reap may run from an event-loop callback with no scope, so
-        # the D2H side must remember where it belongs in the trace
-        from ..codec.tracing import active_span
-
-        self._span = active_span()
 
     def ready(self) -> bool:
         if self._result is not None:
@@ -111,11 +116,7 @@ class PendingEncode:
 
     def result(self) -> dict[int, np.ndarray]:
         if self._result is None:
-            from ..codec.tracing import wait_span
-
-            with wait_span(self._span):
-                parity = np.asarray(self._parity)  # blocks until launch done
-            self._span = None
+            parity = _to_host(self._parity)  # blocks until launch done
             out: dict[int, np.ndarray] = {}
             for i in range(self._k):
                 out[i] = np.ascontiguousarray(self._shaped[:, i, :]).reshape(-1)
@@ -283,15 +284,16 @@ def encode_delta_launch(
             t0 = time.monotonic()
             rec["dispatch_ts"] = t0
             faultpoint("codec.launch")
-            parity = device_guard().call(
-                lambda: ec.encode_delta_device(
-                    [_fit(resident[i]) for i in range(k)],
-                    new_bufs,
-                    [_fit(resident[k + i]) for i in range(m)],
-                    sinfo.chunk_size,
-                ),
-                what="delta dispatch",
-            )
+            def _dispatch():
+                with section("device.dispatch"):
+                    return ec.encode_delta_device(
+                        [_fit(resident[i]) for i in range(k)],
+                        new_bufs,
+                        [_fit(resident[k + i]) for i in range(m)],
+                        sinfo.chunk_size,
+                    )
+
+            parity = device_guard().call(_dispatch, what="delta dispatch")
             # generation bump IN PLACE: the delta output never leaves
             # HBM — each parity row re-enters the cache at new_gen with
             # no device_put (the next cache-hit RMW deltas again)
@@ -337,11 +339,6 @@ class PendingDecode:
         self._handle = handle
         self._assemble = assemble
         self._result = result
-        # the span active at LAUNCH time, so a reap from an event-loop
-        # callback attributes its wait to the right place in the trace
-        from ..codec.tracing import active_span
-
-        self._span = active_span() if handle is not None else None
 
     def ready(self) -> bool:
         if self._result is not None:
@@ -358,12 +355,9 @@ class PendingDecode:
 
     def result(self):
         if self._result is None:
-            from ..codec.tracing import wait_span
-
-            with wait_span(self._span):
-                rec = np.asarray(self._handle)  # blocks until launch done
+            rec = _to_host(self._handle)  # blocks until launch done
             self._result = self._assemble(rec)
-            self._handle = self._assemble = self._span = None
+            self._handle = self._assemble = None
         return self._result
 
 

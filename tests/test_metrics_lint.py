@@ -805,7 +805,13 @@ class TestAuditDiscipline:
                 1 for e in mons[0].logmon.entries
                 if e["channel"] == "audit"
             )
-            await asyncio.to_thread(admin_command, path, "perf dump")
+            dump = await asyncio.to_thread(admin_command, path, "perf dump")
+            # the layer-section table rides the asok dump only: no
+            # ceph_tpu_* scrape family is generated from it
+            assert all(
+                set(v) == {"calls", "total_ns", "self_ns", "bytes"}
+                for v in dump["layers"].values()
+            ), dump["layers"]
             await asyncio.sleep(0.2)
             after = sum(
                 1 for e in mons[0].logmon.entries

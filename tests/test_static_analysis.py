@@ -38,6 +38,7 @@ from ceph_tpu.analysis.passes.ledger import LedgerDisciplinePass
 from ceph_tpu.analysis.passes.locks import LockDisciplinePass
 from ceph_tpu.analysis.passes.options_coherence import OptionsCoherencePass
 from ceph_tpu.analysis.passes.purity import JitPurityPass
+from ceph_tpu.analysis.passes.section_await import SectionAwaitPass
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -466,6 +467,68 @@ class TestLedgerPass:
         assert not LedgerDisciplinePass()(tree)
 
 
+class TestSectionAwaitPass:
+    """section-await: a layer section must not span a suspension point
+    of the shared asyncio loop."""
+
+    @pytest.mark.parametrize("body,kind", [
+        ("await conn.send(msg)", "await"),
+        ("async with lock: pass", "async with"),
+        ("async for m in stream: pass", "async for"),
+    ])
+    def test_suspension_inside_section_trips(self, tmp_path, body, kind):
+        tree = _tree(tmp_path, {"msg/x.py": f"""
+            from ceph_tpu.common.tracer import section
+
+            async def send(conn, msg, lock, stream):
+                with section("msgr.encode"):
+                    {body}
+        """})
+        keys = _keys(SectionAwaitPass()(tree))
+        assert keys == {f"pkg/msg/x.py::send::{kind}"}, keys
+
+    def test_layer_section_and_attribute_form_trip(self, tmp_path):
+        tree = _tree(tmp_path, {"os/x.py": """
+            from ceph_tpu.common import tracer
+
+            async def read(store):
+                with tracer.layer_section("crc32c"):
+                    return await store.read()
+        """})
+        assert len(SectionAwaitPass()(tree)) == 1
+
+    def test_sectioned_async_def_trips(self, tmp_path):
+        tree = _tree(tmp_path, {"osd/x.py": """
+            from ceph_tpu.common.tracer import sectioned
+
+            @sectioned("osd.op")
+            async def run(item):
+                await item()
+        """})
+        assert _keys(SectionAwaitPass()(tree)) == {"pkg/osd/x.py::run::async def"}
+
+    def test_sync_section_and_await_outside_pass(self, tmp_path):
+        """The idiom the tree uses: close the section, then await; a
+        coroutine only DEFINED in the block does not run there."""
+        tree = _tree(tmp_path, {"msg/x.py": """
+            from ceph_tpu.common.tracer import section, sectioned
+
+            async def send(writer, frame):
+                with section("msgr.encode"):
+                    raw = frame.pack()
+                    writer.write(raw)
+
+                    async def later():
+                        await writer.drain()
+                await writer.drain()
+
+            @sectioned("osd.pg")
+            def do_op(msg):
+                return msg
+        """})
+        assert SectionAwaitPass()(tree) == []
+
+
 class TestAllowlist:
     def test_reason_mandatory(self, tmp_path):
         p = tmp_path / "x.allow"
@@ -547,6 +610,11 @@ class TestCli:
         "jit-purity": (
             "import time, jax\n\n@jax.jit\ndef k(x):\n"
             "    time.time()\n    return x\n"
+        ),
+        "section-await": (
+            "from ceph_tpu.common.tracer import section\n\n"
+            "async def f(w):\n    with section('msgr.encode'):\n"
+            "        await w.drain()\n"
         ),
         "donation-lifetime": (
             "import functools, jax\n\n"

@@ -273,6 +273,36 @@ class TestEndToEndTrace:
         asyncio.run(run())
 
 
+class TestCodecSpans:
+    def test_traced_encode_makes_no_transfer_of_its_own(self):
+        """An op span on the codec path changes nothing it observes: a
+        traced and an untraced encode of the same batch give the same
+        parity bytes and the same number of device launches."""
+        import numpy as np
+
+        from ceph_tpu.codec import registry
+        from ceph_tpu.ops import dispatch
+
+        ec = registry.instance().factory("tpu", {"k": "4", "m": "2"})
+        batch = np.random.default_rng(9).integers(0, 256, (3, 4, 4096), dtype=np.uint8)
+
+        def encode():
+            before = dispatch.LAUNCHES.snapshot()["launches"]
+            parity = np.asarray(ec.encode_array(batch))
+            return parity, dispatch.LAUNCHES.snapshot()["launches"] - before
+
+        plain, plain_launches = encode()
+        tr = Tracer("test", enabled=True)
+        root = tr.start_span("ec:write")
+        with tracer_mod.span_scope(root):
+            traced, traced_launches = encode()
+        root.finish()
+        assert np.array_equal(plain, traced)
+        assert traced_launches == plain_launches == 1
+        names = [s["name"] for s in tr.export()]
+        assert names == ["ec:write", "codec:tpu:encode"], names
+
+
 # --- SLOW_OPS health pipeline -------------------------------------------------
 
 
