@@ -36,8 +36,11 @@ here to the triad that defines the design):
 Logical layout: block index `i` of an object maps to one physical block
 slot; the in-memory map is {block_index: (phys_off, crc, clen)} — clen 0
 for a raw BLOCK, else the compressed stored length — and serializes as
-runs.  Every write replaces a block's WHOLE stored image (read-modify-
-write at block granularity), so WAL replay needs no byte-level merging.
+runs.  Every write replaces a block's WHOLE stored image, so WAL replay
+needs no byte-level merging: a block the write covers entirely takes its
+image straight from the payload, with no read-back; a block it covers in
+part is read, verified and overlaid (read-modify-write at block
+granularity).
 Bytes at logical offsets >= the object size are undefined-on-disk but
 never observable: reads clamp to size and overlays treat them as zeros
 (hole semantics).
@@ -50,7 +53,7 @@ import os
 import struct
 from dataclasses import dataclass, field
 
-from ..common.tracer import sectioned
+from ..common.tracer import count_section, sectioned
 from ..utils.crc32c import crc32c, crc32c_blocks
 from .kv import FileKV, KeyValueDB, MemKV
 from .objectstore import ObjectStore, StoreError
@@ -253,26 +256,26 @@ class BlueStore(ObjectStore):
         """Runtime observer target for `bluestore_csum_offload`."""
         self._csum_offload = bool(enabled)
 
-    def _store_forms(self, images: list[bytes]) -> list[tuple[bytes, int]]:
-        """Batched `_store_form`: compressors exposing `compress_batch`
-        (the device plugin) get ONE call for the whole block range so
-        their transforms coalesce into shared offload launches; the
-        required-ratio gate is applied per block exactly as in the
-        scalar path."""
-        if not images:
-            return []
-        if self._compressor.name == "none":
-            return [(img, 0) for img in images]
+    def _store_forms(self, images: list[bytes]) -> tuple[list[bytes], list[int]]:
+        """Batched `_store_form`, as (stored forms, clens): compressors
+        exposing `compress_batch` (the device plugin) get ONE call for
+        the whole block range so their transforms coalesce into shared
+        offload launches; the required-ratio gate is applied per block
+        exactly as in the scalar path."""
+        if not images or self._compressor.name == "none":
+            return images, [0] * len(images)
         batch = getattr(self._compressor, "compress_batch", None)
         if batch is not None:
             comps = batch(images)
         else:
             comps = [self._compressor.compress(img) for img in images]
         limit = int(BLOCK * self._required_ratio)
-        return [
-            (comp, len(comp)) if len(comp) <= limit else (img, 0)
-            for img, comp in zip(images, comps)
-        ]
+        stored, clens = [], []
+        for img, comp in zip(images, comps):
+            small = len(comp) <= limit
+            stored.append(comp if small else img)
+            clens.append(len(comp) if small else 0)
+        return stored, clens
 
     def _csum_batch(self, stored: list[bytes]) -> list[int]:
         """crc32c over a batch of stored forms — one offload-runtime
@@ -463,6 +466,18 @@ class BlueStore(ObjectStore):
     def _touch(self, coll: str, oid: str) -> None:
         self._get_onode(coll, oid, create=True)
 
+    def _stored_bytes(self, poff: int, clen: int) -> bytes:
+        """The stored form in the slot at `poff`, staged or on the block
+        file, unverified.  `_block_read` returns at most the requested
+        bytes: a short raw read (lazily-grown file) zero-pads, a short
+        compressed read is caught by the caller's crc or compare."""
+        stored = self._staged.get(poff)
+        if stored is None:
+            stored = self._block_read(poff, clen or BLOCK)
+            if not clen and len(stored) < BLOCK:
+                stored = stored + b"\x00" * (BLOCK - len(stored))  # lazy file
+        return stored
+
     def _logical_block(self, o: Onode, bidx: int) -> bytes:
         """Stored content of logical block `bidx`, crc-verified; zeros for
         holes.  Bytes beyond o.size are NOT masked here (callers clamp)."""
@@ -470,14 +485,7 @@ class BlueStore(ObjectStore):
         if ent is None:
             return b"\x00" * BLOCK
         poff, crc, clen = ent
-        stored = self._staged.get(poff)
-        if stored is None:
-            # _block_read returns at most the requested bytes; a short raw
-            # read (lazily-grown file) zero-pads, a short compressed read
-            # is caught by the crc below
-            stored = self._block_read(poff, clen or BLOCK)
-            if not clen and len(stored) < BLOCK:
-                stored = stored + b"\x00" * (BLOCK - len(stored))  # lazy file
+        stored = self._stored_bytes(poff, clen)
         # csum covers the STORED bytes (compressed or raw), so corruption
         # is caught before decompression can amplify it
         if crc32c(stored) != crc:
@@ -486,104 +494,140 @@ class BlueStore(ObjectStore):
             return self._compressor.decompress(stored)
         return stored
 
-    def _valid_blocks(self, o: Onode, b_first: int, b_last: int) -> list[bytes]:
-        """Block contents with bytes at logical offsets >= size zeroed —
-        the overlay source for read-modify-write.  The blocks holding
-        bytes below the size are verified with one host crc pass."""
-        live = min(b_last, (o.size - 1) // BLOCK)
-        out = []
-        if live >= b_first:
-            out = self._logical_blocks(o, b_first, live, csum=crc32c_blocks)
-            tail = o.size - live * BLOCK
-            if tail < BLOCK:
-                out[-1] = out[-1][:tail] + b"\x00" * (BLOCK - tail)
-        return out + [b"\x00" * BLOCK] * (b_last - b_first + 1 - len(out))
+    def _valid_block(self, o: Onode, b: int) -> bytes:
+        """Block `b`'s content with bytes at logical offsets >= size
+        zeroed — the overlay source for read-modify-write, verified with
+        the host crc."""
+        live = o.size - b * BLOCK
+        if live <= 0:
+            return b"\x00" * BLOCK
+        image = self._logical_blocks(o, b, b, csum=crc32c_blocks)[0]
+        return image if live >= BLOCK else image[:live] + b"\x00" * (BLOCK - live)
 
     def _write(
         self, coll: str, oid: str, off: int, data: bytes, csums=None
     ) -> None:
-        """`csums` (EC-transaction fusion): per-BLOCK crc32c of `data`,
+        """A block the write covers entirely takes its image straight
+        from `data`, with no read-back; only a partial head or tail block
+        is read, verified and overlaid (read-modify-write).
+
+        `csums` (EC-transaction fusion): per-BLOCK crc32c of `data`,
         precomputed in the encode's offload launch window — an AggTicket
         or uint32 array, trusted only for block-aligned writes whose
         stored form stays raw (stored bytes == image bytes)."""
         if not data:
             self._get_onode(coll, oid, create=True)
             return
+        if not isinstance(data, bytes):
+            # a decoded transaction may carry a memoryview; whole-block
+            # images are slices of `data`, and the crc32c binding takes bytes
+            data = bytes(data)
         o = self._get_onode(coll, oid, create=True)
-        b0, b1 = off // BLOCK, (off + len(data) - 1) // BLOCK
-        # Assemble full-block images for the affected range, keeping the
-        # pre-overlay content of live blocks for the unchanged-skip check.
-        images: dict[int, bytearray] = {}
-        orig: dict[int, bytes] = {}
-        for b, prev in zip(range(b0, b1 + 1), self._valid_blocks(o, b0, b1)):
-            if b in o.blocks:
-                orig[b] = prev
-            images[b] = bytearray(prev)
-        cur = off
-        dpos = 0
-        while dpos < len(data):
-            b = cur // BLOCK
-            boff = cur % BLOCK
-            n = min(BLOCK - boff, len(data) - dpos)
-            images[b][boff : boff + n] = data[dpos : dpos + n]
-            cur += n
-            dpos += n
+        blocks = o.blocks
+        end = off + len(data)
+        b0, b1 = off // BLOCK, (end - 1) // BLOCK
+        head, tail = off % BLOCK != 0, end % BLOCK != 0
+        whole = range(b0 + head, b1 + 1 - tail)
+        todo = list(whole)
+        images = [
+            data[s : s + BLOCK]
+            for s in range(whole.start * BLOCK - off, whole.stop * BLOCK - off, BLOCK)
+        ]
         # Identical-content overwrite: a live block entirely below the
         # current size whose image is unchanged keeps its stored form,
-        # csum, and physical slot — nothing to recompute or rewrite.
-        # (Blocks straddling o.size are never skipped: their stored tail
-        # bytes may be stale, and a size extension would expose them.)
-        skip = {
-            b
-            for b in images
-            if b in orig
-            and (b + 1) * BLOCK <= o.size
-            and bytes(images[b]) == orig[b]
-        }
-        self.csum_compute_skips += len(skip)
-        todo = [b for b in sorted(images) if b not in skip]
-        all_mapped = all(b in o.blocks for b in images)
+        # csum, and physical slot — nothing to rewrite.  (Blocks
+        # straddling o.size are never skipped: their stored tail bytes
+        # may be stale, and a size extension would expose them.)
+        skips = 0
+        partial = sorted({b for b, cut in ((b0, head), (b1, tail)) if cut})
+        for b in partial:
+            prev = self._valid_block(o, b)
+            lo, hi = max(off, b * BLOCK), min(end, (b + 1) * BLOCK)
+            image = bytearray(prev)
+            image[lo - b * BLOCK : hi - b * BLOCK] = data[lo - off : hi - off]
+            if b in blocks and (b + 1) * BLOCK <= o.size and image == prev:
+                skips += 1
+                continue
+            at = 0 if b < whole.start else len(todo)
+            todo.insert(at, b)
+            images.insert(at, bytes(image))
         # One batched store-form + one batched csum pass for the whole
         # range (the device compressor / csum service coalesce these
         # into shared offload launches when armed).
-        forms = self._store_forms([bytes(images[b]) for b in todo])
-        crcs = [0] * len(todo)
+        stored, clens = self._store_forms(images)
         pre = None
-        if csums is not None and off % BLOCK == 0 and len(data) % BLOCK == 0:
+        if csums is not None and not head and not tail:
             pre = csums.result() if hasattr(csums, "result") else csums
-        need = []
+        if pre is None:
+            crcs = self._csum_batch(stored)
+        else:
+            # raw-stored fully-overwritten blocks: the fused digest
+            # covers exactly the stored bytes
+            crcs = [int(pre[b - b0]) for b in todo]
+            need = [i for i, clen in enumerate(clens) if clen]
+            if need:
+                for i, dig in zip(need, self._csum_batch([stored[i] for i in need])):
+                    crcs[i] = dig
+        # A whole block skips only if its stored copy is byte-equal to
+        # the new stored form; the digest and stored length pick the
+        # candidates to read back.  A stored copy that no longer matches
+        # its own crc differs from the new form, so it is rewritten.
+        candidates = 0
+        keep = []
         for i, b in enumerate(todo):
-            if pre is not None and forms[i][1] == 0:
-                # raw-stored fully-overwritten block: the fused digest
-                # covers exactly the stored bytes
-                crcs[i] = int(pre[b - b0])
-                self.csum_fused_blocks += 1
-            else:
-                need.append(i)
-        if need:
-            digs = self._csum_batch([forms[i][0] for i in need])
-            for i, dig in zip(need, digs):
-                crcs[i] = dig
-        if all_mapped and len(data) <= DEFERRED_MAX:
+            ent = blocks.get(b)
+            if (
+                ent is not None
+                and ent[1] == crcs[i]
+                and ent[2] == clens[i]
+                and b in whole
+                and (b + 1) * BLOCK <= o.size
+            ):
+                candidates += 1
+                if self._stored_bytes(ent[0], ent[2]) == stored[i]:
+                    skips += 1
+                    continue
+            keep.append(i)
+        self.csum_compute_skips += skips
+        if pre is not None:
+            self.csum_fused_blocks += sum(1 for i in keep if not clens[i])
+        if whole:
+            count_section("store.write.whole_blocks", nbytes=len(whole) * BLOCK)
+        if partial or candidates:
+            count_section(
+                "store.write.readback_blocks",
+                nbytes=(len(partial) + candidates) * BLOCK,
+            )
+        if len(data) <= DEFERRED_MAX and all(b in blocks for b in range(b0, b1 + 1)):
             # deferred WAL overwrite in place
-            for i, b in enumerate(todo):
-                poff = o.blocks[b][0]
-                stored, clen = forms[i]
-                o.blocks[b] = (poff, crcs[i], clen)
-                self._deferred.append((poff, stored))
-                self._staged[poff] = stored
+            for i in keep:
+                b = todo[i]
+                poff = blocks[b][0]
+                blocks[b] = (poff, crcs[i], clens[i])
+                self._deferred.append((poff, stored[i]))
+                self._staged[poff] = stored[i]
         else:
             # COW: fresh blocks for the (non-skipped) affected range
-            newblocks = self._ensure_capacity(len(todo))
-            for i, (b, nb) in enumerate(zip(todo, newblocks)):
-                old = o.blocks.get(b)
+            newblocks = self._ensure_capacity(len(keep))
+            for i, nb in zip(keep, newblocks):
+                b = todo[i]
+                old = blocks.get(b)
                 if old is not None:
                     self._to_release.append(old[0] // BLOCK)
-                stored, clen = forms[i]
-                o.blocks[b] = (nb * BLOCK, crcs[i], clen)
-                self._direct.append((nb * BLOCK, stored))
-                self._staged[nb * BLOCK] = stored
-        o.size = max(o.size, off + len(data))
+                blocks[b] = (nb * BLOCK, crcs[i], clens[i])
+                self._staged[nb * BLOCK] = stored[i]
+            if keep:
+                # one block-file write per physically contiguous run; a
+                # compressed form is shorter than its slot, so it ends a run
+                kept = [stored[i] for i in keep]
+                cuts = [
+                    j
+                    for j in range(1, len(keep))
+                    if newblocks[j] != newblocks[j - 1] + 1 or len(kept[j - 1]) != BLOCK
+                ]
+                for lo, hi in zip([0, *cuts], [*cuts, len(keep)]):
+                    self._direct.append((newblocks[lo] * BLOCK, b"".join(kept[lo:hi])))
+        o.size = max(o.size, end)
 
     def _apply_op(self, op) -> None:
         # thread the fused-csum hint through to _write; every other op

@@ -8,9 +8,12 @@ store matrix runs in test_objectstore.py.
 
 import asyncio
 import os
+import random
+import threading
 
 import pytest
 
+from ceph_tpu.common import tracer
 from ceph_tpu.os import BlueStore, StoreError, Transaction, make_store
 from ceph_tpu.os.bluestore import BLOCK, DEFERRED_MAX, INITIAL_BLOCKS, SimulatedCrash
 
@@ -182,6 +185,167 @@ class TestWritePaths:
         assert data[:1000] == b"Z" * 1000
         assert data[1000:2000] == b"\x00" * 1000
         assert data[2000:] == b"E" * 100
+        s.umount()
+
+
+def _payload(seed, n):
+    return random.Random(seed).randbytes(n)
+
+
+def _corrupt(path, poff):
+    with open(path / "block", "r+b") as f:
+        f.seek(poff + 17)
+        byte = f.read(1)
+        f.seek(poff + 17)
+        f.write(bytes([byte[0] ^ 0xFF]))
+
+
+def _layer_bytes(name):
+    return tracer.layer_totals(threading.get_ident()).get(name, {}).get("bytes", 0)
+
+
+class TestWholeBlockWrites:
+    """Blocks a write covers entirely are written from the payload with
+    no read-back; only partial head and tail blocks read-modify-write."""
+
+    # 4 blocks over mapped ones ride the deferred WAL; 20 go COW
+    PATHS = pytest.mark.parametrize("nblocks,cow", [(4, False), (20, True)],
+                                    ids=["deferred", "cow"])
+
+    @PATHS
+    def test_aligned_overwrite_reads_no_block_back(self, tmp_path, nblocks, cow):
+        s = mk(tmp_path / "b")
+        s.queue_transaction(Transaction().create_collection("c"))
+        s.queue_transaction(
+            Transaction().write("c", "o", 0, _payload(1, 24 * BLOCK)))
+        before = {b: e[0] for b, e in s._peek_onode("c", "o").blocks.items()}
+        reads = []
+        real_read = s._block_read
+        s._block_read = lambda poff, n: reads.append(poff) or real_read(poff, n)
+        new = _payload(2, nblocks * BLOCK)
+        s.queue_transaction(Transaction().write("c", "o", 4 * BLOCK, new))
+        assert reads == []
+        s._block_read = real_read
+        after = {b: e[0] for b, e in s._peek_onode("c", "o").blocks.items()}
+        moved = [b for b in before if before[b] != after[b]]
+        assert moved == (list(range(4, 4 + nblocks)) if cow else [])
+        want = _payload(1, 24 * BLOCK)
+        want = want[: 4 * BLOCK] + new + want[(4 + nblocks) * BLOCK:]
+        assert s.read("c", "o") == want
+        s.umount()
+        s2 = mk(tmp_path / "b")
+        assert s2.read("c", "o") == want
+        s2.umount()
+
+    @PATHS
+    def test_unaligned_write_keeps_surrounding_bytes(self, tmp_path, nblocks, cow):
+        """Partial head, whole middle, partial tail: the bytes around the
+        write survive on both paths."""
+        s = mk(tmp_path / "b")
+        s.queue_transaction(Transaction().create_collection("c"))
+        old = _payload(3, 24 * BLOCK)
+        s.queue_transaction(Transaction().write("c", "o", 0, old))
+        off = 3 * BLOCK + 100
+        new = _payload(4, nblocks * BLOCK)  # ends 100 B into its last block
+        before = {b: e[0] for b, e in s._peek_onode("c", "o").blocks.items()}
+        s.queue_transaction(Transaction().write("c", "o", off, new))
+        after = {b: e[0] for b, e in s._peek_onode("c", "o").blocks.items()}
+        moved = [b for b in before if before[b] != after[b]]
+        assert moved == (list(range(3, 4 + nblocks)) if cow else [])
+        want = old[:off] + new + old[off + len(new):]
+        assert s.read("c", "o") == want
+        s.umount()
+        s2 = mk(tmp_path / "b")
+        assert s2.read("c", "o") == want
+        s2.umount()
+
+    @pytest.mark.parametrize("same", [False, True], ids=["new-bytes", "same-bytes"])
+    @PATHS
+    def test_whole_block_overwrite_over_corrupt_block(
+        self, tmp_path, nblocks, cow, same
+    ):
+        """A whole-block overwrite never reads the old block, so a copy
+        rotted on disk does not fail it.  With the same bytes the rotted
+        block is still a skip candidate (its digest matches): its stored
+        copy no longer compares equal, so it is rewritten, not skipped."""
+        s = mk(tmp_path / "b")
+        s.queue_transaction(Transaction().create_collection("c"))
+        old = _payload(5, nblocks * BLOCK)
+        s.queue_transaction(Transaction().write("c", "o", 0, old))
+        poff = s._peek_onode("c", "o").blocks[1][0]
+        s.umount()
+        _corrupt(tmp_path / "b", poff)
+        s = mk(tmp_path / "b")
+        with pytest.raises(StoreError):
+            s.read("c", "o")
+        new = old if same else _payload(6, nblocks * BLOCK)
+        skips0 = s.csum_compute_skips
+        s.queue_transaction(Transaction().write("c", "o", 0, new))
+        assert s.csum_compute_skips - skips0 == (nblocks - 1 if same else 0)
+        assert s.read("c", "o") == new
+        s.umount()
+        s2 = mk(tmp_path / "b")
+        assert s2.read("c", "o") == new
+        s2.umount()
+
+    def test_cow_run_of_fresh_blocks_is_one_block_file_write(self, tmp_path):
+        s = mk(tmp_path / "b")
+        s.queue_transaction(Transaction().create_collection("c"))
+        writes = []
+        real_write = s._block_write
+        s._block_write = lambda poff, d: writes.append((poff, len(d))) or real_write(poff, d)
+        data = _payload(7, 30 * BLOCK + 5)
+        s.queue_transaction(Transaction().write("c", "o", 0, data))
+        blocks = s._peek_onode("c", "o").blocks
+        assert writes == [(blocks[0][0], 31 * BLOCK)]
+        assert [blocks[b][0] for b in range(31)] == [
+            blocks[0][0] + b * BLOCK for b in range(31)
+        ]
+        assert s.read("c", "o") == data
+        s.umount()
+
+    def test_cow_runs_end_at_compressed_blocks(self, tmp_path):
+        """A compressed form is shorter than its slot: the run it is in
+        ends with it, and every block reads back after a remount."""
+        s = BlueStore(str(tmp_path / "b"), compression="zlib")
+        s.mount()
+        s.queue_transaction(Transaction().create_collection("c"))
+        writes = []
+        real_write = s._block_write
+        s._block_write = lambda poff, d: writes.append(poff) or real_write(poff, d)
+        # random (stored raw) and zero (stored compressed) blocks in turn
+        data = b"".join(
+            _payload(b, BLOCK) if b % 2 == 0 else bytes(BLOCK) for b in range(20)
+        )
+        s.queue_transaction(Transaction().write("c", "o", 0, data))
+        clens = [s._peek_onode("c", "o").blocks[b][2] for b in range(20)]
+        assert [c > 0 for c in clens] == [b % 2 == 1 for b in range(20)]
+        assert len(writes) == 10
+        s.umount()
+        s2 = BlueStore(str(tmp_path / "b"), compression="zlib")
+        s2.mount()
+        assert s2.read("c", "o") == data
+        s2.umount()
+
+    @pytest.mark.parametrize(
+        "off,length,whole,readback",
+        [(0, 128 * BLOCK, 128 * BLOCK, 0), (0, BLOCK + 100, BLOCK, BLOCK)],
+        ids=["512KiB-aligned", "4KiB+100B"],
+    )
+    def test_engagement_counters(self, off, length, whole, readback):
+        s = BlueStore()
+        s.mount()
+        s.queue_transaction(Transaction().create_collection("c"))
+        s.queue_transaction(
+            Transaction().write("c", "o", 0, _payload(8, 128 * BLOCK)))
+        w0 = _layer_bytes("store.write.whole_blocks")
+        r0 = _layer_bytes("store.write.readback_blocks")
+        s.queue_transaction(
+            Transaction().write("c", "o", off, _payload(9, length)))
+        totals = tracer.layer_totals(threading.get_ident())
+        assert "store.write.whole_blocks" in totals
+        assert _layer_bytes("store.write.whole_blocks") - w0 == whole
+        assert _layer_bytes("store.write.readback_blocks") - r0 == readback
         s.umount()
 
 

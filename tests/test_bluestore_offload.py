@@ -120,14 +120,16 @@ class TestOffloadWriteRead:
 
 
 class TestCsumSkip:
-    def test_identical_overwrite_skips_recompute(self):
+    # 4 blocks take the deferred path, 20 (over DEFERRED_MAX) the COW path
+    @pytest.mark.parametrize("nblocks", [4, 20])
+    def test_identical_overwrite_skips_recompute(self, nblocks):
         s = mko()
-        data = os.urandom(4 * BLOCK)
+        data = os.urandom(nblocks * BLOCK)
         s.queue_transaction(Transaction().write("c", "o", 0, data))
         skips0 = s.csum_compute_skips
         # same content again: every whole block below size skips
         s.queue_transaction(Transaction().write("c", "o", 0, data))
-        assert s.csum_compute_skips == skips0 + 4
+        assert s.csum_compute_skips == skips0 + nblocks
         assert s.read("c", "o") == data
         s.umount()
 
