@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import gc
+import importlib
 import json
 import os
 import sys
@@ -28,6 +29,13 @@ class NoDevice(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell asks for."""
 
 
+# The erasure-code profile keys every configuration states; its optional
+# "profile" object adds others (d, scalar_mds, technique, l, ...).
+PROFILE_KEYS = ("k", "m", "plugin", "crush-failure-domain", "stripe_unit")
+REFERENCE_API = ("chunk_count", "stripe_width", "object_shards", "encode_bytes",
+                 "decode_bytes")
+
+
 @dataclass
 class Cell:
     name: str
@@ -36,6 +44,7 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    ref: object  # the configuration's shard reference module (perfbench/reference.py)
 
 
 def load_json(path: Path) -> dict:
@@ -58,7 +67,38 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
         return [m for m in metrics if name in m.get("workloads", [name])]
 
     return Cell(name, int(entry["chips"]), cfg, traffic,
-                mine(bench["end_to_end"]), mine(bench["per_layer"]))
+                mine(bench["end_to_end"]), mine(bench["per_layer"]),
+                shard_reference(conf["name"], cfg))
+
+
+def shard_reference(config: str, cfg: dict):
+    """The configuration's shard reference module, `perfbench.<reference>`
+    (default `reference`, plain RS), checked to have the whole interface
+    of perfbench/reference.py; and its extra profile keys checked not to
+    restate the five every configuration gives."""
+    profile = cfg.get("profile", {})
+    if not isinstance(profile, dict):
+        raise ValueError(f"config {config!r}: \"profile\" is not an object")
+    again = [key for key in profile if key in PROFILE_KEYS]
+    if again:
+        raise ValueError(f"config {config!r}: \"profile\" repeats {again}, "
+                         f"which the configuration states as {PROFILE_KEYS}")
+    name = cfg.get("reference", "reference")
+    try:
+        ref = importlib.import_module(f"perfbench.{name}")
+    except ImportError as e:
+        raise ValueError(f"config {config!r}: reference {name!r}: {e}") from e
+    missing = [f for f in REFERENCE_API if not callable(getattr(ref, f, None))]
+    if missing:
+        raise ValueError(f"config {config!r}: reference {name!r} lacks {missing}")
+    return ref
+
+
+def profile_args(cfg: dict) -> list[str]:
+    """`key=value` of the erasure-code profile: the five keys, then the
+    configuration's "profile" object in file order."""
+    return [f"{key}={cfg[key]}" for key in PROFILE_KEYS] + [
+        f"{key}={value}" for key, value in cfg.get("profile", {}).items()]
 
 
 def process_age_s() -> float:
@@ -182,9 +222,7 @@ async def start_cluster(cfg: dict):
     rv, rs, _ = await client.mon_command({
         "prefix": "osd erasure-code-profile set",
         "name": f"{cfg['name']}_profile",
-        "profile": [f"k={cfg['k']}", f"m={cfg['m']}", f"plugin={cfg['plugin']}",
-                    f"crush-failure-domain={cfg['crush-failure-domain']}",
-                    f"stripe_unit={cfg['stripe_unit']}"],
+        "profile": profile_args(cfg),
     })
     if rv != 0:
         raise RuntimeError(f"erasure-code profile: {rs}")
@@ -309,8 +347,8 @@ async def run_cell_async(cell: Cell, seed: int, seconds: float, trace: bool,
             run.trace = tracer.summary()
         run.work = work.window_work(cell, run.ops, run.counters)
         progress("checking")
-        run.compared = await verify.check(cluster, cfg, tr, gen, run, start,
-                                             io.pool_id)
+        run.compared = await verify.check(cluster, cell.ref, cfg, tr, gen, run,
+                                          start, io.pool_id)
     finally:
         await client.shutdown()
         await cluster.stop()

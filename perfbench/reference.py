@@ -5,6 +5,22 @@ polynomial x^8+x^4+x^3+x^2+1) and nothing of `ceph_tpu.codec` or
 `ceph_tpu.gf`: the same operations on the same bytes give the same
 shards, so a shard on an OSD's store that differs from `encode` here is
 a wrong result of the program.
+
+This is also the default shard reference of a configuration.  A
+configuration names its own with `"reference": "<module>"`, a module of
+the `perfbench` package (`tests.cauchy_reference` is
+`perfbench/tests/cauchy_reference.py`); the harness resolves it once, at
+`load_cell`.  A reference module imports nothing of `ceph_tpu` and
+takes nothing the program has made.  It exposes these functions of the
+configuration dict `cfg`:
+
+- `chunk_count(cfg)`: the shards the pool stores for one object;
+- `stripe_width(cfg)`: the user bytes of one stripe;
+- `object_shards(payload, cfg) -> list[bytes]`: every shard, in shard
+  order, that the pool stores for `payload`;
+- `encode_bytes(cfg, stripes)`, `decode_bytes(cfg, stripes, erasures)`:
+  the bytes a coding kernel has to move to encode `stripes` whole
+  stripes, or to rebuild `erasures` chunks of each (perfbench/work.py).
 """
 
 from __future__ import annotations
@@ -63,15 +79,39 @@ def encode(data: np.ndarray, rows: np.ndarray) -> np.ndarray:
     return out
 
 
-def object_shards(payload: bytes, k: int, m: int, stripe_unit: int) -> list[bytes]:
-    """The k+m shard objects an RS(k, m) pool stores for `payload`: the
-    object zero-padded to whole stripes of k * stripe_unit bytes, and
-    shard s the concatenation of chunk s of every stripe."""
+def systematic_shards(payload: bytes, cfg: dict, rows: np.ndarray) -> list[bytes]:
+    """The k+m shard objects of a systematic code with parity `rows`:
+    the object zero-padded to whole stripes of k * stripe_unit bytes,
+    and shard s the concatenation of chunk s of every stripe."""
+    k, m, stripe_unit = cfg["k"], cfg["m"], cfg["stripe_unit"]
     width = k * stripe_unit
     stripes = -(-len(payload) // width)
     buf = np.zeros(stripes * width, np.uint8)
     buf[: len(payload)] = np.frombuffer(payload, np.uint8)
     data = buf.reshape(stripes, k, stripe_unit)
-    parity = encode(data, vandermonde_rows(k, m))
+    parity = encode(data, rows)
     full = np.concatenate([data, parity], axis=1)
     return [full[:, s].tobytes() for s in range(k + m)]
+
+
+def object_shards(payload: bytes, cfg: dict) -> list[bytes]:
+    """The k+m shard objects an RS(k, m) pool stores for `payload`."""
+    return systematic_shards(payload, cfg, vandermonde_rows(cfg["k"], cfg["m"]))
+
+
+def chunk_count(cfg: dict) -> int:
+    return cfg["k"] + cfg["m"]
+
+
+def stripe_width(cfg: dict) -> int:
+    return cfg["k"] * cfg["stripe_unit"]
+
+
+def encode_bytes(cfg: dict, stripes: int) -> int:
+    """A full-stripe encode reads k chunks and writes m: (k+m) chunks."""
+    return stripes * (cfg["k"] + cfg["m"]) * cfg["stripe_unit"]
+
+
+def decode_bytes(cfg: dict, stripes: int, erasures: int) -> int:
+    """A decode reads k surviving chunks and writes the rebuilt ones."""
+    return stripes * (cfg["k"] + erasures) * cfg["stripe_unit"]

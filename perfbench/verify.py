@@ -6,7 +6,9 @@
   write left it;
 - ECBackend, codec, kernel and store: every shard of those sampled
   objects, read straight off each OSD's store (stopped OSDs included),
-  equals the plain RS(k,m) encoding of the object (`reference.py`);
+  equals the plain encoding of the object by the configuration's shard
+  reference (`reference.py`, plain RS(k,m), unless the configuration
+  names another);
 - device: the window launched on the device, nothing fell back to the
   host oracle, and the device guard never went DEGRADED.
 
@@ -19,8 +21,6 @@ from __future__ import annotations
 import re
 
 import numpy as np
-
-from perfbench import reference
 
 
 def shard_copies(cluster, pool_id: int, oid: str) -> dict[int, list[bytes]]:
@@ -52,13 +52,14 @@ def lost_shards(cluster, pool_id: int, victims: list[int], oids) -> dict[str, in
     return out
 
 
-def shard_mismatches(cfg: dict, copies: dict[int, list[bytes]], payload: bytes) -> int:
-    """Shards missing or differing from the reference encoding."""
-    want = reference.object_shards(payload, cfg["k"], cfg["m"], cfg["stripe_unit"])
+def shard_mismatches(ref, cfg: dict, copies: dict[int, list[bytes]],
+                     payload: bytes) -> int:
+    """Shards missing or differing from the encoding by reference `ref`."""
+    want = ref.object_shards(payload, cfg)
     bad = 0
-    for s, ref in enumerate(want):
+    for s, expect in enumerate(want):
         have = copies.get(s, [])
-        bad += (not have) + sum(c != ref for c in have)
+        bad += (not have) + sum(c != expect for c in have)
     return bad
 
 
@@ -76,7 +77,8 @@ async def differs(read, want: bytes) -> bool:
         return True
 
 
-async def check(cluster, cfg: dict, tr: dict, gen, run, start: dict, pool_id: int) -> dict:
+async def check(cluster, ref, cfg: dict, tr: dict, gen, run, start: dict,
+                pool_id: int) -> dict:
     from perfbench.harness import delta, launch_totals
 
     n = int(tr["check_objects"])
@@ -89,7 +91,7 @@ async def check(cluster, cfg: dict, tr: dict, gen, run, start: dict, pool_id: in
             want = gen.expected_range(objno * obj, obj)
             read_bad += await differs(gen.image.read(objno * obj, obj), want)
             shard_bad += shard_mismatches(
-                cfg, shard_copies(cluster, pool_id, names[objno]), want)
+                ref, cfg, shard_copies(cluster, pool_id, names[objno]), want)
             checked += 1
     else:
         touched = sorted({o.target for o in run.ops if o.ok} & set(gen.state))
@@ -97,7 +99,7 @@ async def check(cluster, cfg: dict, tr: dict, gen, run, start: dict, pool_id: in
             want = gen.expected_object(t)
             read_bad += await differs(gen.io.read(gen.oid(t)), want)
             shard_bad += shard_mismatches(
-                cfg, shard_copies(cluster, pool_id, gen.oid(t)), want)
+                ref, cfg, shard_copies(cluster, pool_id, gen.oid(t)), want)
             checked += 1
     dev = delta(launch_totals(), start)
     run.notes["objects_checked"] = checked
