@@ -9,14 +9,16 @@ U by pairwise 2x2 GF transforms across the grid, and each plane of U is a
 codeword of an inner scalar MDS code (ErasureCodeClay.cc:271-296 for the
 geometry; :645-739 for layered decoding; :462-642 for single-chunk repair).
 
-TPU-first re-design (not a loop-for-loop translation): chunks live as one
-(q*t, q^t, sc) tensor; the pairwise coupling transforms are *batched* —
-vectorized gathers build (pairs, sc) arrays and the 2x2 GF multiplies are
-table lookups over whole batches — and each round of layered decoding runs
-the inner MDS decode for *all planes of equal intersection score in one
-bitsliced XOR-matmul launch* (planes are the batch axis).  The sequential
-structure that remains (rounds ordered by intersection score, <= m+1 of
-them) is inherent to the code, not an implementation artifact.
+The served path is the stripe-batch interface (`encode_array`,
+`decode_array`, `decode_fragments_batch`): one device program per batch
+of stripes, planned per erasure pattern (ops/clay_gf.py), with the numpy
+run of that program as the offload runtime's host oracle.  The
+chunk-level methods below (`encode_chunks`, `decode_chunks`, `_repair`)
+couple on the host and stay as the independent oracle the tests hold
+the programs to: chunks live as one (q*t, q^t, sc) tensor, the pairwise
+transforms are batched table lookups, and each round of layered decoding
+runs the inner MDS decode for all planes of one intersection score in
+one launch.  Every stripe they code counts as a host fallback.
 
 Profile: k, m, d (default k+m-1), scalar_mds in {jerasure, isa, tpu}
 (default jerasure), technique per inner plugin.  The reference also accepts
@@ -30,11 +32,21 @@ from typing import Mapping
 
 import numpy as np
 
+from ceph_tpu.common.tracer import section
 from ceph_tpu.gf import GF_MUL_TABLE, gf_inv, gf_invert_matrix
+from ceph_tpu.ops import clay_gf
+from ceph_tpu.ops.dispatch import CLAY, record_fallback
 
 from .base import EINVAL, EIO, ErasureCode
 from .interface import EcError, Profile
 from .matrix_codec import PLAN_CACHE
+
+# The chunk of one byte of the (2, 2) pairwise-transform code in the
+# reference's scalar plugins: 32 for ISA-L (EC_ISA_ADDRESS_ALIGNMENT) and
+# for jerasure w=8 (k * w * sizeof(int) / k).  ErasureCodeClay aligns
+# objects to sub_chunk_no * k of it (ErasureCodeClay.cc get_chunk_size),
+# so a sub-chunk is a multiple of 32 bytes.
+SCALAR_CHUNK_ALIGNMENT = 32
 
 
 def _gf_scale(c: int, arr: np.ndarray) -> np.ndarray:
@@ -114,6 +126,12 @@ class ErasureCodeClay(ErasureCode):
         assert self._pft_inv is not None
         assert (self._pft != 0).all(), "pairwise transform needs nonzero entries"
         self._plane_digits = self._compute_plane_digits()
+        self._geometry = clay_gf.ClayGeometry(
+            self.k, self.m, self.nu, self.q, self.t,
+            P=tuple(tuple(int(v) for v in row) for row in self._pft),
+            dist=tuple(tuple(int(v) for v in row)
+                       for row in self._inner.distribution_matrix()),
+        )
 
     def init(self, profile: Profile) -> None:
         self.parse(profile)
@@ -139,11 +157,63 @@ class ErasureCodeClay(ErasureCode):
         return self.sub_chunk_no
 
     def get_chunk_size(self, object_size: int) -> int:
-        """round_up(object, sub_chunk_no * k * inner_alignment) / k
-        (ErasureCodeClay.cc:90-96)."""
-        alignment = self.sub_chunk_no * self.k * self._inner.get_chunk_size(1)
+        """round_up(object, sub_chunk_no * k * scalar alignment) / k
+        (ErasureCodeClay.cc:90-96), the scalar alignment being the
+        pairwise-transform code's chunk of one byte: a stripe unit of
+        4096 passes for (8, 4, d=11), 64 planes of 64 bytes."""
+        alignment = self.sub_chunk_no * self.k * SCALAR_CHUNK_ALIGNMENT
         padded = -(-object_size // alignment) * alignment
         return padded // self.k
+
+    # -- stripe-batch device interface (ops/clay_gf.py) ---------------------
+    #
+    # The same surface the matrix codecs give the stripe layer and the
+    # offload runtime: (S, n, chunk) batches in, one device program per
+    # batch, a numpy run of that same program as the host oracle.
+
+    def encode_key(self) -> tuple:
+        """What groups encode launches in the offload runtime."""
+        return ("clay", self._geometry)
+
+    def decode_key(self, erasures: list[int]) -> tuple:
+        return ("clay", self._geometry, tuple(erasures))
+
+    def _encode_pattern(self) -> "clay_gf.Pattern":
+        with section("ec.clay"):
+            return clay_gf.pattern("encode", self._geometry)
+
+    def _decode_pattern(self, erasures: list[int]) -> "clay_gf.Pattern":
+        if len(erasures) > self.m:
+            raise EcError(EIO, f"{len(erasures)} erasures > m={self.m}")
+        with section("ec.clay"):
+            grid = tuple(self._ext(i) for i in erasures)
+            return clay_gf.pattern("decode", self._geometry, tuple(sorted(grid)), grid)
+
+    def decode_index(self, erasures: list[int]) -> list[int]:
+        """The k stored chunks a layered decode of `erasures` reads: every
+        chunk outside the erasures padded to m with parities."""
+        if len(erasures) > self.m:
+            raise EcError(EIO, f"{len(erasures)} erasures > m={self.m}")
+        lost = clay_gf.padded_erasures(self._geometry, [self._ext(i) for i in erasures])
+        return [i for i in range(self.k + self.m) if self._ext(i) not in lost]
+
+    def encode_array(self, data, out=None):
+        """(S, k, chunk) uint8 -> (S, m, chunk) parity, a live device
+        array: the clay_encode program over the whole batch."""
+        return clay_gf.launch(self._geometry, self._encode_pattern(), data, False)
+
+    def encode_array_host(self, data) -> np.ndarray:
+        return clay_gf.run_host(self._geometry, self._encode_pattern(), data)
+
+    def decode_array(self, erasures: list[int], survivors, out=None):
+        """survivors (S, k, chunk) in decode_index order -> (S,
+        len(erasures), chunk), a live device array (clay_decode)."""
+        return clay_gf.launch(self._geometry, self._decode_pattern(list(erasures)),
+                              survivors, True)
+
+    def decode_array_host(self, erasures: list[int], survivors) -> np.ndarray:
+        return clay_gf.run_host(self._geometry, self._decode_pattern(list(erasures)),
+                                survivors)
 
     # -- node/plane helpers --------------------------------------------------
 
@@ -307,8 +377,16 @@ class ErasureCodeClay(ErasureCode):
             )
         return C
 
+    @staticmethod
+    def _count_host(stripes: int, nbytes: int) -> None:
+        """A stripe coupled on the host is a host fallback of the device
+        path: counted where the launch gates look."""
+        CLAY.record_host(stripes)
+        record_fallback(stripes, nbytes)
+
     def encode_chunks(self, chunks: dict[int, np.ndarray]) -> None:
         chunk_size = len(chunks[0])
+        self._count_host(1, self.k * chunk_size)
         if chunk_size % self.sub_chunk_no:
             raise EcError(EINVAL, f"chunk size {chunk_size} not divisible by "
                                   f"sub_chunk_no {self.sub_chunk_no}")
@@ -330,6 +408,7 @@ class ErasureCodeClay(ErasureCode):
         if len(erasures_ext) > self.m:
             raise EcError(EIO, f"{len(erasures_ext)} erasures > m={self.m}")
         chunk_size = len(next(iter(chunks.values())))
+        self._count_host(1, len(chunks) * chunk_size)
         C = self._grid_arrays(chunks, chunk_size)
         erased_nodes = {self._ext(i) for i in erasures_ext}
         self._decode_layered(erased_nodes, C)
@@ -414,17 +493,31 @@ class ErasureCodeClay(ErasureCode):
         helper_chunks: Mapping[int, np.ndarray],
         chunk_size: int,
     ) -> dict[int, np.ndarray]:
-        """Vectorized repair across a whole shard's stripes at once.
+        """Single-chunk repair across a whole shard's stripes at once.
 
         Helper values are (stripes, fragment) uint8 arrays — stripe s's
         repair-plane fragment in row s — and the result maps the lost
-        chunk to a (stripes, chunk_size) array.  Each score round runs
-        ONE inner-MDS launch over (planes, stripes, k+nu, sc) instead of
-        the per-stripe Python loop the OSD recovery path used to drive
-        (ISSUE 5 tentpole: stripes are just another batch axis)."""
+        chunk to a (stripes, chunk_size) array.  One repair program
+        (ops/clay_gf.py, named `clay_decode` on the device) runs every
+        round over every stripe, under the device guard; if the device
+        fails, the host runs the same program."""
         if not self.is_repair(want_to_read, set(helper_chunks)):
             raise EcError(EIO, "fragment decode requires a repair-plan read")
-        return self._repair(want_to_read, helper_chunks, chunk_size)
+        if len(helper_chunks) != self.d:
+            raise EcError(EIO, f"repair needs d={self.d} helpers, "
+                               f"got {len(helper_chunks)}")
+        lost_ext = next(iter(want_to_read))
+        helpers = sorted(helper_chunks)
+        with section("ec.clay"):
+            pat = clay_gf.pattern("repair", self._geometry, self._ext(lost_ext),
+                                  tuple(self._ext(i) for i in helpers))
+            data = np.stack([np.asarray(helper_chunks[i], dtype=np.uint8)
+                             for i in helpers], axis=1)
+        if data.shape[-1] * self.q != chunk_size:
+            raise EcError(EINVAL, f"fragment of {data.shape[-1]} bytes does not "
+                                  f"hold the repair planes of a {chunk_size} chunk")
+        out = clay_gf.run_guarded(self._geometry, pat, data)
+        return {lost_ext: out[:, 0, :]}
 
     def _repair(
         self,
@@ -448,6 +541,9 @@ class ErasureCodeClay(ErasureCode):
         arbitrary leading batch dims, so the stripe axis rides along.
         """
         assert len(want_to_read) == 1 and len(helper_chunks) == self.d
+        first = np.asarray(next(iter(helper_chunks.values())))
+        self._count_host(first.shape[0] if first.ndim == 2 else 1,
+                         len(helper_chunks) * first.size)
         lost_ext = next(iter(want_to_read))
         lost = self._ext(lost_ext)
         qt = self.q * self.t

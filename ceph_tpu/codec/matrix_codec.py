@@ -465,9 +465,7 @@ class EncodeAggregator(LaunchAggregator):
 
     def submit(self, ec: "MatrixCodecMixin", shaped: np.ndarray) -> AggTicket:
         """Queue one (stripes, k, L) uint8 encode; returns its ticket."""
-        return self._submit(
-            (ec.distribution_matrix().tobytes(), shaped.shape[-1]), ec, None, shaped
-        )
+        return self._submit((ec.encode_key(), shaped.shape[-1]), ec, None, shaped)
 
     def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
         return g.ec.encode_array(data, out=donate)
@@ -513,9 +511,7 @@ class DecodeAggregator(LaunchAggregator):
         their decode-plan signature matches, so every ticket in a group
         agrees on the erasure row order."""
         erasures = list(erasures)
-        key = PLAN_CACHE._decode_key(
-            ec.distribution_matrix(), erasures, ec.k
-        ) + (survivors.shape[-1],)
+        key = ec.decode_key(erasures) + (survivors.shape[-1],)
         return self._submit(key, ec, tuple(erasures), survivors)
 
     def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
@@ -932,6 +928,14 @@ class MatrixCodecMixin:
     def decode_index(self, erasures: list[int]) -> list[int]:
         _, idx = PLAN_CACHE.decode_plan(self.distribution_matrix(), erasures, self.k)
         return idx
+
+    def encode_key(self) -> bytes:
+        """What groups encode launches in the offload runtime."""
+        return self.distribution_matrix().tobytes()
+
+    def decode_key(self, erasures: list[int]) -> tuple:
+        """What groups decode launches: the decode-plan signature."""
+        return PLAN_CACHE._decode_key(self.distribution_matrix(), erasures, self.k)
 
     # -- chunk-level interface ---------------------------------------------
 

@@ -300,6 +300,46 @@ def record_fallback(stripes: int, nbytes: int) -> None:
     FALLBACK_LAUNCHES.record(stripes, nbytes)
 
 
+class ClayCounters:
+    """CLAY coupled-layer coding (ops/clay_gf.py): device programs
+    launched for encode and for decode (layered decode and single-chunk
+    repair), the stripes and the layered rounds they carried, and the
+    stripes coupled on the host instead (the codec's chunk-level oracle
+    and the programs' numpy executor).  `host_stripes` stays 0 on the
+    served path; a launch that fell back to the host is also counted on
+    FALLBACK_LAUNCHES by the offload runtime."""
+
+    FIELDS = ("encode_launches", "decode_launches", "stripes", "rounds",
+              "host_stripes")
+
+    __slots__ = ("_lock", "_vals")
+
+    def __init__(self) -> None:
+        self._lock = make_lock("clay_counters")
+        self._vals = dict.fromkeys(self.FIELDS, 0)
+
+    def record_launch(self, decode: bool, stripes: int, rounds: int) -> None:
+        with self._lock:
+            self._vals["decode_launches" if decode else "encode_launches"] += 1
+            self._vals["stripes"] += int(stripes)
+            self._vals["rounds"] += int(rounds)
+
+    def record_host(self, stripes: int) -> None:
+        with self._lock:
+            self._vals["host_stripes"] += int(stripes)
+
+    def snapshot(self) -> dict[str, int]:
+        with self._lock:
+            return dict(self._vals)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._vals = dict.fromkeys(self.FIELDS, 0)
+
+
+CLAY = ClayCounters()
+
+
 def record_launch(
     stripes: int, nbytes: int, decode: bool = False, devices: int = 1,
     verify: bool = False,
@@ -362,6 +402,8 @@ def perf_dump() -> dict[str, object]:
     ):
         for name, val in counter.snapshot().items():
             out[f"{prefix}{name}"] = val
+    for name, val in CLAY.snapshot().items():
+        out[f"clay.{name}"] = val
     out["device_launches"] = DEVICES_PER_LAUNCH.device_launches
     for devices, launches in sorted(DEVICES_PER_LAUNCH.snapshot().items()):
         out[f"devices_per_launch.{devices}"] = launches

@@ -18,16 +18,26 @@ from typing import Mapping
 import numpy as np
 
 from ceph_tpu.codec.base import EINVAL, EIO
+from ceph_tpu.codec.clay import ErasureCodeClay
 from ceph_tpu.common.tracer import section
 from ceph_tpu.codec.interface import EcError, ErasureCodeInterface
 from ceph_tpu.codec.matrix_codec import MatrixCodecMixin
 
 
 def _matrix_fast_path(ec: ErasureCodeInterface) -> bool:
-    """Single-launch device path applies to matrix codecs whose raw chunk
-    order is the logical order (no `mapping=` remap); remapped codecs go
-    through their own chunk-level interface, which is mapping-aware."""
+    """The on-device RMW delta path applies to matrix codecs whose raw
+    chunk order is the logical order (no `mapping=` remap)."""
     return isinstance(ec, MatrixCodecMixin) and not ec.get_chunk_mapping()
+
+
+def _array_fast_path(ec: ErasureCodeInterface) -> bool:
+    """Single-launch device path: codecs with a stripe-batch device
+    interface (`encode_array`, `decode_array`, `decode_index`, their
+    host oracles and launch keys) — matrix codecs and CLAY — whose raw
+    chunk order is the logical order; remapped codecs go through their
+    own chunk-level interface, which is mapping-aware."""
+    return (isinstance(ec, (MatrixCodecMixin, ErasureCodeClay))
+            and not ec.get_chunk_mapping())
 
 
 class StripeInfo:
@@ -136,10 +146,10 @@ def encode_launch(
 ) -> PendingEncode:
     """Launch a batched stripe encode WITHOUT materializing the parity.
 
-    Matrix codecs dispatch one device launch and return immediately with a
-    live handle; layered/array codecs (lrc, clay) compute eagerly (their
-    chunk-level interfaces materialize internally) and the PendingEncode is
-    born ready.
+    Matrix codecs and CLAY dispatch one device launch and return
+    immediately with a live handle; other codecs (lrc, jerasure
+    bit-matrix, remapped chunks) compute eagerly through their
+    chunk-level interfaces and the PendingEncode is born ready.
 
     With an `aggregator` (codec.matrix_codec.EncodeAggregator), the stripe
     batch is SUBMITTED instead of launched: concurrent small encodes from
@@ -157,7 +167,7 @@ def encode_launch(
     shaped = raw.reshape(stripes, k, sinfo.chunk_size)
     if want is None:
         want = set(range(n))
-    if _matrix_fast_path(ec) and m > 0:
+    if _array_fast_path(ec) and m > 0:
         if aggregator is not None:
             return PendingEncode(shaped, aggregator.submit(ec, shaped), k, m, want)
         return PendingEncode(shaped, ec.encode_array(shaped), k, m, want)
@@ -180,10 +190,10 @@ def encode(
     """Batched stripe encode: object -> per-shard concatenated chunks.
 
     `data` length must be a multiple of stripe_width (the caller pads, as
-    ECTransaction does before encode_and_write).  Matrix codecs take the
-    single-launch path; layered/array codecs (lrc, clay) fall back to
-    per-stripe encode_chunks, still one python loop over stripes but device
-    work batched inside each codec.
+    ECTransaction does before encode_and_write).  Matrix codecs and CLAY
+    take the single-launch path; other codecs fall back to per-stripe
+    encode_chunks, still one python loop over stripes but device work
+    batched inside each codec.
     """
     return encode_launch(sinfo, ec, data, want).result()
 
@@ -426,7 +436,7 @@ def decode_concat_launch(
     # The decode plan needs the full erasure set (every shard we don't
     # have), not just the wanted data shards.
     erasures = [i for i in range(n) if i not in have]
-    if _matrix_fast_path(ec):
+    if _array_fast_path(ec):
         idx = ec.decode_index(erasures)
         if any(i not in have for i in idx):
             raise EcError(EIO, f"missing survivor shards {idx}")
@@ -517,7 +527,7 @@ def decode_shards_launch(
             for e in missing:
                 out[e] = cached[e][:shard_len]
             return PendingDecode(None, None, result=out)
-    if _matrix_fast_path(ec):
+    if _array_fast_path(ec):
         erasures = [i for i in range(ec.get_chunk_count()) if i not in have]
         idx = ec.decode_index(erasures)
         if any(i not in have for i in idx):

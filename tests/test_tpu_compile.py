@@ -108,3 +108,24 @@ def test_sharded_plan_encode_compiles_on_four_chips(topo):
         assert op not in text, op
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert per_chip == 256 * K * 128 * 1024 // 4
+
+
+@pytest.mark.parametrize("erasures", [None, [3]])
+def test_clay84_object_program_compiles(one_chip, erasures):
+    """CLAY(8,4,d=11): the encode, and one program for every decode
+    pattern, of one 4 MiB object (128 stripes of 64 planes of 64 B)."""
+    from ceph_tpu.codec.registry import instance
+    from ceph_tpu.ops import clay_gf
+
+    ec = instance().factory("clay", {"k": "8", "m": "4", "d": "11", "scalar_mds": "isa"})
+    if erasures is None:
+        fn, pat = clay_gf.clay_encode, ec._encode_pattern()
+    else:
+        fn, pat = clay_gf.clay_decode, ec._decode_pattern(erasures)
+    x = jax.ShapeDtypeStruct((128, 8, 4096), jnp.uint8, sharding=one_chip)
+    arrays = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                   for a in pat.arrays)
+    compiled = fn.lower(x, arrays, geo=ec._geometry, n_out=pat.n_out,
+                        repair=pat.repair).compile()
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out == 128 * pat.n_out * 4096
