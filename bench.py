@@ -452,159 +452,6 @@ def measure(clog) -> dict:
         **cc.perf_dump(),
     }
 
-    # Super-launch fusion stage (ISSUE 18): the AGGREGATED data path
-    # under a multi-submitter backlog — the production shape fusion
-    # exists for.  N submitter threads race sub-batches through one
-    # EncodeAggregator whose in-flight ring is kept full, so window
-    # trips defer and whole windows launch as ONE fused dispatch
-    # (ec_tpu_fuse_max_windows).  Unlike the single-thread pipeline
-    # stage above, every byte here also pays the aggregator's
-    # concatenate + per-group parity settle — this is end-to-end
-    # aggregated throughput, not a kernel number.
-    import threading
-
-    from ceph_tpu.codec.matrix_codec import EncodeAggregator
-
-    f_threads = 4
-    f_sub = max(1, batch // 4)
-    f_window = 4
-    agg = EncodeAggregator(
-        window=f_window,
-        max_bytes=1 << 30,
-        inflight_max_bytes=1 << 30,
-        pipeline_depth=2,
-        fuse_max_windows=4,
-    )
-    f_tickets = max(16, 4 * iters)  # per thread, per pass
-    per_thread = [
-        [
-            rng.integers(0, 256, (f_sub, k, chunk), dtype=np.uint8)
-            for _ in range(4)
-        ]
-        for _ in range(f_threads)
-    ]
-    f_errs: list[BaseException] = []
-
-    def f_worker(t: int, n: int) -> None:
-        try:
-            pend = []
-            for i in range(n):
-                h = per_thread[t][i % 4]
-                pend.append(agg.submit(ec, h))
-                # lag the reaps so the ring stays full — the backlog
-                # is what arms the window-trip deferral
-                if len(pend) > 2 * f_window:
-                    np.asarray(pend.pop(0))
-            for p in pend:
-                np.asarray(p)
-        except BaseException as e:
-            f_errs.append(e)
-
-    def f_pass(n: int) -> float:
-        threads = [
-            threading.Thread(target=f_worker, args=(t, n), daemon=True)
-            for t in range(f_threads)
-        ]
-        t0 = time.perf_counter()
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join()
-        agg.flush()
-        elapsed = time.perf_counter() - t0
-        if f_errs:
-            raise f_errs[0]
-        return f_threads * n * f_sub * k * chunk / elapsed / 1e9
-
-    clog(
-        f"fused warm-up: {f_threads} submitters x sub_batch={f_sub} "
-        f"window={f_window}"
-    )
-    # warm pass: compiles the fused launch shapes (each fused window
-    # count is its own jit geometry) outside the measured window
-    f_pass(max(8, f_tickets // 4))
-    f_gbps = 0.0
-    launches = fused_launches = fused_windows = 0
-    for p in range(2):
-        l0 = agg.perf.get("launches")
-        fl0 = agg.perf.get("fused_launches")
-        fw0 = agg.perf.get("fused_windows")
-        pass_gbps = f_pass(f_tickets)
-        if pass_gbps > f_gbps:
-            f_gbps = pass_gbps
-            launches = int(agg.perf.get("launches") - l0)
-            fused_launches = int(agg.perf.get("fused_launches") - fl0)
-            fused_windows = int(agg.perf.get("fused_windows") - fw0)
-        clog(f"fused pass {p}: {pass_gbps:.3f} GB/s")
-    windows_dispatched = f_threads * f_tickets // f_window
-    clog(
-        f"fused done: {f_gbps:.3f} GB/s "
-        f"({fused_launches}/{launches} launches fused, "
-        f"{fused_windows} windows over {windows_dispatched} dispatched)"
-    )
-    fused_result = {
-        "gbps": f_gbps,
-        "threads": f_threads,
-        "sub_batch": f_sub,
-        "window": f_window,
-        "launches": launches,
-        "fused_launches": fused_launches,
-        "fused_windows": fused_windows,
-        "windows_dispatched": windows_dispatched,
-    }
-
-    # Padding-waste stage (ISSUE 18): a mixed-size workload through a
-    # bucketed aggregator.  The first passes pay the static pow2/64
-    # rounding; the _PadBuckets learner promotes each recurring batch
-    # size to an exact-fit launch target, so the LAST pass's waste
-    # ratio is the learned steady state — reported next to the analytic
-    # pow2 baseline the same sizes would have paid forever.
-    from ceph_tpu.codec.matrix_codec import EncodeAggregator
-
-    wagg = EncodeAggregator(
-        window=2,
-        max_bytes=1 << 30,
-        inflight_max_bytes=1 << 30,
-        pipeline_depth=0,
-        fuse_max_windows=1,  # isolate the learner from fusion
-        pad_buckets=4,
-    )
-    w_chunk = 32 * 1024
-    w_sizes = (5, 12, 23, 51)  # pairs -> group stripes 10/24/46/102
-    pow2_pad = sum(wagg._pad_target(2 * s) - 2 * s for s in w_sizes)
-    pow2_baseline = pow2_pad / (
-        pow2_pad + sum(2 * s for s in w_sizes)
-    )
-    w_hosts = {
-        s: rng.integers(0, 256, (s, k, w_chunk), dtype=np.uint8)
-        for s in w_sizes
-    }
-    w_ratio = pow2_baseline
-    for wp in range(4):
-        pad0 = wagg.perf.get("pad_stripes")
-        w_stripes = 0
-        w_tickets = []
-        for s in w_sizes:
-            for _ in range(2):  # one window = 2 same-size tickets
-                w_tickets.append(wagg.submit(ec, w_hosts[s]))
-                w_tickets.append(wagg.submit(ec, w_hosts[s]))
-                w_stripes += 2 * s
-        wagg.flush()
-        for t in w_tickets:
-            np.asarray(t)
-        w_pad = wagg.perf.get("pad_stripes") - pad0
-        w_ratio = w_pad / (w_pad + w_stripes)
-        clog(f"pad_waste pass {wp}: ratio {w_ratio:.4f}")
-    clog(
-        f"pad_waste done: learned {w_ratio:.4f} "
-        f"vs pow2 baseline {pow2_baseline:.4f}"
-    )
-    waste_result = {
-        "ratio": round(w_ratio, 6),
-        "pow2_baseline": round(pow2_baseline, 6),
-        "sizes": list(w_sizes),
-    }
-
     # Checksum stage (ISSUE 20): BlueStore per-block crc32c as packed
     # bit-matrix matmuls through the offload runtime's device kernel.
     # Bytes first: the probe digests are checked against utils/crc32c
@@ -723,8 +570,7 @@ def measure(clog) -> dict:
         )
     result.update(
         decode=decode_result, verify=verify_result,
-        pipeline=pipeline_result, fused=fused_result,
-        pad_waste=waste_result, csum=csum_result, offload=offload_result,
+        pipeline=pipeline_result, csum=csum_result, offload=offload_result,
         stages=stages,
     )
     # Flight-recorder summary (ISSUE 8): launch count, mean queue-wait,
@@ -808,37 +654,6 @@ def main() -> None:
             out["pipelined"]["hbm_peak_bytes"] = p["hbm_peak_bytes"]
         if "device_cache" in p:
             out["pipelined"]["device_cache"] = p["device_cache"]
-    # fused metric (ISSUE 18): aggregated end-to-end throughput with
-    # super-launch fusion armed under a multi-submitter backlog, plus
-    # the fusion witnesses (fused_launches >= 1, launches < windows
-    # dispatched) the perf smoke gate asserts on the same machinery
-    if "fused" in result:
-        f = result["fused"]
-        out["fused"] = {
-            "metric": "rs_8_3_encode_GBps_per_chip_fused",
-            "value": round(f["gbps"], 3),
-            "unit": "GB/s",
-            "threads": f["threads"],
-            "launches": f["launches"],
-            "fused_launches": f["fused_launches"],
-            "fused_windows": f["fused_windows"],
-            "windows_dispatched": f["windows_dispatched"],
-        }
-        p = result.get("pipeline")
-        if p and p.get("gbps"):
-            out["fused"]["vs_pipelined"] = round(f["gbps"] / p["gbps"], 4)
-    # padding-waste metric (ISSUE 18, lower-is-better): the bucketed
-    # learner's steady-state pad fraction on a mixed-size workload,
-    # next to the analytic pow2 baseline the same sizes would pay
-    # without it
-    if "pad_waste" in result:
-        w = result["pad_waste"]
-        out["pad_waste"] = {
-            "metric": "padding_waste_ratio",
-            "value": w["ratio"],
-            "pow2_baseline": w["pow2_baseline"],
-            "sizes": w["sizes"],
-        }
     # write-path offload metrics (ISSUE 20, same {metric, value} sub-
     # object shape): device crc32c GB/s and the fused compress+csum
     # write-path GB/s, both probe-checked byte-identical to their host

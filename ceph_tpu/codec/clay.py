@@ -197,7 +197,7 @@ class ErasureCodeClay(ErasureCode):
         lost = clay_gf.padded_erasures(self._geometry, [self._ext(i) for i in erasures])
         return [i for i in range(self.k + self.m) if self._ext(i) not in lost]
 
-    def encode_array(self, data, out=None):
+    def encode_array(self, data):
         """(S, k, chunk) uint8 -> (S, m, chunk) parity, a live device
         array: the clay_encode program over the whole batch."""
         return clay_gf.launch(self._geometry, self._encode_pattern(), data, False)
@@ -205,7 +205,7 @@ class ErasureCodeClay(ErasureCode):
     def encode_array_host(self, data) -> np.ndarray:
         return clay_gf.run_host(self._geometry, self._encode_pattern(), data)
 
-    def decode_array(self, erasures: list[int], survivors, out=None):
+    def decode_array(self, erasures: list[int], survivors):
         """survivors (S, k, chunk) in decode_index order -> (S,
         len(erasures), chunk), a live device array (clay_decode)."""
         return clay_gf.launch(self._geometry, self._decode_pattern(list(erasures)),

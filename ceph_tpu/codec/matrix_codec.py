@@ -29,6 +29,12 @@ from ceph_tpu.common.lockdep import make_lock as _lockdep_make_lock
 from ceph_tpu.common.mempool import track_buffer as _hbm_track
 from ceph_tpu.gf import expand_matrix, isa_decode_matrix
 from ceph_tpu.ops.dispatch import record_launch
+from ceph_tpu.ops.offload_runtime import (
+    AggTicket,
+    LaunchAggregator,
+    _AggGroup,
+    register_service,
+)
 from ceph_tpu.ops.packed_gf import (
     PACKED_MIN_BYTES,
     PackedPlan,
@@ -108,29 +114,23 @@ class _DeviceCoder:
         # also count their dispatches on ops.dispatch.DECODE_LAUNCHES
         self.decode = decode
 
-    def shard_mesh_for(self, shape):
-        """Mesh for a sharded dispatch at this input shape, or None for
-        the single-device path.  Batched (..., k, L) inputs of at least
+    def _shard_mesh(self, data):
+        """Mesh for a sharded dispatch of `data`, or None for the
+        single-device path.  Batched (..., k, L) inputs of at least
         PACKED_MIN_BYTES shard — lead dims collapse into one stripe axis
         (CLAY's (planes, S, k+nu, sc) fragment launches included); the
         threshold/width policy lives in parallel/dispatch.py (the
-        ec_tpu_shard_* knobs)."""
-        if len(shape) < 3 or int(np.prod(shape)) < PACKED_MIN_BYTES:
+        ec_tpu_shard_* knobs).  A batch traced inside an outer jit
+        (bench.py's serial chain) stays on the in-trace kernel — a
+        device_put of a tracer poisons the trace."""
+        shape = data.shape
+        if _trace_local(data) or len(shape) < 3 or int(np.prod(shape)) < PACKED_MIN_BYTES:
             return None
         from ceph_tpu.parallel import dispatch as shard_dispatch
 
         return shard_dispatch.shard_mesh(int(np.prod(shape[:-2])))
 
-    def _shard_mesh(self, data):
-        """shard_mesh_for, guarded against trace-local values: a batch
-        traced inside an outer jit (bench.py's serial chain) must stay on
-        the in-trace kernel — a device_put of a tracer poisons the
-        trace."""
-        if _trace_local(data):
-            return None
-        return self.shard_mesh_for(data.shape)
-
-    def __call__(self, data: jnp.ndarray, out=None) -> jnp.ndarray:
+    def __call__(self, data: jnp.ndarray) -> jnp.ndarray:
         mesh = self._shard_mesh(data)
         if mesh is not None:
             # sharded dispatch mode (ISSUE 6): place the batch with a
@@ -142,16 +142,15 @@ class _DeviceCoder:
             if len(lead) > 1:
                 # collapse lead dims into the stripe axis (CLAY batched
                 # fragments); a host reshape of the contiguous batch is a
-                # view.  Donation skipped: the pooled buffer has the
-                # caller's lead geometry, not the flattened one.
+                # view
                 flat = data.reshape(-1, *data.shape[-2:])
                 res = sharded_coder_code(self, flat, mesh)
                 return res.reshape(*lead, *res.shape[-2:])
-            return sharded_coder_code(self, data, mesh, out=out)
+            return sharded_coder_code(self, data, mesh)
         if self.plan is not None and data.shape[-1] % 128 == 0:
             return self.plan(data)
         if int(np.prod(data.shape)) >= PACKED_MIN_BYTES:
-            return self.packed(data, out=out)
+            return self.packed(data)
         lead = data.shape[:-2]
         record_launch(
             int(np.prod(lead)) if lead else 1,
@@ -415,46 +414,6 @@ class _GlobalPlanCache:
 PLAN_CACHE = _GlobalPlanCache()
 
 
-def _coder_donatable(coder: _DeviceCoder, data_shape) -> bool:
-    """Will a dispatch through `coder` at this (already >= packed-size)
-    input shape actually consume a donated out= buffer?  Mirrors the
-    _DeviceCoder dispatch exactly: the packed jnp kernel donates; the
-    Pallas plan ignores `out`; a SHARDED launch donates only on the
-    packed path with no remainder padding (a padded launch's output
-    shape differs from the pooled logical-shape buffer)."""
-    mesh = coder.shard_mesh_for(tuple(data_shape))
-    if mesh is not None:
-        if len(data_shape) != 3:
-            return False  # flattened-lead launches skip donation
-        if coder.plan is not None and data_shape[-1] % 128 == 0:
-            return False
-        from ceph_tpu.parallel.sharded import _stripe_shards
-
-        return data_shape[0] % _stripe_shards(mesh) == 0
-    return not (coder.plan is not None and data_shape[-1] % 128 == 0)
-
-
-# The aggregation engine (ISSUE 20): AggTicket / DonationPool /
-# _PadBuckets / _AggGroup / LaunchAggregator and the process-wide
-# aggregator set moved to the service-agnostic offload runtime.  They
-# are re-exported here verbatim — every existing import path
-# (`from ceph_tpu.codec.matrix_codec import LaunchAggregator, ...`)
-# keeps working, and the EC aggregators below are now plain service
-# subclasses of the shared engine.
-from ceph_tpu.ops.offload_runtime import (  # noqa: F401  (re-exports)
-    _AGGREGATORS,
-    AggTicket,
-    DonationPool,
-    LaunchAggregator,
-    _AggGroup,
-    _next_pow2,
-    _PadBuckets,
-    drain_all_aggregators,
-    drop_donation_retention,
-)
-
-
-
 class EncodeAggregator(LaunchAggregator):
     """Cross-write launch aggregation: concurrent stripe encodes of one
     (matrix, chunk-size) geometry coalesce into one padded device launch
@@ -467,22 +426,11 @@ class EncodeAggregator(LaunchAggregator):
         """Queue one (stripes, k, L) uint8 encode; returns its ticket."""
         return self._submit((ec.encode_key(), shaped.shape[-1]), ec, None, shaped)
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
-        return g.ec.encode_array(data, out=donate)
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
+        return g.ec.encode_array(data)
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         return g.ec.encode_array_host(data)
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        return (
-            data_shape[0],
-            g.ec.get_chunk_count() - data_shape[1],
-            data_shape[2],
-        )
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
-        check = getattr(g.ec, "encode_donatable", None)
-        return bool(check(data_shape)) if check is not None else False
 
 
 class DecodeAggregator(LaunchAggregator):
@@ -514,18 +462,11 @@ class DecodeAggregator(LaunchAggregator):
         key = ec.decode_key(erasures) + (survivors.shape[-1],)
         return self._submit(key, ec, tuple(erasures), survivors)
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
-        return g.ec.decode_array(list(g.ctx), data, out=donate)
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
+        return g.ec.decode_array(list(g.ctx), data)
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         return g.ec.decode_array_host(list(g.ctx), data)
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        return (data_shape[0], len(g.ctx), data_shape[2])
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
-        check = getattr(g.ec, "decode_donatable", None)
-        return bool(check(list(g.ctx), data_shape)) if check is not None else False
 
 
 class VerifyAggregator(LaunchAggregator):
@@ -558,19 +499,11 @@ class VerifyAggregator(LaunchAggregator):
             ec, None, codewords,
         )
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
         return g.ec.verify_array(data)
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         return g.ec.verify_array_host(data)
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        return (data_shape[0],)
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
-        return False  # the bitmap output is tiny; pooling buys nothing
-
-
 
 
 _DEFAULT_AGGREGATOR: EncodeAggregator | None = None
@@ -635,19 +568,17 @@ def default_verify_aggregator() -> VerifyAggregator:
 # (ISSUE 20): same singletons, same knobs, same perf names — the
 # registry only ADDS a uniform by-name surface (service_aggregator /
 # offload_perf_dump) on top of the existing factories.
-from ceph_tpu.ops.offload_runtime import register_service as _register_service
-
-_register_service(
+register_service(
     "encode", default_encode_aggregator, lane="client",
     oracle="MatrixCodecMixin.encode_array_host",
     doc="EC stripe encode (parity generation)",
 )
-_register_service(
+register_service(
     "decode", default_decode_aggregator, lane="recovery",
     oracle="MatrixCodecMixin.decode_array_host",
     doc="EC reconstruct decode (recovery / degraded reads)",
 )
-_register_service(
+register_service(
     "verify", default_verify_aggregator, lane="background",
     oracle="MatrixCodecMixin.verify_array_host",
     doc="EC deep-scrub compare-only verify",
@@ -752,16 +683,12 @@ class MatrixCodecMixin:
 
     # -- device-native bulk paths ------------------------------------------
 
-    def encode_array(self, data, out=None) -> jnp.ndarray:
+    def encode_array(self, data) -> jnp.ndarray:
         """(..., k, L) uint8 -> (..., m, L) parity, stays on device.
 
         Dispatches through the cached _DeviceCoder, so on a TPU backend this
         IS the fused Pallas kernel — the production analog of the reference
-        plugin's `ec_encode_data` hot call (isa/ErasureCodeIsa.cc:83-91).
-
-        `out`: optional dead device buffer of the parity's shape, donated
-        into the packed kernel so recurring aggregated launches reuse the
-        allocation (ignored on paths that cannot donate)."""
+        plugin's `ec_encode_data` hot call (isa/ErasureCodeIsa.cc:83-91)."""
         mat = self.distribution_matrix()
         if self.m == 1 and self._xor_row_available():
             arr = jnp.asarray(data)
@@ -772,45 +699,18 @@ class MatrixCodecMixin:
         # does ONE sharded device_put (a premature jnp.asarray would
         # commit to device 0 and pay a second reshard copy)
         arr = data if isinstance(data, np.ndarray) else jnp.asarray(data)
-        return PLAN_CACHE.encode_coder(mat[self.k :])(arr, out=out)
+        return PLAN_CACHE.encode_coder(mat[self.k :])(arr)
 
-    def encode_donatable(self, data_shape) -> bool:
-        """True when encode_array(data, out=...) at this input shape will
-        actually consume a donated parity buffer — i.e. the dispatch lands
-        on the packed jnp kernel.  The EncodeAggregator gates its donation
-        pool on this so it never hoards dead device memory for paths
-        (xor_reduce, Pallas, small-matmul) that ignore `out`."""
-        mat = self.distribution_matrix()
-        if self.m == 1 and self._xor_row_available():
-            return False
-        if int(np.prod(data_shape)) < PACKED_MIN_BYTES:
-            return False
-        coder = PLAN_CACHE.encode_coder(mat[self.k :])
-        return _coder_donatable(coder, data_shape)
-
-    def decode_array(self, erasures: list[int], survivors, out=None) -> jnp.ndarray:
+    def decode_array(self, erasures: list[int], survivors) -> jnp.ndarray:
         """survivors (..., k, L) in decode_index order -> (..., nerrs, L).
 
         The decode twin of encode_array: dispatches through the cached
         erasure-pattern _DeviceCoder (Pallas on TPU-aligned chunks, packed
         planes for bulk work, bitsliced matmul for small one-off
-        patterns).  `out`: optional dead device buffer of the
-        reconstruction's shape, donated into the packed kernel so
-        recurring aggregated recovery launches reuse the allocation."""
+        patterns)."""
         coder, _ = PLAN_CACHE.decode_coder(self.distribution_matrix(), erasures, self.k)
         arr = survivors if isinstance(survivors, np.ndarray) else jnp.asarray(survivors)
-        return coder(arr, out=out)
-
-    def decode_donatable(self, erasures: list[int], data_shape) -> bool:
-        """True when decode_array(erasures, data, out=...) at this input
-        shape will actually consume a donated output buffer — the decode
-        twin of encode_donatable, gating the DecodeAggregator's pool."""
-        if int(np.prod(data_shape)) < PACKED_MIN_BYTES:
-            return False
-        coder, _ = PLAN_CACHE.decode_coder(
-            self.distribution_matrix(), list(erasures), self.k
-        )
-        return _coder_donatable(coder, data_shape)
+        return coder(arr)
 
     def verify_array(self, codewords) -> jnp.ndarray:
         """(..., k+m, L) uint8 full codewords (data rows in encode order,

@@ -36,26 +36,26 @@ def instrument_codec(ec, plugin: str):
     if hasattr(ec, "encode_array"):
         orig_encode_array = ec.encode_array
 
-        def encode_array(data, out=None):
+        def encode_array(data):
             parent = active_span()
             if parent is None:
-                return orig_encode_array(data, out=out)
+                return orig_encode_array(data)
             with parent.child(f"codec:{plugin}:encode") as sp:
                 sp.keyval("shape", lambda: str(getattr(data, "shape", len(data))))
-                return orig_encode_array(data, out=out)
+                return orig_encode_array(data)
 
         ec.encode_array = encode_array
 
     if hasattr(ec, "decode_array"):
         orig_decode_array = ec.decode_array
 
-        def decode_array(erasures, survivors, out=None):
+        def decode_array(erasures, survivors):
             parent = active_span()
             if parent is None:
-                return orig_decode_array(erasures, survivors, out=out)
+                return orig_decode_array(erasures, survivors)
             with parent.child(f"codec:{plugin}:decode") as sp:
                 sp.keyval("erasures", lambda: ",".join(map(str, erasures)))
-                return orig_decode_array(erasures, survivors, out=out)
+                return orig_decode_array(erasures, survivors)
 
         ec.decode_array = decode_array
 

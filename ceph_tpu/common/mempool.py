@@ -1,11 +1,10 @@
 """HBM mempool ledger — unified device/host memory accounting (ISSUE 13).
 
 Every lever built for the per-chip throughput push holds TPU HBM — the
-donation pool's refcounted output buffers, the depth-N pipeline's
-in-flight ring, the device-resident chunk cache, sharded placements —
-yet until this module nothing answered "how many bytes are resident on
-the device right now, held by whom, and are we about to OOM?".  The
-reference treats this as a first-class subsystem
+depth-N pipeline's in-flight ring, the device-resident chunk cache,
+sharded placements — yet until this module nothing answered "how many
+bytes are resident on the device right now, held by whom, and are we
+about to OOM?".  The reference treats this as a first-class subsystem
 (src/include/mempool.h: per-pool byte/object accounting behind
 ``dump_mempools``, sharded by type in debug mode, plus
 ``osd_memory_target``/PriorityCache arbitrating cache sizes under one
@@ -33,12 +32,11 @@ Design:
 
 Pressure (``ec_tpu_hbm_target_bytes``, 0 = off): the ratio of total
 resident bytes to the target drives a staged response — first trim the
-device-resident chunk cache, then cap donation-pool retention, then
-clamp the effective pipeline depth to 1 — and raises the
-``TPU_HBM_PRESSURE`` HEALTH_WARN through the OSD status → mgr digest →
-mon pipeline, clearing (and releasing the caps) on relief.  The lock is
-never held across a trim call: pool/cache locks may nest INTO the
-ledger lock, so the ledger lock stays a leaf.
+device-resident chunk cache, then clamp the effective pipeline depth to
+1 — and raises the ``TPU_HBM_PRESSURE`` HEALTH_WARN through the OSD
+status → mgr digest → mon pipeline, clearing (and releasing the clamp)
+on relief.  The lock is never held across a trim call: pool/cache locks
+may nest INTO the ledger lock, so the ledger lock stays a leaf.
 """
 
 from __future__ import annotations
@@ -51,14 +49,12 @@ from collections import deque
 from ceph_tpu.common.lockdep import make_rlock
 
 # The EC data path's predeclared pools.  Holders:
-#   ec_donation          codec/matrix_codec.DonationPool free buffers
 #   ec_pipeline_inflight encode/decode launch outputs dispatched, unsettled
 #   device_cache         ops/device_cache.DeviceChunkCache entries
 #   sharded_placement    parallel/sharded.py NamedSharding device_puts
 #   verify               VerifyAggregator in-flight mismatch bitmaps
 #   scratch              plan-cache bit matrices + bench staging
 POOLS = (
-    "ec_donation",
     "ec_pipeline_inflight",
     "device_cache",
     "sharded_placement",
@@ -68,20 +64,18 @@ POOLS = (
 
 # Pressure staging thresholds (ratio = total resident / target):
 # at PRESSURE_RAISE the cache is trimmed back toward PRESSURE_RAISE of
-# the target; still over PRESSURE_DONATION_CAP afterwards caps
-# donation-pool retention; still over PRESSURE_DEPTH_CLAMP clamps the
-# effective pipeline depth to 1.  The raised state clears (and the caps
-# release) only under PRESSURE_CLEAR — hysteresis so the health check
-# doesn't flap at the boundary.
+# the target; still over PRESSURE_DEPTH_CLAMP afterwards clamps the
+# effective pipeline depth to 1.  The raised state clears (and the
+# clamp releases) only under PRESSURE_CLEAR — hysteresis so the health
+# check doesn't flap at the boundary.
 PRESSURE_RAISE = 0.85
-PRESSURE_DONATION_CAP = 0.95
 PRESSURE_DEPTH_CLAMP = 1.0
 PRESSURE_CLEAR = 0.70
 
 # maybe_check_pressure() evaluates at most this often (hot-path guard)
 _PRESSURE_CHECK_INTERVAL_S = 0.05
 
-_STAGE_NAMES = {0: "none", 1: "cache-trim", 2: "donation-cap", 3: "depth-clamp"}
+_STAGE_NAMES = {0: "none", 1: "cache-trim", 2: "depth-clamp"}
 
 
 class _PoolStats:
@@ -178,12 +172,10 @@ class MempoolLedger:
         # pressure state (hysteresis: sticky until ratio < PRESSURE_CLEAR)
         self._pressure_raised = False
         self._pressure_stage = 0
-        self.donation_capped = False
         self.depth_clamped = False
         self._last_pressure_check = 0.0
         self._actions = {
             "cache_trimmed_bytes": 0,
-            "donation_dropped_bytes": 0,
             "depth_clamps": 0,
             "raises": 0,
             "clears": 0,
@@ -234,9 +226,9 @@ class MempoolLedger:
                 # defer, never free inline: the finalizer fires in GC
                 # context, where taking any lock can self-deadlock the
                 # interrupted thread (see _deferred).  Kept on the
-                # handle so an explicit free can DETACH it — a recycled
-                # buffer (the donation pool's whole point) must not
-                # accumulate one dead registration per cycle.
+                # handle so an explicit free can DETACH it — a buffer
+                # accounted more than once must not accumulate one dead
+                # registration per cycle.
                 h._fin = weakref.finalize(buf, self._deferred.append, h)
             except TypeError:
                 pass  # not weakref-able: explicit free only
@@ -272,10 +264,10 @@ class MempoolLedger:
     def _free(self, h: MempoolHandle) -> None:
         fin, h._fin = h._fin, None
         if fin is not None:
-            # unregister the buffer finalizer: a recycled buffer (the
-            # donation pool recycles by design) must not pin one dead
-            # handle + registration per accounting cycle for its whole
-            # lifetime.  No-op when the finalizer already fired.
+            # unregister the buffer finalizer: a buffer accounted more
+            # than once must not pin one dead handle + registration per
+            # accounting cycle for its whole lifetime.  No-op when the
+            # finalizer already fired.
             fin.detach()
         with self._lock:
             if not h._open:
@@ -412,7 +404,6 @@ class MempoolLedger:
                 "pressure": self._pressure_raised,
                 "stage": self._pressure_stage,
                 "stage_name": _STAGE_NAMES[self._pressure_stage],
-                "donation_capped": self.donation_capped,
                 "depth_clamped": self.depth_clamped,
                 "actions": dict(self._actions),
                 "pools": {
@@ -435,14 +426,13 @@ class MempoolLedger:
 
     def check_pressure(self) -> dict:
         """Evaluate the pressure ratio and apply the staged response:
-        trim the device cache back toward the raise threshold, then cap
-        donation-pool retention, then clamp the effective pipeline
-        depth.  Raised state (and the caps) persist until the ratio
-        drops under PRESSURE_CLEAR.  The whole read-evaluate-apply
-        sequence holds the (outermost) pressure lock so concurrent
-        evaluations cannot interleave their flag writes; trims run with
-        NO counter lock held (pool/cache locks nest into the counter
-        lock, never the other way)."""
+        trim the device cache back toward the raise threshold, then
+        clamp the effective pipeline depth.  Raised state (and the
+        clamp) persist until the ratio drops under PRESSURE_CLEAR.  The
+        whole read-evaluate-apply sequence holds the (outermost)
+        pressure lock so concurrent evaluations cannot interleave their
+        flag writes; trims run with NO counter lock held (pool/cache
+        locks nest into the counter lock, never the other way)."""
         with self._pressure_lock:
             return self._check_pressure_locked()
 
@@ -471,22 +461,11 @@ class MempoolLedger:
                     with self._lock:
                         self._actions["cache_trimmed_bytes"] += freed
             total = self.total_device_bytes()
-            if total / target >= PRESSURE_DONATION_CAP:
-                # stage 2: stop retaining dead output buffers — the
-                # donation pool trades allocation churn for resident
-                # bytes, the wrong trade under pressure
-                stage = max(2, stage)
-                self.donation_capped = True
-                freed = self._drop_donation_retention()
-                if freed:
-                    with self._lock:
-                        self._actions["donation_dropped_bytes"] += freed
-                total = self.total_device_bytes()
             if total / target >= PRESSURE_DEPTH_CLAMP:
-                # stage 3: clamp the effective pipeline depth to 1 — no
+                # stage 2: clamp the effective pipeline depth to 1 — no
                 # more than one launch's output in flight, trading the
                 # H2D/kernel overlap for bounded residency
-                stage = 3
+                stage = 2
                 if not self.depth_clamped:
                     self.depth_clamped = True
                     with self._lock:
@@ -503,7 +482,6 @@ class MempoolLedger:
             was = self._pressure_raised
             self._pressure_raised = False
             self._pressure_stage = 0
-            self.donation_capped = False
             self.depth_clamped = False
             if was and not disabled:
                 self._actions["clears"] += 1
@@ -518,18 +496,6 @@ class MempoolLedger:
             from ceph_tpu.common.log import dout
 
             dout("osd", 1, f"mempool: device-cache trim failed: {e!r}")
-            return 0
-
-    @staticmethod
-    def _drop_donation_retention() -> int:
-        try:
-            from ceph_tpu.codec.matrix_codec import drop_donation_retention
-
-            return drop_donation_retention()
-        except Exception as e:
-            from ceph_tpu.common.log import dout
-
-            dout("osd", 1, f"mempool: donation-pool drop failed: {e!r}")
             return 0
 
 

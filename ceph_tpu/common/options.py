@@ -279,47 +279,11 @@ OPTIONS: dict[str, Option] = _opts(
         "yet settled) before a new launch first settles the oldest.  At "
         "depth >= 2 window N+1's H2D staging overlaps window N's kernel "
         "— the overlap the flight recorder's idle gaps pointed at.  The "
-        "settle order is oldest-first, and the donation pool's per-slot "
-        "refcounts guarantee an in-flight launch's output buffer is "
-        "never recycled early.  <= 0 disables the ring (in-flight "
+        "settle order is oldest-first, and every launch's output buffer "
+        "is released at its own settle.  <= 0 disables the ring (in-flight "
         "launches bounded only by ec_tpu_inflight_max_bytes, the "
         "pre-ISSUE-11 behavior)",
         see_also=("ec_tpu_inflight_max_bytes", "ec_tpu_aggregate_window"),
-        runtime=True,
-    ),
-    Option(
-        "ec_tpu_fuse_max_windows",
-        int,
-        4,
-        A,
-        "super-launch fusion bound (ISSUE 18): when the in-flight launch "
-        "ring (ec_tpu_pipeline_depth) is full as an aggregation window "
-        "trips, the group keeps accumulating up to this many whole "
-        "windows and launches them as ONE fused multi-window dispatch — "
-        "amortizing the fixed dispatch overhead exactly when the backlog "
-        "proves demand.  Per-ticket settle slices, QoS arbitration and "
-        "the host-oracle fallback are unchanged; fused launches count on "
-        "fused_launches/fused_windows and flag `fused` on their flight "
-        "records.  <= 1 disables fusion (every window trip launches "
-        "immediately)",
-        see_also=("ec_tpu_pipeline_depth", "ec_tpu_aggregate_window"),
-        runtime=True,
-    ),
-    Option(
-        "ec_tpu_pad_buckets",
-        int,
-        4,
-        A,
-        "learned pad-bucket slots per aggregation group key (ISSUE 18): "
-        "a batch size the key's workload produces repeatedly is promoted "
-        "to an exact-fit launch target instead of rounding up to the "
-        "static pow2/64-multiple bucket, cutting zero-pad stripes on "
-        "recurring sizes while the bounded, LRU-evicted slot set keeps "
-        "the jit-cache geometry count capped (evicted targets drop "
-        "their pooled output buffers so bucket churn cannot pin HBM).  "
-        "Waste is exported as padding_waste_ratio / pad_waste.<label>.  "
-        "<= 0 keeps the static buckets only",
-        see_also=("ec_tpu_aggregate_window",),
         runtime=True,
     ),
     Option(
@@ -379,10 +343,10 @@ OPTIONS: dict[str, Option] = _opts(
         "the osd_memory_target analog for device memory).  When total "
         "ledger-tracked bytes exceed 85% of the target the staged "
         "response engages — trim the device-resident chunk cache, then "
-        "cap donation-pool retention, then clamp the effective pipeline "
-        "depth to 1 — and TPU_HBM_PRESSURE raises through the OSD "
-        "status -> mgr digest -> mon health pipeline, clearing (and "
-        "releasing the caps) once residency falls back under 70%.  "
+        "clamp the effective pipeline depth to 1 — and TPU_HBM_PRESSURE "
+        "raises through the OSD status -> mgr digest -> mon health "
+        "pipeline, clearing (and releasing the clamp) once residency "
+        "falls back under 70%.  "
         "0 disables pressure evaluation entirely",
         see_also=("ec_tpu_mempool_debug", "ec_tpu_device_cache_bytes",
                   "ec_tpu_pipeline_depth"),

@@ -184,19 +184,12 @@ class CompressAggregator(LaunchAggregator):
             ("#compress", shaped.shape[1]), None, None, shaped[:, None, :]
         )
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
         S = data.shape[0]
         return transform_rows_device(data.reshape(S, -1))
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         return transform_rows(data.reshape(data.shape[0], -1))
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        Lp = data_shape[1] * data_shape[2]
-        return (data_shape[0], Lp + Lp // CELL)
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
-        return False  # output shape differs from input; no buffer reuse
 
 
 _DEFAULT_COMPRESS_AGGREGATOR: CompressAggregator | None = None

@@ -44,10 +44,6 @@ def _perf_type(counter: str) -> str:
         # engagement flag and the local burn rate are levels; the
         # wave/shed/ramp/storm totals stay counters
         or name in ("wave_objects", "engaged", "burn_rate")
-        # padding-waste exports (ISSUE 18): the global ratio and every
-        # per-label `pad_waste.<label>` slice are fractions that rise
-        # AND fall as the bucketed pad targets learn
-        or "waste" in counter
         # offload-runtime registry levels (ISSUE 20): a service's pending
         # submission count drains to zero, and the registered-service
         # count is a level, not a monotone total

@@ -512,8 +512,8 @@ class Monitor(Dispatcher):
             )
         # daemons over their HBM residency target (the mempool ledger's
         # pressure verdict via the mgr digest, ISSUE 13).  Clears when
-        # the staged trims — cache, donation retention, pipeline depth
-        # — bring residency back under the relief threshold, or the
+        # the staged response — cache trim, pipeline-depth clamp —
+        # brings residency back under the relief threshold, or the
         # holder frees its buffers.
         pressured = self.pg_digest.get("hbm_pressure") or {}
         summary = health.hbm_pressure_summary(pressured)

@@ -167,18 +167,12 @@ class ChecksumAggregator(LaunchAggregator):
             ("#csum", shaped.shape[1]), None, None, shaped[:, None, :]
         )
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
         S = data.shape[0]
         return crc32c_device(data.reshape(S, -1))
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         return crc32c_host_rows(data.reshape(data.shape[0], -1))
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        return (data_shape[0],)
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
-        return False  # 4 output bytes per block; pooling buys nothing
 
 
 _DEFAULT_CSUM_AGGREGATOR: ChecksumAggregator | None = None

@@ -118,23 +118,17 @@ class DeviceOccupancy:
 DEVICES_PER_LAUNCH = DeviceOccupancy()
 
 class PipelineGauges:
-    """Process-wide pipeline/donation accounting for the depth-N async
-    launch ring (ISSUE 11, codec/matrix_codec.LaunchAggregator):
+    """Process-wide accounting for the depth-N async launch ring
+    (ISSUE 11, ops/offload_runtime.LaunchAggregator):
 
     - ``depth``: the configured ``ec_tpu_pipeline_depth`` (gauge),
     - ``inflight`` / ``inflight_peak``: launches dispatched but not yet
       settled, now and at peak,
     - ``drains``: ring-full settles (the submitter paid the oldest
-      launch's wait so the new one could overlap it),
-    - ``donation_reuses``: output buffers recycled from the donation
-      pool into a later launch,
-    - ``donation_recycled_live``: the INVARIANT counter — a pooled
-      buffer handed out while its producing launch was still in flight.
-      Must stay 0; the chaos pipelined-wedge phase asserts it.
+      launch's wait so the new one could overlap it).
     """
 
-    __slots__ = ("_lock", "depth", "inflight", "inflight_peak", "drains",
-                 "donation_reuses", "donation_recycled_live")
+    __slots__ = ("_lock", "depth", "inflight", "inflight_peak", "drains")
 
     def __init__(self) -> None:
         self._lock = make_lock("pipeline_gauges")
@@ -142,8 +136,6 @@ class PipelineGauges:
         self.inflight = 0
         self.inflight_peak = 0
         self.drains = 0
-        self.donation_reuses = 0
-        self.donation_recycled_live = 0
 
     def set_depth(self, depth: int) -> None:
         with self._lock:
@@ -162,13 +154,6 @@ class PipelineGauges:
         with self._lock:
             self.drains += 1
 
-    def record_donation(self, reused: bool, live: bool = False) -> None:
-        with self._lock:
-            if reused:
-                self.donation_reuses += 1
-            if live:
-                self.donation_recycled_live += 1
-
     def snapshot(self) -> dict[str, int]:
         with self._lock:
             return {
@@ -176,113 +161,10 @@ class PipelineGauges:
                 "inflight": self.inflight,
                 "inflight_peak": self.inflight_peak,
                 "drains": self.drains,
-                "donation_reuses": self.donation_reuses,
-                "donation_recycled_live": self.donation_recycled_live,
             }
 
 
 PIPELINE = PipelineGauges()
-
-
-class PaddingWaste:
-    """Pad-stripe accounting for aggregated launches (ISSUE 18): every
-    padded launch records its padded batch and how many of those stripes
-    were zero padding, globally and per group label, so `perf dump` (and
-    the bench) can show WHERE padding bytes go instead of only that the
-    `pad_stripes` counter moved.  The per-label map is capped — group
-    labels are bounded in practice (one per (matrix, chunk-size) key),
-    but a pathological key churn must not grow the perf dump unboundedly."""
-
-    LABEL_CAP = 32
-
-    __slots__ = ("_lock", "padded_stripes", "pad_stripes", "_labels")
-
-    def __init__(self) -> None:
-        self._lock = make_lock("padding_waste")
-        self.padded_stripes = 0  # stripes dispatched, padding included
-        self.pad_stripes = 0  # of those, zero-pad stripes
-        self._labels: dict[str, list[int]] = {}  # label -> [padded, pad]
-
-    def record(self, label: str, padded: int, pad: int) -> None:
-        with self._lock:
-            self.padded_stripes += int(padded)
-            self.pad_stripes += int(pad)
-            slot = self._labels.get(label)
-            if slot is None:
-                if len(self._labels) >= self.LABEL_CAP:
-                    return  # global totals still track the overflow
-                slot = self._labels[label] = [0, 0]
-            slot[0] += int(padded)
-            slot[1] += int(pad)
-
-    def ratio(self) -> float:
-        with self._lock:
-            if not self.padded_stripes:
-                return 0.0
-            return self.pad_stripes / self.padded_stripes
-
-    def per_label(self) -> dict[str, float]:
-        with self._lock:
-            return {
-                label: (pad / padded if padded else 0.0)
-                for label, (padded, pad) in self._labels.items()
-            }
-
-    def reset(self) -> None:
-        with self._lock:
-            self.padded_stripes = 0
-            self.pad_stripes = 0
-            self._labels.clear()
-
-
-PAD_WASTE = PaddingWaste()
-
-
-def record_padding(label: str, padded: int, pad: int) -> None:
-    """Record one padded aggregated launch: `padded` stripes dispatched
-    (padding included) of which `pad` were zero padding, attributed to
-    the group `label` (codec/matrix_codec._group_label)."""
-    PAD_WASTE.record(label, padded, pad)
-
-
-class FusedGauges:
-    """Super-launch fusion totals (ISSUE 18): launches that carried more
-    than one aggregation window's worth of tickets because the in-flight
-    ring was full when their window tripped, and the windows they fused.
-    Mirrors of the per-aggregator `fused_launches`/`fused_windows` perf
-    counters, totalled process-wide for the dispatch perf dump."""
-
-    __slots__ = ("_lock", "fused_launches", "fused_windows")
-
-    def __init__(self) -> None:
-        self._lock = make_lock("fused_gauges")
-        self.fused_launches = 0
-        self.fused_windows = 0
-
-    def record(self, windows: int) -> None:
-        with self._lock:
-            self.fused_launches += 1
-            self.fused_windows += int(windows)
-
-    def snapshot(self) -> dict[str, int]:
-        with self._lock:
-            return {
-                "fused_launches": self.fused_launches,
-                "fused_windows": self.fused_windows,
-            }
-
-    def reset(self) -> None:
-        with self._lock:
-            self.fused_launches = 0
-            self.fused_windows = 0
-
-
-FUSED = FusedGauges()
-
-
-def record_fused(windows: int) -> None:
-    """Record one fused multi-window launch spanning `windows` windows."""
-    FUSED.record(windows)
 
 
 # Launches that completed on the HOST ORACLE instead of the device
@@ -439,24 +321,10 @@ def perf_dump() -> dict[str, object]:
 
     for name, val in launch_scheduler().perf_dump().items():
         out[f"sched.{name}"] = val
-    # pipelined-dispatch ring + donation-pool invariants (ISSUE 11):
-    # configured depth, current/peak in-flight launches, ring-full
-    # drains, and the recycled-live invariant counter (must stay 0)
+    # pipelined-dispatch ring (ISSUE 11): configured depth,
+    # current/peak in-flight launches, ring-full drains
     for name, val in PIPELINE.snapshot().items():
         out[f"pipeline.{name}"] = val
-    # super-launch fusion totals (ISSUE 18): launches carrying more than
-    # one window's worth of tickets because the ring was full, and the
-    # windows they fused — launches < submits/window proves amortization
-    for name, val in FUSED.snapshot().items():
-        out[name] = val
-    # padding-waste accounting (ISSUE 18): the process-wide pad-stripe
-    # fraction of everything dispatched padded, plus a per-group-label
-    # slice (`pad_waste.<label>`) so asok/Perfetto show WHERE padding
-    # bytes go — the bench proves the bucketed targets push the global
-    # ratio below the pow2 baseline
-    out["padding_waste_ratio"] = round(PAD_WASTE.ratio(), 6)
-    for label, ratio in sorted(PAD_WASTE.per_label().items()):
-        out[f"pad_waste.{label}"] = round(ratio, 6)
     # device-resident chunk cache (ISSUE 11): hit/miss/evict counters
     # plus the resident-bytes/entries gauges, as `cache.<counter>`
     # scalars -> ceph_tpu_ec_dispatch_cache_* prometheus families

@@ -147,14 +147,9 @@ def new_record(
         "h2d_s": 0.0,
         "kernel_s": 0.0,
         "d2h_s": 0.0,
-        # zero-pad stripes in `batch` (batch - stripes when the launch
-        # padded to a bucket target): the per-launch waste the
-        # ops/dispatch.py pad_waste slice aggregates (ISSUE 18)
+        # zero-pad stripes in `batch` (batch - stripes when a windowed
+        # launch padded to its static bucket target)
         "pad_stripes": 0,
-        # aggregation windows fused into this launch (ISSUE 18): > 1
-        # only on super-launches that stretched past their window while
-        # the in-flight ring was full (the `fused` flag mirrors it)
-        "fused_windows": 0,
         "flags": {
             "sharded": False,
             "fallback": False,
@@ -171,9 +166,6 @@ def new_record(
             "cache_hit": False,
             # a winning hedged sub-read fed this decode (ISSUE 17)
             "hedged": _HEDGED.get(),
-            # super-launch fusion (ISSUE 18): this launch carried more
-            # than one aggregation window's worth of tickets
-            "fused": False,
             # on-device RMW delta encode (ISSUE 18): parity updated in
             # HBM from cached operands — zero H2D, zero D2H
             "delta": False,

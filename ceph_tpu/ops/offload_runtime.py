@@ -1,19 +1,18 @@
 """Service-agnostic device-offload runtime (ISSUE 20).
 
-The LaunchAggregator / DonationPool / pad-bucket / guard / launch-
-scheduler / mempool stack grew up inside codec/matrix_codec.py serving
-exactly one client: EC coding launches.  Nothing in it is EC-specific —
-a "launch" is any batched per-byte transform with a device plan and a
-byte-identical host oracle — so this module hoists the machinery out of
-the codec and fronts it with a small service registry:
+The LaunchAggregator / guard / launch-scheduler / mempool stack grew up
+inside codec/matrix_codec.py serving exactly one client: EC coding
+launches.  Nothing in it is EC-specific — a "launch" is any batched
+per-byte transform with a device plan and a byte-identical host oracle
+— so this module hoists the machinery out of the codec and fronts it
+with a small service registry:
 
-- **LaunchAggregator** (and its moving parts: AggTicket, DonationPool,
-  _PadBuckets, _AggGroup) is the generic aggregation engine.  A service
-  subclasses it and supplies the device plan builder (`_dispatch`), the
-  byte-identical host oracle (`_dispatch_host`), the output geometry
-  (`_out_shape`) and the donation predicate (`_donate_ok`); the engine
-  owns windowing, padding, pipelining, donation-pool recycling, QoS
-  lane submission (SCHED_CLASS), guard fallback and mempool accounting.
+- **LaunchAggregator** (and its moving parts: AggTicket, _AggGroup) is
+  the generic aggregation engine.  A service subclasses it and supplies
+  the device plan builder (`_dispatch`) and the byte-identical host
+  oracle (`_dispatch_host`); the engine owns windowing, padding,
+  pipelining, QoS lane submission (SCHED_CLASS), guard fallback and
+  mempool accounting.
 - **register_service / service_aggregator** is the registry: a service
   registers its aggregator factory, QoS lane and host-oracle
   description once; callers reach the shared process-wide instance by
@@ -99,211 +98,14 @@ class AggTicket:
         return out if dtype is None else out.astype(dtype)
 
 
-class DonationPool:
-    """Per-shape pool of dead device output buffers with per-buffer LIVE
-    refcounts (ISSUE 11).  At pipeline depth > 1 several launches'
-    outputs are in flight at once; a buffer becomes donatable only after
-    ITS producing launch settles — `hold` marks an output live at
-    dispatch, `release` at settle, and `take`/`put` refuse live buffers,
-    counting any violation on the process-wide invariant gauge
-    (`ec_dispatch.pipeline.donation_recycled_live`, asserted 0 by the
-    chaos pipelined-wedge phase).  Callers serialize access under the
-    aggregator-wide lock; the pool itself is not thread-safe."""
-
-    # ceiling on settled buffers retained per shape: pipeline-depth
-    # launches can settle close together, and one slot (the old
-    # dict-per-shape pool) would drop all but the last.  The aggregator
-    # syncs the effective `cap` to its ring depth — retaining more dead
-    # buffers than launches that can be in flight would just pin HBM
-    # (each pooled RS(8,3) output of a large launch is tens of MiB).
-    SLOT_CAP = 4
-
-    __slots__ = ("_free", "_live", "cap", "_mem")
-
-    def __init__(self, cap: int | None = None) -> None:
-        self._free: dict[tuple, list] = {}
-        self._live: dict[int, int] = {}  # id(buf) -> refcount
-        self.cap = self.SLOT_CAP if cap is None else max(1, int(cap))
-        # HBM ledger handles per pooled FREE buffer (ISSUE 13): pooled
-        # dead buffers are resident device memory nothing else accounts
-        # for.  Handles are buffer-finalized too, so a pool dropped with
-        # buffers still slotted cannot leak ledger bytes.
-        self._mem: dict[int, object] = {}
-
-    def hold(self, buf) -> None:
-        self._live[id(buf)] = self._live.get(id(buf), 0) + 1
-
-    def release(self, buf) -> None:
-        key = id(buf)
-        refs = self._live.get(key, 0) - 1
-        if refs <= 0:
-            self._live.pop(key, None)
-        else:
-            self._live[key] = refs
-
-    def _mem_release(self, buf) -> int:
-        """Close a pooled buffer's ledger handle; returns its bytes."""
-        h = self._mem.pop(id(buf), None)
-        if h is None:
-            return 0
-        nbytes = h.nbytes
-        h.free()
-        return nbytes
-
-    def take(self, shape):
-        from ceph_tpu.ops.dispatch import PIPELINE
-
-        slot = self._free.get(tuple(shape))
-        if not slot:
-            return None
-        buf = slot.pop()
-        self._mem_release(buf)  # leaving the free list either way
-        if id(buf) in self._live:
-            PIPELINE.record_donation(reused=False, live=True)
-            return None  # never hand out a live buffer
-        PIPELINE.record_donation(reused=True)
-        return buf
-
-    def put(self, shape, buf) -> None:
-        from ceph_tpu.ops.dispatch import PIPELINE
-
-        if id(buf) in self._live:
-            # pooling an unsettled launch's output would let a later
-            # launch donate (and XLA invalidate) bytes a reaper still
-            # needs — refuse and count the invariant violation
-            PIPELINE.record_donation(reused=False, live=True)
-            return
-        led = _hbm_ledger()
-        if led.donation_capped:
-            # HBM pressure stage 2: retention capped — dead buffers go
-            # back to the allocator instead of pinning device memory
-            return
-        slot = self._free.setdefault(tuple(shape), [])
-        slot.append(buf)
-        self._mem[id(buf)] = led.alloc(
-            "ec_donation", int(getattr(buf, "nbytes", 0) or 0), buf=buf
-        )
-        while len(slot) > self.cap:
-            # oldest out — also trims promptly after a runtime cap
-            # shrink (a pipeline-depth config drop)
-            self._mem_release(slot.pop(0))
-
-    def drop_free(self) -> int:
-        """Drop every FREE pooled buffer (HBM pressure stage 2);
-        returns the bytes released.  Live refcounts are untouched —
-        in-flight launches still settle normally."""
-        freed = 0
-        for slot in self._free.values():
-            for buf in slot:
-                freed += self._mem_release(buf)
-        self._free.clear()
-        return freed
-
-    def drop_batch(self, batch: int) -> int:
-        """Drop the FREE pooled buffers whose leading (batch) dimension
-        is `batch` — shapes a retired pad bucket can no longer produce
-        (ISSUE 18): when the bucket learner evicts a target, every
-        pooled output at that geometry is dead weight, and bucket churn
-        must not pin HBM in the mempool ledger.  Returns bytes freed;
-        live refcounts are untouched."""
-        freed = 0
-        for shape in [s for s in self._free if s and s[0] == batch]:
-            for buf in self._free.pop(shape):
-                freed += self._mem_release(buf)
-        return freed
-
-    # mapping-ish view (tests and introspection): the shapes with at
-    # least one FREE buffer pooled
-    def __iter__(self):
-        return iter([s for s, slot in self._free.items() if slot])
-
-    def __len__(self) -> int:
-        return sum(1 for slot in self._free.values() if slot)
-
-
-class _PadBuckets:
-    """Learned launch-size buckets for one (matrix, chunk-size) group
-    key (ISSUE 18): replaces the static pow2/64-multiple `_pad_target`
-    with a small set of batch sizes the key's workload actually
-    produces.  A batch size seen `PROMOTE_AFTER` times becomes a bucket
-    (padding a recurring 23-stripe launch to 32 wastes 28% of every
-    launch forever; padding it to 23 wastes nothing and still recurs
-    for the jit cache and the donation pool); the slot set is bounded
-    and LRU-evicted so the jit-cache geometry count stays capped, and
-    the caller drops the evicted target's pooled output buffers
-    (DonationPool.drop_batch).  A padding-waste EWMA per key feeds the
-    `padding_waste_ratio` export.  Callers serialize access under the
-    aggregator-wide lock."""
-
-    PROMOTE_AFTER = 3
-    EWMA_ALPHA = 0.2
-    # candidate-count map bound: recurring sizes promote out of it long
-    # before this; a never-repeating workload must not grow it unboundedly
-    CANDIDATE_CAP = 64
-
-    __slots__ = ("buckets", "_counts", "_lru", "_seq", "waste_ewma")
-
-    def __init__(self) -> None:
-        self.buckets: list[int] = []  # sorted learned batch targets
-        self._counts: "OrderedDict[int, int]" = OrderedDict()
-        self._lru: dict[int, int] = {}  # bucket -> last-use seq
-        self._seq = 0
-        self.waste_ewma = 0.0
-
-    def target(self, stripes: int, static: int, cap: int) -> tuple[int, int | None]:
-        """(pad target for `stripes`, evicted bucket or None).
-
-        The smallest learned bucket >= `stripes` wins when it beats the
-        static bucket; otherwise the static target stands.  Learning:
-        `stripes` itself is promoted to a bucket once seen
-        PROMOTE_AFTER times (exact fit = zero waste for the recurring
-        size); past `cap` buckets the least-recently-used target is
-        evicted and returned so the caller can drop its pooled buffers."""
-        self._seq += 1
-        evicted: int | None = None
-        target = static
-        for b in self.buckets:  # sorted: first fit is smallest
-            if b >= stripes:
-                if b < static:
-                    target = b
-                break
-        if target in self._lru:
-            self._lru[target] = self._seq
-        if target != stripes and stripes not in self.buckets:
-            # static padding is wasting stripes on this size: count it
-            # toward promotion
-            seen = self._counts.get(stripes, 0) + 1
-            if seen >= self.PROMOTE_AFTER:
-                self._counts.pop(stripes, None)
-                self.buckets.append(stripes)
-                self.buckets.sort()
-                self._lru[stripes] = self._seq
-                target = stripes
-                if len(self.buckets) > max(1, cap):
-                    evicted = min(self.buckets, key=lambda b: self._lru[b])
-                    self.buckets.remove(evicted)
-                    self._lru.pop(evicted, None)
-                    if evicted == target:  # evicted ourselves: static stands
-                        target = static
-            else:
-                self._counts[stripes] = seen
-                self._counts.move_to_end(stripes)
-                while len(self._counts) > self.CANDIDATE_CAP:
-                    self._counts.popitem(last=False)
-        waste = (target - stripes) / target if target else 0.0
-        self.waste_ewma += self.EWMA_ALPHA * (waste - self.waste_ewma)
-        return target, evicted
-
-
 class _AggGroup:
     """Pending submissions sharing one (matrix, chunk-length) geometry —
     the unit that concatenates into a single padded device launch."""
 
     __slots__ = (
         "key", "ec", "ctx", "arrays", "tickets", "stripes", "nbytes",
-        "parity", "host", "pad", "error", "donatable", "lock",
-        "input", "credit", "flight", "submit_ts", "stalled", "held",
-        "mem", "fused_windows",
+        "parity", "host", "pad", "error", "lock", "input", "credit",
+        "flight", "submit_ts", "stalled", "mem",
     )
 
     def __init__(self, key, ec, ctx=None):
@@ -318,10 +120,6 @@ class _AggGroup:
         self.host: np.ndarray | None = None  # materialized parity
         self.pad = 0
         self.error: BaseException | None = None  # a failed launch, sticky
-        self.donatable = False  # launch path can reuse a donated buffer
-        # the in-flight launch's device output, refcounted in the
-        # donation pool from dispatch until settle (pipeline depth > 1)
-        self.held = None
         # HBM ledger handle for that in-flight output (ISSUE 13):
         # alloc'd at dispatch, freed at settle on every outcome —
         # host-fallback and sticky-error settles included
@@ -337,11 +135,6 @@ class _AggGroup:
         self.flight: dict | None = None
         self.submit_ts = time.monotonic()
         self.stalled = False
-        # super-launch fusion (ISSUE 18): > 0 once this group's window
-        # trip was deferred because the in-flight ring was full — the
-        # group keeps accumulating whole windows behind the backlog and
-        # launches them fused (one dispatch, per-ticket settle slices)
-        self.fused_windows = 0
         # serializes THIS group's launch/materialization (the encode
         # dispatch + blocking device wait) without stalling the
         # aggregator-wide lock; RLock because a reap-forced launch runs
@@ -352,23 +145,23 @@ class _AggGroup:
 class LaunchAggregator:
     """Cross-op launch aggregation: coalesce concurrent small stripe-batch
     coding calls (different ops, PGs, objects) into one padded device
-    launch.  Shared machinery of the encode and decode aggregators; the
-    subclasses supply the group key and the device dispatch.
+    launch.  Shared machinery of every offload service's aggregator;
+    the subclasses supply the group key and the device dispatch.
 
     The storage-side analog of a training stack's bucketed all-reduce:
     per-op launches under ~1 MiB are dominated by dispatch overhead, so
     submissions queue in per-geometry groups and launch together when the
     window fills, the byte budget trips, or a barrier drains the window
     (ECBackend.flush_encodes / flush_decodes — or any ticket reap).
-    window <= 1 launches every submission immediately (aggregation off,
-    metrics still recorded).
 
-    In aggregating mode, stripe counts are padded to a bounded bucket set
-    (power of two up to 64, then multiples of 64 — capped waste, unlike
-    pure pow2) so the jit cache sees few geometries and the donation pool
-    can recycle output buffers across launches (see docs/PERFORMANCE.md
-    for the donation caveats).  Tickets slice their own stripes back out,
-    in submission order.
+    One launch path, two modes.  window <= 1 (the encode and decode
+    windows' default) launches each submission at once as its own
+    group: nothing is concatenated or padded.  window > 1 concatenates a group's
+    submissions and pads the stripe count to a static bucket (power of
+    two up to 64, then multiples of 64 — capped waste, unlike pure pow2)
+    so the jit cache sees few geometries; a window that trips launches
+    at once.  Tickets slice their own stripes back out, in submission
+    order.  No device buffer outlives its launch's settle.
 
     Occupancy and launch-size distributions are PerfHistograms on
     `self.perf`, exportable through the PR-1 prometheus layer
@@ -386,16 +179,13 @@ class LaunchAggregator:
     MEM_POOL = "ec_pipeline_inflight"
 
     def __init__(self, window: int = 0, max_bytes: int = 64 << 20,
-                 pad_pow2: bool = True, inflight_max_bytes: int | None = None,
-                 pipeline_depth: int | None = None,
-                 fuse_max_windows: int | None = None,
-                 pad_buckets: int | None = None):
+                 inflight_max_bytes: int | None = None,
+                 pipeline_depth: int | None = None):
         from ceph_tpu.common.perf_counters import PerfCountersBuilder
         from ceph_tpu.common.throttle import Throttle
 
         self.window = int(window)
         self.max_bytes = int(max_bytes)
-        self.pad_pow2 = pad_pow2
         # depth-N asynchronous launch pipeline (ISSUE 11): how many
         # launched-but-unsettled groups may be in flight before a new
         # launch first settles the oldest — the settle happens AFTER the
@@ -407,26 +197,6 @@ class LaunchAggregator:
 
             pipeline_depth = int(OPTIONS["ec_tpu_pipeline_depth"].default)
         self.pipeline_depth = int(pipeline_depth)
-        # super-launch fusion bound (ISSUE 18): with the in-flight ring
-        # full, a group whose window trips may keep accumulating up to
-        # this many windows and launch them as ONE fused dispatch —
-        # amortizing dispatch overhead exactly when the backlog proves
-        # demand.  <= 1 disables fusion (every window trip launches).
-        if fuse_max_windows is None:
-            from ceph_tpu.common.options import OPTIONS
-
-            fuse_max_windows = int(OPTIONS["ec_tpu_fuse_max_windows"].default)
-        self.fuse_max_windows = int(fuse_max_windows)
-        # learned pad-bucket slots per group key (ISSUE 18): recurring
-        # batch sizes promote to exact-fit launch targets, bounded and
-        # LRU-evicted so the jit cache stays capped.  <= 0 keeps the
-        # static pow2/64-multiple targets only.
-        if pad_buckets is None:
-            from ceph_tpu.common.options import OPTIONS
-
-            pad_buckets = int(OPTIONS["ec_tpu_pad_buckets"].default)
-        self.pad_buckets = int(pad_buckets)
-        self._pad_state: dict[tuple, _PadBuckets] = {}
         from ceph_tpu.ops.dispatch import PIPELINE
 
         PIPELINE.set_depth(self.pipeline_depth)
@@ -435,11 +205,6 @@ class LaunchAggregator:
         # still validated on the outermost acquire)
         self._lock = _lockdep_make_rlock(self.PERF_NAME)
         self._groups: "OrderedDict[tuple, _AggGroup]" = OrderedDict()
-        # per-shape retention follows the ring depth: more dead buffers
-        # than launches that can be in flight would only pin HBM
-        self._donate_pool = DonationPool(
-            cap=min(DonationPool.SLOT_CAP, max(1, self.pipeline_depth))
-        )
         # end-to-end backpressure (ec_tpu_inflight_max_bytes): byte credit
         # over everything admitted but not yet settled — windowed groups
         # AND launched-but-unreaped ones.  Over the bound, _admit makes
@@ -456,7 +221,7 @@ class LaunchAggregator:
         for c in ("submits", "launches", "flush_window", "flush_bytes",
                   "flush_explicit", "flush_immediate", "flush_reap",
                   "flush_backpressure", "pad_stripes", "host_fallbacks",
-                  "throttle_stalls", "fused_launches", "fused_windows"):
+                  "throttle_stalls"):
             b.add_u64_counter(c)
         b.add_histogram("stripes_per_launch",
                         "stripe-batch occupancy of each device launch",
@@ -468,15 +233,13 @@ class LaunchAggregator:
                         "input bytes per device launch",
                         lowest=4096, buckets=18)
         self.perf = b.create_perf_counters()
-        # live-aggregator registry (ISSUE 13): HBM pressure's stage-2
-        # trim and the leak-gate drain reach every instance through it
+        # live-aggregator registry (ISSUE 13): the leak-gate and chaos
+        # drains reach every instance through it
         _AGGREGATORS.add(self)
 
     def configure(self, window: int | None = None, max_bytes: int | None = None,
                   inflight_max_bytes: int | None = None,
-                  pipeline_depth: int | None = None,
-                  fuse_max_windows: int | None = None,
-                  pad_buckets: int | None = None) -> None:
+                  pipeline_depth: int | None = None) -> None:
         """Apply live config (the OSD wires its Config + runtime observers
         here, so the aggregate_* settings reach the shared instance)."""
         if window is not None:
@@ -485,50 +248,20 @@ class LaunchAggregator:
             self.max_bytes = int(max_bytes)
         if inflight_max_bytes is not None:
             self.inflight.limit = int(inflight_max_bytes)
-        if fuse_max_windows is not None:
-            self.fuse_max_windows = int(fuse_max_windows)
-        if pad_buckets is not None:
-            self.pad_buckets = int(pad_buckets)
-            with self._lock:
-                # shrinking the bucket bound must trim now-dead shapes:
-                # retired targets' pooled outputs would pin HBM forever
-                for state in self._pad_state.values():
-                    while len(state.buckets) > max(1, self.pad_buckets):
-                        gone = min(
-                            state.buckets, key=lambda b: state._lru[b]
-                        )
-                        state.buckets.remove(gone)
-                        state._lru.pop(gone, None)
-                        self._donate_pool.drop_batch(gone)
-                if self.pad_buckets <= 0:
-                    for state in self._pad_state.values():
-                        for b in state.buckets:
-                            self._donate_pool.drop_batch(b)
-                    self._pad_state.clear()
         if pipeline_depth is not None:
             self.pipeline_depth = int(pipeline_depth)
-            with self._lock:
-                self._donate_pool.cap = min(
-                    DonationPool.SLOT_CAP, max(1, self.pipeline_depth)
-                )
             from ceph_tpu.ops.dispatch import PIPELINE
 
             PIPELINE.set_depth(self.pipeline_depth)
 
     # -- subclass hooks ------------------------------------------------------
 
-    def _dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _dispatch(self, g: _AggGroup, data: np.ndarray):
         raise NotImplementedError
 
     def _dispatch_host(self, g: _AggGroup, data: np.ndarray) -> np.ndarray:
         """Byte-identical host-oracle recompute of `_dispatch` (pure
         numpy): the DEGRADED-mode path a wedged device cannot hang."""
-        raise NotImplementedError
-
-    def _out_shape(self, g: _AggGroup, data_shape) -> tuple:
-        raise NotImplementedError
-
-    def _donate_ok(self, g: _AggGroup, data_shape) -> bool:
         raise NotImplementedError
 
     # -- submission ----------------------------------------------------------
@@ -545,8 +278,7 @@ class LaunchAggregator:
         stripes = shaped.shape[0]
         # HBM pressure hook (ISSUE 13): time-throttled, no locks held —
         # under a target, sustained submission pressure trims the cache
-        # / caps donation retention / clamps depth without waiting for
-        # the next status beacon
+        # / clamps depth without waiting for the next status beacon
         _hbm_ledger().maybe_check_pressure()
         stalled = self._admit(shaped.nbytes)
         reason = None
@@ -569,25 +301,6 @@ class LaunchAggregator:
                 reason = "flush_bytes"
             elif len(g.tickets) >= self.window:
                 reason = "flush_window"
-                # super-launch fusion (ISSUE 18): the window tripped but
-                # the in-flight ring is full — launching now would only
-                # queue a dispatch behind the backlog.  Defer the trip
-                # (the group stays windowed, accumulating whole windows)
-                # until the ring drains, the fuse bound or byte budget
-                # trips, or a barrier/reap flushes: the deferred windows
-                # then ride ONE fused dispatch, amortizing its overhead
-                # exactly when demand is proven.  Per-ticket settle
-                # slices, QoS arbitration, and the host-oracle fallback
-                # are untouched — a fused group is just a bigger group.
-                if (
-                    self.fuse_max_windows > 1
-                    and self.pipeline_depth > 0
-                    and len(self._live) >= self.pipeline_depth
-                    and len(g.tickets) < self.window * self.fuse_max_windows
-                    and g.nbytes < self.max_bytes
-                ):
-                    g.fused_windows = len(g.tickets) // self.window
-                    reason = None
             if reason is not None:
                 self._groups.pop(key, None)  # detach under the lock...
         if reason is not None:
@@ -613,7 +326,7 @@ class LaunchAggregator:
         if depth <= 0:
             return
         if _hbm_ledger().depth_clamped:
-            # HBM pressure stage 3: one launch's output in flight at a
+            # HBM pressure stage 2: one launch's output in flight at a
             # time — overlap traded for bounded residency until relief
             depth = 1
         from ceph_tpu.ops.dispatch import PIPELINE
@@ -702,8 +415,8 @@ class LaunchAggregator:
             except Exception:
                 continue  # sticky on the group; other groups still launch
         if detached:
-            # a fused group deferred past a full ring (ISSUE 18) launches
-            # here — re-bound the in-flight set at the depth budget
+            # the flushed groups joined the ring: re-bound the in-flight
+            # set at the depth budget
             self._drain_pipeline()
 
     # -- launch + reap -------------------------------------------------------
@@ -716,39 +429,6 @@ class LaunchAggregator:
         if stripes <= 64:
             return _next_pow2(stripes)
         return -(-stripes // 64) * 64
-
-    def _pad_target_for(self, key, stripes: int) -> int:
-        """Bucketed pad specialization (ISSUE 18): the static bucket,
-        improved by the per-key learner when this key's workload keeps
-        producing a batch size the static rounding wastes stripes on.
-        Updates the key's waste EWMA and the process-wide pad_waste
-        slice inputs; evicted bucket targets drop their pooled output
-        buffers so bucket churn cannot pin HBM."""
-        static = self._pad_target(stripes)
-        if self.pad_buckets <= 0:
-            return static
-        with self._lock:
-            state = self._pad_state.get(key)
-            if state is None:
-                state = self._pad_state[key] = _PadBuckets()
-            target, evicted = state.target(stripes, static, self.pad_buckets)
-            if evicted is not None:
-                self._donate_pool.drop_batch(evicted)
-        return target
-
-    def padding_waste(self) -> dict[str, float]:
-        """Per-key padding-waste EWMA snapshot (introspection/tests),
-        keyed by the group label `_group_label` would give the key."""
-        import zlib
-
-        with self._lock:
-            out = {}
-            for key, state in self._pad_state.items():
-                chunk = key[-1] if key and isinstance(key[-1], int) else 0
-                digest = zlib.crc32(repr(key).encode())
-                label = f"{self.PERF_NAME}/{digest:08x}/L{chunk}"
-                out[label] = state.waste_ewma
-            return out
 
     def _launch(self, g: _AggGroup, reason: str) -> None:
         """Concatenate a (detached) group's submissions into one padded
@@ -764,22 +444,12 @@ class LaunchAggregator:
             # write would pay a concatenate copy + dead-stripe encode the
             # direct path never did
             pad = 0
-            if self.pad_pow2 and self.window > 1:
-                pad = self._pad_target_for(g.key, g.stripes) - g.stripes
+            if self.window > 1:
+                pad = self._pad_target(g.stripes) - g.stripes
             if pad:
                 data = np.concatenate(
                     [data, np.zeros((pad, *data.shape[1:]), dtype=np.uint8)]
                 )
-            out_shape = self._out_shape(g, data.shape)
-            # the donation pool only pays off when the coder's dispatch
-            # will actually consume the donated buffer (the packed jnp
-            # path); on e.g. the Pallas path pooling would just hold dead
-            # device memory an extra launch
-            g.donatable = self._donate_ok(g, data.shape)
-            donate = None
-            if g.donatable:
-                with self._lock:
-                    donate = self._donate_pool.take(out_shape)
             # retained until settle: a device that wedges AFTER this
             # dispatch is recomputed from these exact bytes on the host
             g.input = data
@@ -803,15 +473,6 @@ class LaunchAggregator:
                 sched_class=self.SCHED_CLASS,
             )
             rec["pad_stripes"] = pad
-            # fused verdict (ISSUE 18): the deferral armed AND the group
-            # actually accumulated more than one window before launching
-            # (a reap right after the deferral is a plain launch)
-            fused_windows = 0
-            if g.fused_windows and self.window > 1:
-                fused_windows = len(g.tickets) // self.window
-            if fused_windows > 1:
-                rec["flags"]["fused"] = True
-                rec["fused_windows"] = fused_windows
             if g.stalled:
                 rec["flags"]["throttle_stall"] = True
             # QoS arbitration (ISSUE 9): the ready launch enters the
@@ -837,7 +498,7 @@ class LaunchAggregator:
 
             def _dispatch_scheduled():
                 timing["t_dispatch"] = time.monotonic()
-                out = self._guarded_dispatch(g, data, donate)
+                out = self._guarded_dispatch(g, data)
                 timing["t_done"] = time.monotonic()
                 return out
 
@@ -913,13 +574,6 @@ class LaunchAggregator:
                         self.MEM_POOL, out_nbytes, buf=parity
                     )
             rec["hbm_bytes"] = _hbm_ledger().total_device_bytes()
-            # donation-pool refcount (ISSUE 11): the device output is
-            # LIVE until this launch settles — at pipeline depth > 1 a
-            # same-shape co-launch settling first must not recycle it
-            if g.donatable and not isinstance(parity, np.ndarray):
-                with self._lock:
-                    self._donate_pool.hold(parity)
-                    g.held = parity
             # inside g.lock, like the error path above: appending after
             # release races a reaper that settles (and _live-removes) the
             # group first, which would pin a settled group in _live
@@ -935,19 +589,6 @@ class LaunchAggregator:
         self.perf.hinc("stripes_per_launch", g.stripes)
         self.perf.hinc("tickets_per_launch", len(g.tickets))
         self.perf.hinc("launch_bytes", data.nbytes)
-        if fused_windows > 1:
-            self.perf.inc("fused_launches")
-            self.perf.inc("fused_windows", fused_windows)
-            from ceph_tpu.ops.dispatch import record_fused
-
-            record_fused(fused_windows)
-        if pad or (self.pad_pow2 and self.window > 1):
-            # padding-waste slice (ISSUE 18): every padded-mode launch
-            # reports its batch and pad so perf_dump's pad_waste.<label>
-            # and padding_waste_ratio show where padding bytes go
-            from ceph_tpu.ops.dispatch import record_padding
-
-            record_padding(self._group_label(g), g.stripes + pad, pad)
 
     def _group_label(self, g: _AggGroup) -> str:
         """Stable human-readable lane name for a group's flight records
@@ -963,7 +604,7 @@ class LaunchAggregator:
 
     # -- device guard / host fallback ---------------------------------------
 
-    def _guarded_dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _guarded_dispatch(self, g: _AggGroup, data: np.ndarray):
         """Dispatch one launch under the device guard: the `codec.launch`
         faultpoint and the per-launch deadline apply here; a device error
         or timeout re-runs the group on the byte-identical host oracle
@@ -979,18 +620,18 @@ class LaunchAggregator:
         try:
             faultpoint("codec.launch")
             return guard.call(
-                lambda: self._timed_dispatch(g, data, donate),
+                lambda: self._timed_dispatch(g, data),
                 what=f"{self.WHAT} dispatch",
             )
         except BaseException as e:
             return self._host_fallback(g, data, e)
 
-    def _timed_dispatch(self, g: _AggGroup, data: np.ndarray, donate):
+    def _timed_dispatch(self, g: _AggGroup, data: np.ndarray):
         """The guarded callable: the launch as the `device.dispatch`
         section of whichever thread runs it (a launch worker, or the
         caller with the watchdog off)."""
         with section("device.dispatch", data.nbytes):
-            return self._dispatch(g, data, donate)
+            return self._dispatch(g, data)
 
     def _host_fallback(self, g: _AggGroup, data: np.ndarray, cause):
         """Re-run a launch on the host oracle.  `cause` is the device
@@ -1051,7 +692,6 @@ class LaunchAggregator:
             if g.host is None and g.error is None:
                 parity = g.parity
                 device_side = not isinstance(parity, np.ndarray)
-                single = len(g.tickets) == 1 and not g.pad
                 host = parity
                 if device_side:
                     # completion-ordered readiness probe (ISSUE 11): at
@@ -1071,15 +711,8 @@ class LaunchAggregator:
                             host = self._host_fallback(g, g.input, None)
                         except BaseException as e2:
                             g.error = e2
-                        device_side = False  # suspect buffer: never pool it
+                        device_side = False  # suspect buffer: skip the wait
                 if device_side:
-                    # when the buffer is headed for the donation pool the
-                    # copy MUST be forced (np.array): a zero-copy
-                    # CPU-backend view into a later-donated buffer would
-                    # corrupt silently.  Single-ticket unpadded groups
-                    # (the window<=1 default path) hand the result
-                    # straight through — no forced copy, no pooling.
-                    force_copy = g.donatable and not single
                     rec = g.flight
                     # the worker writes spans into a side dict, folded
                     # into the record only on SUCCESS: a materialize
@@ -1105,11 +738,7 @@ class LaunchAggregator:
                                 wait()
                         t1 = time.monotonic()
                         with section("device.d2h") as d2h:
-                            out = (
-                                np.array(parity)
-                                if force_copy
-                                else np.asarray(parity)
-                            )
+                            out = np.asarray(parity)
                             d2h.nbytes = out.nbytes
                         t2 = time.monotonic()
                         spans["kernel_s"] = t1 - t0
@@ -1138,32 +767,8 @@ class LaunchAggregator:
                             host = self._host_fallback(g, g.input, e)
                         except BaseException as e2:
                             g.error = e2
-                        device_side = False  # suspect buffer: never pool it
-                # the launch's output stops being LIVE at settle whatever
-                # happened to it — leaving a stale refcount would poison
-                # a later buffer that reuses the id
-                if g.held is not None:
-                    with self._lock:
-                        self._donate_pool.release(g.held)
-                    g.held = None
                 if g.error is None:
-                    if single:
-                        g.host = host
-                    else:
-                        g.host = host[: g.stripes] if g.pad else host
-                        if g.donatable and device_side:
-                            # release the in-flight ledger hold BEFORE
-                            # the donation pool re-accounts the same
-                            # buffer under ec_donation — the two charges
-                            # overlapping would double-count the bytes
-                            # and permanently inflate the peak gauges
-                            if g.mem is not None:
-                                g.mem.free()
-                                g.mem = None
-                            with self._lock:
-                                self._donate_pool.put(
-                                    tuple(parity.shape), parity
-                                )
+                    g.host = host[: g.stripes] if g.pad else host
                     g.parity = None
             # settled (host bytes or sticky error): release the
             # backpressure credit, the retained launch input, and the
@@ -1208,20 +813,12 @@ class LaunchAggregator:
                 EIO, f"aggregated {self.WHAT} launch failed: {g.error!r}"
             )
         ticket._value = g.host[ticket._start : ticket._start + ticket._stripes]
-# every live aggregator, weakly held (ISSUE 13): the HBM pressure
-# layer's stage-2 trim and the tier-1 leak gate's teardown drain reach
-# all instances — the process-wide defaults AND test-local ones
+
+
+# every live aggregator, weakly held (ISSUE 13): the tier-1 leak gate's
+# teardown drain and the chaos harness's end-of-run drain reach all
+# instances — the process-wide defaults AND test-local ones
 _AGGREGATORS: "weakref.WeakSet[LaunchAggregator]" = weakref.WeakSet()
-
-
-def drop_donation_retention() -> int:
-    """Drop every live aggregator's FREE pooled buffers (HBM pressure
-    stage 2); returns the bytes released."""
-    freed = 0
-    for agg in list(_AGGREGATORS):
-        with agg._lock:
-            freed += agg._donate_pool.drop_free()
-    return freed
 
 
 def drain_all_aggregators() -> None:

@@ -26,10 +26,7 @@ Ring Transforms", arXiv:1701.07731):
     fraction of the 8m x 8k bit-row schedule.
 
 The gather-reshape -> plane tower -> XOR schedule -> output stack chain is
-ONE jitted computation per (matrix, geometry); `PackedPlan.__call__`
-accepts an `out=` device buffer and routes through a `donate_argnums`
-variant so steady-state aggregated launches (codec/matrix_codec.py's
-EncodeAggregator) reuse the parity allocation instead of growing the heap.
+ONE jitted computation per (matrix, geometry).
 
 Byte-identical to `xor_matmul` and to the host oracle
 (gf.bitslice.xor_matmul_host) for every matrix — the schedule is an exact
@@ -360,30 +357,11 @@ def _packed_code(data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
     return _packed_code_impl(data, sched, k, m)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("sched", "k", "m"), donate_argnums=(0,)
-)
-def _packed_code_into(out: jax.Array, data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
-    """Donating variant: `out` is a dead parity buffer of the result's
-    exact (..., m, L) shape; XLA aliases the result into it, so launches
-    at a recurring aggregated geometry stop allocating.  The donated array
-    is INVALID after the call — callers own that discipline
-    (docs/PERFORMANCE.md, donation caveats)."""
-    return _packed_code_impl(data, sched, k, m)
-
-
 # The decode twins: the same program under its own name, so that a
 # device trace tells decode launches from encode launches.  Every name
 # contains `packed`, which the benchmark's kernel reader matches.
 @functools.partial(jax.jit, static_argnames=("sched", "k", "m"))
 def _packed_decode(data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
-    return _packed_code_impl(data, sched, k, m)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("sched", "k", "m"), donate_argnums=(0,)
-)
-def _packed_decode_into(out: jax.Array, data: jax.Array, *, sched, k: int, m: int) -> jax.Array:
     return _packed_code_impl(data, sched, k, m)
 
 
@@ -551,21 +529,10 @@ class PackedPlan:
         lead = shape[:-2]
         return int(np.prod(lead)) if lead else 1
 
-    def __call__(self, data: jax.Array, out: jax.Array | None = None) -> jax.Array:
-        """(..., k, L) uint8 -> (..., m, L) uint8 parity/coded output.
-
-        `out`: optional donated device buffer of the result shape (see
-        _packed_code_into); ignored when the shape/dtype does not match."""
+    def __call__(self, data: jax.Array) -> jax.Array:
+        """(..., k, L) uint8 -> (..., m, L) uint8 parity/coded output."""
         record_launch(
             self._stripes(data.shape), int(np.prod(data.shape)), decode=self.decode
         )
-        kw = dict(sched=self.sched, k=self.k, m=self.m)
-        want_shape = (*data.shape[:-2], self.m, data.shape[-1])
-        if (
-            out is not None
-            and tuple(getattr(out, "shape", ())) == want_shape
-            and getattr(out, "dtype", None) == jnp.uint8
-        ):
-            into = _packed_decode_into if self.decode else _packed_code_into
-            return into(out, data, **kw)
-        return (_packed_decode if self.decode else _packed_code)(data, **kw)
+        kernel = _packed_decode if self.decode else _packed_code
+        return kernel(data, sched=self.sched, k=self.k, m=self.m)

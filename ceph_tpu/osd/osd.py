@@ -400,29 +400,6 @@ class OSD(Dispatcher):
             ["ec_tpu_pipeline_depth"],
             lambda _n, v: _apply_pipeline_depth(v),
         )
-        # super-launch fusion + bucketed pad specialization (ISSUE 18):
-        # every aggregator shares both knobs, runtime-mutable; shrinking
-        # the bucket budget trims the now-dead pooled shapes in place
-        def _apply_fuse_windows(v: int) -> None:
-            self.encode_aggregator.configure(fuse_max_windows=int(v))
-            self.decode_aggregator.configure(fuse_max_windows=int(v))
-            self.verify_aggregator.configure(fuse_max_windows=int(v))
-
-        def _apply_pad_buckets(v: int) -> None:
-            self.encode_aggregator.configure(pad_buckets=int(v))
-            self.decode_aggregator.configure(pad_buckets=int(v))
-            self.verify_aggregator.configure(pad_buckets=int(v))
-
-        _apply_fuse_windows(self.conf.get("ec_tpu_fuse_max_windows"))
-        self.conf.add_observer(
-            ["ec_tpu_fuse_max_windows"],
-            lambda _n, v: _apply_fuse_windows(v),
-        )
-        _apply_pad_buckets(self.conf.get("ec_tpu_pad_buckets"))
-        self.conf.add_observer(
-            ["ec_tpu_pad_buckets"],
-            lambda _n, v: _apply_pad_buckets(v),
-        )
         # on-device RMW delta path (ISSUE 18): process-wide arm bit the
         # EC backend consults before trying the zero-copy delta encode
         from . import ec_backend as ec_backend_mod

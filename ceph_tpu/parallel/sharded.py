@@ -259,43 +259,24 @@ def plan_scrub_step(
     return _plan_scrub_executable(mesh, plan, k)(chunks)
 
 
-def _packed_shard_executable(mesh: Mesh, packed: PackedPlan, donate: bool):
+def _packed_shard_executable(mesh: Mesh, packed: PackedPlan):
     """shard_map wrapper of the packed-plane kernel: each device runs the
     fused plane-tower/XOR-schedule program (ops/packed_gf.py) on its own
     (S/n, k, L) stripe tile — the multi-chip fan-out of the exact kernel
-    the aggregated single-device launch ships.
-
-    `donate=True` builds the `_packed_code_into` twin: a dead output
-    buffer (already sharded with the output's NamedSharding from a prior
-    launch at this geometry) is threaded through as a donated first
-    argument, so recurring aggregated launches recycle the allocation on
-    every device instead of growing each device's heap."""
+    the aggregated single-device launch ships."""
     spec = _stripe_spec(mesh)
 
     def build():
-        if donate:
-            local = jax.shard_map(
-                # `out` is dead — it exists only to be donated; XLA
-                # aliases each device's result tile into it
-                lambda out, data: _packed_code_impl(
-                    data, packed.sched, packed.k, packed.m
-                ),
-                mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                check_vma=False,
-            )
-            return jax.jit(local, donate_argnums=(0,))
         local = jax.shard_map(
             lambda data: _packed_code_impl(data, packed.sched, packed.k, packed.m),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
         )
         return jax.jit(local)
 
-    return _cached_exec(
-        ("packed", mesh, packed.sched, packed.k, packed.m, donate), build
-    )
+    return _cached_exec(("packed", mesh, packed.sched, packed.k, packed.m), build)
 
 
-def sharded_coder_code(coder, data, mesh: Mesh, out=None) -> jax.Array:
+def sharded_coder_code(coder, data, mesh: Mesh) -> jax.Array:
     """One (S, k, L) uint8 coding launch, data-parallel over the mesh's
     stripe axis — the sharded dispatch mode of codec/matrix_codec.py's
     `_DeviceCoder` (ISSUE 6 tentpole).
@@ -309,11 +290,7 @@ def sharded_coder_code(coder, data, mesh: Mesh, out=None) -> jax.Array:
     stripe count.  Kernel choice per device mirrors the single-device
     dispatch: the fused Pallas kernel on TPU-aligned chunk lengths
     (lane_parallelism is 1, so the per-device tile keeps L), the packed
-    plane kernel otherwise — bytes are identical either way.
-
-    `out`: optional dead device buffer from a prior sharded launch at
-    this exact geometry AND sharding; consumed (donated) only on the
-    packed path with no remainder padding, ignored otherwise."""
+    plane kernel otherwise — bytes are identical either way."""
     S, _, L = data.shape
     n = _stripe_shards(mesh)
     pad = -S % n
@@ -329,7 +306,7 @@ def sharded_coder_code(coder, data, mesh: Mesh, out=None) -> jax.Array:
             data = jnp.pad(data, ((0, pad), (0, 0), (0, 0)))
     # HBM ledger (ISSUE 13): the sharded H2D placement is device-resident
     # for the life of the launch — tracked so dump_mempools shows bulk
-    # launches' staging alongside the cache/donation/in-flight pools
+    # launches' staging alongside the cache and in-flight pools
     with section("device.h2d", data.nbytes):
         placed = track_buffer(
             jax.device_put(data, _stripe_sharding(mesh)), "sharded_placement"
@@ -340,20 +317,7 @@ def sharded_coder_code(coder, data, mesh: Mesh, out=None) -> jax.Array:
         # per-dispatch accounting above is the authoritative count
         result = _plan_encode_executable(mesh, coder.plan)(placed)
     else:
-        packed = coder.packed
-        want = (S + pad, packed.m, L)
-        if (
-            not pad
-            and out is not None
-            and tuple(getattr(out, "shape", ())) == want
-            and getattr(out, "dtype", None) == jnp.uint8
-            and getattr(out, "sharding", None) == _stripe_sharding(mesh)
-        ):
-            result = _packed_shard_executable(mesh, packed, donate=True)(
-                out, placed
-            )
-        else:
-            result = _packed_shard_executable(mesh, packed, donate=False)(placed)
+        result = _packed_shard_executable(mesh, coder.packed)(placed)
     return result[:S] if pad else result
 
 

@@ -729,10 +729,9 @@ async def _run(cfg: dict) -> dict:
         # Launch faults armed while depth>1 launches are IN FLIGHT: a
         # wedge at pipeline depth must host-fallback every affected
         # ticket byte-identically WITHOUT losing the other in-flight
-        # groups' tickets, and the donation pool's per-slot refcounts
-        # must never recycle a live buffer (the invariant gauge stays
-        # 0).  The live OSDs' aggregators get the depth through the
-        # runtime config observer — the knob path itself is under test.
+        # groups' tickets.  The live OSDs' aggregators get the depth
+        # through the runtime config observer — the knob path itself is
+        # under test.
         import numpy as np
 
         from ceph_tpu.codec.matrix_codec import EncodeAggregator
@@ -774,17 +773,9 @@ async def _run(cfg: dict) -> dict:
         assert max_depth >= 2, (
             f"chaos: pipelined wedge never reached depth>1 ({max_depth})"
         )
-        recycled = (
-            pipe1["donation_recycled_live"]
-            - pipe0["donation_recycled_live"]
-        )
-        assert recycled == 0, (
-            f"chaos: donation pool recycled {recycled} LIVE buffer(s)"
-        )
         report["pipeline_wedge_tickets"] = len(tickets)
         report["pipeline_max_inflight_depth"] = max_depth
         report["pipeline_drains"] = pipe1["drains"] - pipe0["drains"]
-        report["donation_recycled_live"] = recycled
         report["events"].append("pipelined wedge recovered byte-identical")
 
         # ---- phase 3.8: offload fallback — csum + compressor (ISSUE 20) -
@@ -1289,7 +1280,7 @@ async def _run(cfg: dict) -> dict:
         # leaked bytes once the EC pipelines drain — host-fallback and
         # sticky-error launches released their holds too, or this
         # assertion names the bytes they kept
-        from ceph_tpu.codec.matrix_codec import drain_all_aggregators
+        from ceph_tpu.ops.offload_runtime import drain_all_aggregators
 
         drain_all_aggregators()
         report["hbm_peak_bytes"] = hbm.peak_total_bytes()

@@ -46,12 +46,6 @@ METRICS: dict[str, tuple[tuple[str, ...], str, bool]] = {
     "rs_8_3_decode_GBps_per_chip": (("decode",), "higher", True),
     "rs_8_3_verify_GBps_per_chip": (("verify",), "higher", True),
     "rs_8_3_encode_GBps_per_chip_pipelined": (("pipelined",), "higher", True),
-    # fusion trajectory (ISSUE 18): aggregated end-to-end throughput
-    # with super-launch fusion armed (multi-submitter backlog), and the
-    # bucketed pad learner's steady-state waste fraction — waste is
-    # lower-is-better and platform-independent (a stripe-count ratio)
-    "rs_8_3_encode_GBps_per_chip_fused": (("fused",), "higher", True),
-    "padding_waste_ratio": (("pad_waste",), "lower", False),
     "rs_8_3_encode_GBps_aggregate": (("multichip",), "higher", True),
     "rs_8_3_decode_GBps_aggregate": (("multichip", "decode"), "higher", True),
     "chaos_p99_ms": (("chaos", "chaos_p99_ms"), "lower", False),

@@ -60,7 +60,7 @@ def _hbm_leak_gate():
 
     leaked = _held()
     if leaked:
-        from ceph_tpu.codec.matrix_codec import drain_all_aggregators
+        from ceph_tpu.ops.offload_runtime import drain_all_aggregators
 
         try:
             drain_all_aggregators()

@@ -2,7 +2,7 @@
 
 Covers: ticket ordering, window/byte-budget/explicit flush triggers, the
 "64 stripes across 8 submitters <= 2 device dispatches" launch-counter
-invariant, padding correctness, the donation pool, flush-on-commit through
+invariant, padding correctness, flush-on-commit through
 a full ECBackend write pipeline, and the prometheus export of the
 occupancy/launch-size histograms."""
 
@@ -137,36 +137,19 @@ class TestAggregatorCore:
         assert np.array_equal(shards[4].reshape(-1, 4096), want[:, 0, :])
         assert shards[4].size == 3 * 4096
 
-    def test_donation_pool_recycled_across_launches(self):
-        # multi-ticket groups go through the pooled (forced-copy) path;
-        # two rounds at the same padded shape exercise buffer reuse
-        agg = EncodeAggregator(window=100)
-        for round_seed in (0, 2):
-            pends = [
-                (
-                    d := payload(self.sinfo, 2, seed=round_seed + i),
-                    stripe_mod.encode_launch(self.sinfo, self.ec, d, aggregator=agg),
-                )
-                for i in range(2)
-            ]
-            agg.flush()
-            for data, pend in pends:
-                shards = pend.result()  # materialization recycles the buffer
-                want = parity_oracle(self.ec, data, self.sinfo)
-                assert np.array_equal(shards[5].reshape(-1, 4096), want[:, 1, :])
-        # pool holds exactly the one (4, 2, 4096) parity buffer shape
-        assert list(agg._donate_pool) == [(4, 2, 4096)]
-
-    def test_single_ticket_unpadded_group_skips_pool(self):
-        """The default-path optimization: a lone submission's parity is
-        handed through without the forced host copy or pool recycling."""
+    def test_single_unpadded_ticket_gets_launch_host_bytes(self):
+        """The default path: a lone submission in immediate mode is its
+        own unpadded launch, and its ticket resolves to that launch's
+        host bytes — no concatenate, no pad, no slice copy."""
         agg = EncodeAggregator(window=0)
         data = payload(self.sinfo, 4, seed=7)
-        pend = stripe_mod.encode_launch(self.sinfo, self.ec, data, aggregator=agg)
-        shards = pend.result()
-        want = parity_oracle(self.ec, data, self.sinfo)
-        assert np.array_equal(shards[4].reshape(-1, 4096), want[:, 0, :])
-        assert not agg._donate_pool
+        ticket = agg.submit(self.ec, data.reshape(-1, 4, 4096))
+        parity = ticket.result()
+        group = ticket._group
+        assert group.pad == 0 and agg.perf.get("pad_stripes") == 0
+        assert parity.shape == group.host.shape
+        assert np.shares_memory(parity, group.host)
+        assert np.array_equal(parity, parity_oracle(self.ec, data, self.sinfo))
 
     def test_immediate_mode_still_counts_metrics(self):
         agg = EncodeAggregator(window=0)
@@ -272,7 +255,7 @@ class TestAggregatorRobustness:
         real = self.ec.encode_array
         real_host = self.ec.encode_array_host
 
-        def boom(data, out=None):
+        def boom(data):
             # both the device dispatch AND the host-oracle fallback fail:
             # only then is the error sticky (a device-only failure now
             # completes on the host, ISSUE 7)
@@ -315,7 +298,7 @@ class TestAggregatorRobustness:
         real = primary.ec.encode_array
         real_host = primary.ec.encode_array_host
 
-        def boom(data, out=None):
+        def boom(data):
             # device AND host-oracle failure: the truly-unrecoverable case
             raise RuntimeError("injected launch failure")
 
@@ -365,10 +348,10 @@ class TestAggregatorRobustness:
         real = primary.ec.encode_array
         real_host = primary.ec.encode_array_host
 
-        def boom_two_stripes(data, out=None):
+        def boom_two_stripes(data):
             if data.shape[0] == 2:  # only W1's 2-stripe group fails
                 raise RuntimeError("injected launch failure")
-            return real(data, out=out)
+            return real(data)
 
         def boom_two_stripes_host(data):
             if data.shape[0] == 2:  # the host fallback fails identically
@@ -429,10 +412,10 @@ class TestAggregatorRobustness:
         real_host = primary.ec.encode_array_host
         armed = [True]
 
-        def boom_once(data, out=None):
+        def boom_once(data):
             if armed[0]:
                 raise RuntimeError("injected launch failure")
-            return real(data, out=out)
+            return real(data)
 
         def boom_once_host(data):
             # the host fallback fails the same launch, then disarms: the
@@ -492,10 +475,10 @@ class TestAggregatorRobustness:
         real_host = primary.ec.encode_array_host
         armed = [False]
 
-        def boom_when_armed(data, out=None):
+        def boom_when_armed(data):
             if armed[0]:
                 raise RuntimeError("injected launch failure")
-            return real(data, out=out)
+            return real(data)
 
         def boom_when_armed_host(data):
             if armed[0]:

@@ -61,12 +61,10 @@ class TestChaosSmoke:
         assert report["fallback_launches"] >= 1, report
         # ISSUE 11: the pipelined-wedge phase armed launch faults while
         # depth>1 launches were in flight — every ticket recovered
-        # byte-identically (asserted inside the phase), the ring
-        # provably ran deeper than one launch, and the donation pool
-        # never recycled a live buffer
+        # byte-identically (asserted inside the phase) and the ring
+        # provably ran deeper than one launch
         assert report["pipeline_wedge_tickets"] >= 4, report
         assert report["pipeline_max_inflight_depth"] >= 2, report
-        assert report["donation_recycled_live"] == 0, report
         # 4 launches through a depth-2 ring MUST overflow it: a zero here
         # means _drain_pipeline silently stopped bounding the ring
         assert report["pipeline_drains"] >= 1, report

@@ -198,7 +198,7 @@ class TestDecodeAggregatorCore:
         real = self.ec.decode_array
         real_host = self.ec.decode_array_host
 
-        def boom(erasures, survivors, out=None):
+        def boom(erasures, survivors):
             # device AND host-oracle failure: only then is the error
             # sticky (a device-only failure now completes on the host)
             raise RuntimeError("injected device OOM")
@@ -368,7 +368,7 @@ class TestBackendAggregatedRecovery:
         real = primary.ec.decode_array
         real_host = primary.ec.decode_array_host
 
-        def boom(erasures, survivors, out=None):
+        def boom(erasures, survivors):
             # fails on the device AND the host oracle: truly unrecoverable
             raise RuntimeError("injected decode launch failure")
 

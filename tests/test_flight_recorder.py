@@ -182,9 +182,9 @@ class TestAggregatedLaunchRecords:
         DEGRADED, probe gated) is flagged degraded_bypass."""
         real = self.ec.encode_array
 
-        def wedge(arr, out=None):
+        def wedge(arr):
             time.sleep(0.5)
-            return real(arr, out=out)
+            return real(arr)
 
         device_guard().configure(timeout_ms=50, probe_interval_ms=10_000_000)
         # burn the immediate post-degrade probe allowance up front so the
@@ -227,7 +227,7 @@ class TestAggregatedLaunchRecords:
         real_dev = self.ec.encode_array
         real_host = self.ec.encode_array_host
 
-        def boom(arr, out=None):
+        def boom(arr):
             raise RuntimeError("dev boom")
 
         def boom_host(arr):

@@ -1,17 +1,18 @@
-"""ISSUE 18 tier-1 coverage: super-launch fusion, bucketed pad
-specialization, and the on-device RMW delta path.
+"""Windowed launches under a backlog and the on-device RMW delta path
+(ISSUE 18).
 
-- Byte-identity of fused multi-window launches vs the host oracle
-  across RS(4,2)/RS(8,3) with ragged per-ticket batch sizes.
-- The all-wedged fault matrix with fusion armed: a fused group must
-  split byte-identically per ticket through the host oracle whatever
-  way the device dies (dispatch fault, wedged timeout, pre-degraded).
+- Byte-identity of windowed launches behind a full in-flight ring vs the
+  host oracle across RS(4,2)/RS(8,3) with ragged per-ticket batch sizes:
+  every window that trips launches at once.
+- The all-wedged fault matrix on windowed groups: a padded multi-ticket
+  group must split byte-identically per ticket through the host oracle
+  whatever way the device dies (dispatch fault, wedged timeout,
+  pre-degraded).
 - The RMW delta program vs the full-encode oracle (host and device
   forms), and the end-to-end delta write path on an EC-overwrites pool
   including ragged tails and armed `codec.launch` faults.
-- Leak gates: `pipeline.donation_recycled_live` and the EC in-flight
-  mempool stay clean with fusion + delta enabled, and pad-bucket churn
-  cannot pin donated buffers in the mempool ledger.
+- Leak gate: the EC in-flight mempool reads zero after drain with the
+  windowed backlog and the delta path both exercised.
 """
 
 import time
@@ -20,13 +21,13 @@ import numpy as np
 import pytest
 
 from ceph_tpu.codec import ErasureCodeTpuRs
-from ceph_tpu.codec.matrix_codec import EncodeAggregator, drain_all_aggregators
+from ceph_tpu.codec.matrix_codec import EncodeAggregator
 from ceph_tpu.common.fault_injector import global_injector
 from ceph_tpu.common.mempool import ledger as hbm_ledger
 from ceph_tpu.ops import dispatch as ec_dispatch
 from ceph_tpu.ops.device_cache import device_chunk_cache
-from ceph_tpu.ops.flight_recorder import flight_recorder
 from ceph_tpu.ops.guard import device_guard
+from ceph_tpu.ops.offload_runtime import drain_all_aggregators
 
 
 @pytest.fixture(autouse=True)
@@ -46,14 +47,14 @@ def make_rs(k, m):
 
 
 def _backlogged_aggregator():
-    """window=2 / depth=1 / fuse=4: the first window trip launches and
-    fills the ring, every later trip defers — deterministic fusion."""
+    """window=2 / depth=1: the first window trip launches and fills the
+    ring, and every later trip launches behind it — each settling the
+    oldest launch first."""
     return EncodeAggregator(
         window=2,
         max_bytes=1 << 30,
         inflight_max_bytes=1 << 30,
         pipeline_depth=1,
-        fuse_max_windows=4,
     )
 
 
@@ -63,76 +64,40 @@ def _submit_all(agg, ec, batches):
     return tickets
 
 
-class TestFusedByteIdentity:
-    """Fused multi-window launches are byte-identical to per-ticket host
-    encodes — fusion is just a bigger group, not a different program."""
+class TestWindowedBacklogByteIdentity:
+    """Windowed launches behind a full ring are byte-identical to
+    per-ticket host encodes, and each window trip launches at once."""
 
     @pytest.mark.parametrize("k,m", [(4, 2), (8, 3)])
-    def test_fused_multiwindow_launch_byte_identical(self, k, m):
+    def test_backlogged_multiwindow_launch_byte_identical(self, k, m):
         ec = make_rs(k, m)
         rng = np.random.default_rng(21)
         agg = _backlogged_aggregator()
         # ragged per-ticket stripe counts: the pad machinery zero-fills
         # around these, and the settle slices must cut exactly at the
-        # ticket boundaries inside the fused batch
+        # ticket boundaries inside each padded batch
         sizes = (1, 2, 3, 1, 2, 3, 5, 1, 2, 1)
         batches = [
             rng.integers(0, 256, (s, k, 2048), dtype=np.uint8) for s in sizes
         ]
-        f0 = agg.perf.get("fused_launches")
+        w0 = agg.perf.get("flush_window")
         tickets = _submit_all(agg, ec, batches)
         for h, t in zip(batches, tickets):
             assert np.array_equal(np.asarray(t), ec.encode_array_host(h))
-        assert agg.perf.get("fused_launches") - f0 >= 1, (
-            "backlogged window trips never fused"
+        assert agg.perf.get("flush_window") - w0 == len(sizes) // 2, (
+            "a tripped window did not launch at once"
         )
 
-    def test_fused_flight_record_flags_and_window_count(self):
-        ec = make_rs(4, 2)
-        rng = np.random.default_rng(22)
-        agg = _backlogged_aggregator()
-        batches = [
-            rng.integers(0, 256, (1, 4, 2048), dtype=np.uint8)
-            for _ in range(10)
-        ]
-        tickets = _submit_all(agg, ec, batches)
-        for t in tickets:
-            np.asarray(t)
-        fused = [
-            r for r in flight_recorder().records()
-            if r["flags"].get("fused")
-        ]
-        assert fused, "no fused flight record committed"
-        rec = fused[-1]
-        assert rec["fused_windows"] >= 2
-        assert rec["tickets"] >= 2 * 2  # at least two whole windows
 
-    def test_fused_counters_reach_perf_dump(self):
-        ec = make_rs(4, 2)
-        rng = np.random.default_rng(23)
-        agg = _backlogged_aggregator()
-        d0 = ec_dispatch.perf_dump()
-        tickets = _submit_all(agg, ec, [
-            rng.integers(0, 256, (1, 4, 2048), dtype=np.uint8)
-            for _ in range(10)
-        ])
-        for t in tickets:
-            np.asarray(t)
-        d1 = ec_dispatch.perf_dump()
-        assert d1["fused_launches"] > d0["fused_launches"]
-        assert d1["fused_windows"] >= d0["fused_windows"] + 2
-        assert "padding_waste_ratio" in d1
-
-
-class TestFusedWedgedFaultMatrix:
-    """All-wedged fault matrix with fusion armed: however the device
-    dies, a fused multi-window group completes on the host oracle and
+class TestWindowedWedgedFaultMatrix:
+    """All-wedged fault matrix on windowed groups: however the device
+    dies, a padded multi-ticket group completes on the host oracle and
     splits byte-identically per ticket."""
 
     @pytest.mark.parametrize(
         "mode", ["dispatch_fault", "wedged_timeout", "pre_degraded"]
     )
-    def test_fused_group_host_fallback_byte_identical(self, mode):
+    def test_windowed_group_host_fallback_byte_identical(self, mode):
         ec = make_rs(4, 2)
         rng = np.random.default_rng(31)
         agg = _backlogged_aggregator()
@@ -141,15 +106,16 @@ class TestFusedWedgedFaultMatrix:
             rng.integers(0, 256, (s, 4, 2048), dtype=np.uint8) for s in sizes
         ]
         hf0 = agg.perf.get("host_fallbacks")
-        f0 = agg.perf.get("fused_launches")
+        w0 = agg.perf.get("flush_window")
+        pad0 = agg.perf.get("pad_stripes")
         real = ec.encode_array
         if mode == "dispatch_fault":
             global_injector().inject("codec.launch", 5, hits=100)
         elif mode == "wedged_timeout":
 
-            def wedge(arr, out=None):
+            def wedge(arr):
                 time.sleep(0.3)  # well past the 50 ms deadline below
-                return real(arr, out=out)
+                return real(arr)
 
             device_guard().configure(timeout_ms=50)
             ec.encode_array = wedge
@@ -169,9 +135,10 @@ class TestFusedWedgedFaultMatrix:
             ec.encode_array = real
             global_injector().clear()
         assert agg.perf.get("host_fallbacks") > hf0, mode
-        assert agg.perf.get("fused_launches") - f0 >= 1, (
-            f"{mode}: the fault matrix never exercised a FUSED launch"
-        )
+        assert agg.perf.get("flush_window") - w0 == len(sizes) // 2, mode
+        # (1,3) (2,2) (1,1) (2,3) -> 4, 4, 2, 5 stripes: the 5-stripe
+        # window pads to 8, so the fallback split a padded group
+        assert agg.perf.get("pad_stripes") - pad0 == 3, mode
         if mode != "pre_degraded":
             assert device_guard().degraded, mode
 
@@ -327,12 +294,12 @@ class TestRmwDeltaEndToEnd:
             self._teardown_cache(cc)
 
 
-class TestFusionDeltaLeakGates:
-    """The ISSUE 18 chaos invariant at tier-1 scope: with fusion and the
-    delta path both exercised, donation_recycled_live does not move and
-    the EC in-flight mempool drains to zero."""
+class TestWindowedDeltaLeakGates:
+    """The ISSUE 18 chaos invariant at tier-1 scope: with the windowed
+    backlog and the delta path both exercised, the EC in-flight mempool
+    reads zero after drain."""
 
-    def test_recycled_live_and_inflight_ledger_stay_clean(self):
+    def test_inflight_ledger_reads_zero_after_drain(self):
         from test_ec_backend import (
             FLAG_EC_OVERWRITES,
             Cluster,
@@ -340,12 +307,11 @@ class TestFusionDeltaLeakGates:
             payload,
         )
 
-        d0 = ec_dispatch.perf_dump()["pipeline.donation_recycled_live"]
         cc = device_chunk_cache()
         cc.configure(max_bytes=1 << 24)
         cc.clear()
         try:
-            # fused workload
+            # windowed backlog workload
             ec = make_rs(4, 2)
             rng = np.random.default_rng(51)
             agg = _backlogged_aggregator()
@@ -355,7 +321,7 @@ class TestFusionDeltaLeakGates:
             ]
             for t in _submit_all(agg, ec, batches):
                 np.asarray(t)
-            assert agg.perf.get("fused_launches") >= 1
+            assert agg.perf.get("flush_window") >= 1
             # delta workload
             pool, profiles = ec_pool(4, 2, flags=FLAG_EC_OVERWRITES)
             c = Cluster(pool, profiles)
@@ -372,61 +338,6 @@ class TestFusionDeltaLeakGates:
             )
         drain_all_aggregators()
         led = hbm_ledger()
-        assert (
-            ec_dispatch.perf_dump()["pipeline.donation_recycled_live"] == d0
-        ), "fusion/delta recycled a LIVE donated buffer"
         assert led.current_bytes("ec_pipeline_inflight") == 0, (
             led.reconcile()
-        )
-
-
-class TestDonationBucketChurn:
-    """Bucket churn cannot pin HBM (ISSUE 18 satellite): shrinking the
-    learned bucket set must trim the evicted shapes' pooled outputs out
-    of the mempool ledger immediately."""
-
-    def test_bucket_shrink_trims_pooled_shapes_from_ledger(self):
-        ec = make_rs(4, 2)
-        rng = np.random.default_rng(61)
-        agg = EncodeAggregator(
-            window=2,
-            max_bytes=1 << 30,
-            inflight_max_bytes=1 << 30,
-            pipeline_depth=2,
-            fuse_max_windows=1,
-            pad_buckets=4,
-        )
-        led = hbm_ledger()
-        pooled0 = led.current_bytes("ec_donation")
-        # recurring ragged group sizes (6 and 10 stripes): the learner
-        # promotes both to exact-fit targets, and the donation pool
-        # retains parity outputs at those geometries.  Chunk length 4096
-        # keeps even the exact-fit launches above PACKED_MIN_BYTES, so
-        # they stay on the donatable packed path.
-        for _ in range(5):
-            for s in (3, 5):
-                t1 = agg.submit(
-                    ec, rng.integers(0, 256, (s, 4, 4096), dtype=np.uint8)
-                )
-                t2 = agg.submit(
-                    ec, rng.integers(0, 256, (s, 4, 4096), dtype=np.uint8)
-                )
-                agg.flush()
-                np.asarray(t1)
-                np.asarray(t2)
-        drain_all_aggregators()
-        learned = {s[0] for s in agg._donate_pool}
-        assert {6, 10} & learned, (
-            f"no exact-fit shapes pooled (got {learned}); the bucket "
-            "learner or the donation pool regressed"
-        )
-        pooled = led.current_bytes("ec_donation")
-        assert pooled > pooled0, "no donated bytes pooled; premise broken"
-        # retire every learned bucket: the evicted targets' pooled
-        # outputs must leave the ledger NOW, not at process exit
-        agg.configure(pad_buckets=0)
-        assert led.current_bytes("ec_donation") < pooled
-        remaining = {s[0] for s in agg._donate_pool}
-        assert not ({6, 10} & remaining), (
-            f"evicted bucket shapes still pooled: {remaining}"
         )

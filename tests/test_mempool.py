@@ -1,14 +1,13 @@
 """HBM mempool ledger (ISSUE 13): accounting, reconciliation, pressure
 staging, and the health pipeline.
 
-Acceptance shape: every HBM holder (donation pool, pipeline in-flight
-ring, device chunk cache, sharded placements) accounts its bytes in the
-process-wide ledger; ledger totals reconcile against the sum of live
-tracked-buffer nbytes under the 8-concurrent-submitter harness at
-pipeline depth 4 with faults armed (host-fallback and sticky-error
-settles release their holds); the pressure layer trims cache → donation
-retention → pipeline depth in order and raises/clears TPU_HBM_PRESSURE
-through the mon; and the device cache's cap-shrink observer recomputes
+Acceptance shape: every HBM holder (pipeline in-flight ring, device
+chunk cache, sharded placements) accounts its bytes in the process-wide
+ledger; ledger totals reconcile against the sum of live tracked-buffer
+nbytes under the 8-concurrent-submitter harness at pipeline depth 4
+with faults armed (host-fallback and sticky-error settles release their
+holds); the pressure layer trims cache → pipeline depth in order and
+raises/clears TPU_HBM_PRESSURE through the mon; and the device cache's cap-shrink observer recomputes
 resident bytes from the entry index instead of trusting a drifted
 counter."""
 
@@ -22,7 +21,6 @@ import pytest
 from ceph_tpu.codec import ErasureCodeTpuRs
 from ceph_tpu.codec.matrix_codec import (
     DecodeAggregator,
-    DonationPool,
     EncodeAggregator,
     VerifyAggregator,
 )
@@ -56,18 +54,18 @@ def make_rs(k=4, m=2):
 class TestLedgerCore:
     def test_alloc_resize_free_and_peaks(self):
         led = MempoolLedger()
-        h = led.alloc("ec_donation", 1000)
-        assert led.current_bytes("ec_donation") == 1000
+        h = led.alloc("ec_pipeline_inflight", 1000)
+        assert led.current_bytes("ec_pipeline_inflight") == 1000
         h.resize(4000)
-        assert led.current_bytes("ec_donation") == 4000
-        snap = led.snapshot()["ec_donation"]
+        assert led.current_bytes("ec_pipeline_inflight") == 4000
+        snap = led.snapshot()["ec_pipeline_inflight"]
         assert snap["peak_bytes"] == 4000 and snap["buffers"] == 1
         h.free()
         h.free()  # idempotent: the finalizer double-release shape
-        assert led.current_bytes("ec_donation") == 0
-        assert led.snapshot()["ec_donation"]["peak_bytes"] == 4000
+        assert led.current_bytes("ec_pipeline_inflight") == 0
+        assert led.snapshot()["ec_pipeline_inflight"]["peak_bytes"] == 4000
         led.reset_peaks()
-        assert led.snapshot()["ec_donation"]["peak_bytes"] == 0
+        assert led.snapshot()["ec_pipeline_inflight"]["peak_bytes"] == 0
 
     def test_predeclared_pools_and_dynamic_creation(self):
         led = MempoolLedger()
@@ -155,7 +153,7 @@ class TestLedgerCore:
         assert led.peak_total_bytes() <= 3 * 1024, led.peak_total_bytes()
 
     def test_explicit_free_detaches_the_finalizer(self):
-        """A recycled buffer (donation pool) gets a fresh handle per
+        """A buffer accounted more than once gets a fresh handle per
         cycle; the explicit free must detach the old finalizer or the
         buffer pins one dead handle per cycle for its lifetime."""
         import jax.numpy as jnp
@@ -197,37 +195,6 @@ class TestLedgerCore:
         h = led2.alloc("scratch", 100)
         h.devices = ("a", "b", "c")
         assert sum(led2.per_device().values()) == 100
-
-
-class TestDonationPoolAccounting:
-    def test_put_take_and_overflow_track_ec_donation(self):
-        import jax.numpy as jnp
-
-        led = ledger()
-        base = led.current_bytes("ec_donation")
-        pool = DonationPool(cap=2)
-        bufs = [jnp.zeros(1024, dtype=jnp.uint8) + i for i in range(3)]
-        pool.put((1024,), bufs[0])
-        pool.put((1024,), bufs[1])
-        assert led.current_bytes("ec_donation") == base + 2048
-        pool.put((1024,), bufs[2])  # overflow: oldest out
-        assert led.current_bytes("ec_donation") == base + 2048
-        assert pool.take((1024,)) is not None
-        assert led.current_bytes("ec_donation") == base + 1024
-        assert pool.drop_free() == 1024
-        assert led.current_bytes("ec_donation") == base
-
-    def test_dead_pool_cannot_leak(self):
-        import jax.numpy as jnp
-
-        led = ledger()
-        base = led.current_bytes("ec_donation")
-        pool = DonationPool(cap=2)
-        pool.put((512,), jnp.zeros(512, dtype=jnp.uint8))
-        assert led.current_bytes("ec_donation") == base + 512
-        del pool
-        gc.collect()  # buffer finalizer closes the handle
-        assert led.current_bytes("ec_donation") == base
 
 
 class TestDeviceCacheAccounting:
@@ -288,10 +255,8 @@ class TestReconciliationUnderLoad:
         faults armed (1-in-3 dispatches fail to the host oracle).  After
         drain the in-flight pools read zero — the host-fallback path
         released its holds — the handle registry reconciles against the
-        counters with zero drift, and the donation pools' ledger bytes
-        equal the sum of the actually-pooled buffers' nbytes."""
+        counters with zero drift."""
         led = ledger()
-        base_donation = led.current_bytes("ec_donation")
         ec = make_rs(4, 2)
         agg = EncodeAggregator(window=4, pipeline_depth=4)
         dagg = DecodeAggregator(window=4, pipeline_depth=4)
@@ -305,8 +270,7 @@ class TestReconciliationUnderLoad:
             try:
                 for i in range(5):
                     # >= PACKED_MIN_BYTES so the coalesced launches take
-                    # the donatable packed path — the donation pool and
-                    # its ledger accounting are part of what reconciles
+                    # the packed bulk kernel
                     data = rng.integers(0, 256, (4, 4, 4096), dtype=np.uint8)
                     par = np.asarray(agg.submit(ec, data))
                     assert np.array_equal(
@@ -341,44 +305,6 @@ class TestReconciliationUnderLoad:
             k: v["drift"] for k, v in led.reconcile().items() if v["drift"]
         }
         assert not drift, drift
-        pooled = 0
-        for a in (agg, dagg, vagg):
-            with a._lock:
-                pooled += sum(
-                    int(b.nbytes)
-                    for slot in a._donate_pool._free.values()
-                    for b in slot
-                )
-        assert led.current_bytes("ec_donation") - base_donation == pooled
-
-    def test_donatable_settle_moves_hold_without_double_count(self):
-        """A donatable launch's output moves from the in-flight pool to
-        ec_donation at settle — the in-flight hold must release BEFORE
-        the donation charge, or the same bytes count twice and inflate
-        the peak gauges."""
-        led = ledger()
-        ec = make_rs(4, 2)
-        agg = EncodeAggregator(window=2, pipeline_depth=1)
-        data = np.zeros((4, 4, 4096), dtype=np.uint8)  # >= packed min
-        assert ec.encode_donatable((8, 4, 4096))
-        base_total = led.total_device_bytes()
-        base_donation = led.current_bytes("ec_donation")
-        led.reset_peaks()
-        tickets = [agg.submit(ec, data), agg.submit(ec, data)]
-        for t in tickets:
-            np.asarray(t)
-        agg.flush()
-        agg.drain()
-        assert led.current_bytes("ec_pipeline_inflight") == 0
-        parity_nbytes = 8 * 2 * 4096
-        assert led.current_bytes("ec_donation") - base_donation == \
-            parity_nbytes
-        # peak saw ONE accounting of the output (plus small scratch) —
-        # a double count at the settle handoff would have spiked it to
-        # ~2x the parity size
-        assert led.peak_total_bytes() - base_total < int(1.5 * parity_nbytes)
-        with agg._lock:
-            agg._donate_pool.drop_free()
 
     def test_sticky_error_settle_releases_hold(self, monkeypatch):
         """A launch that fails on the device AND on the host recompute
@@ -407,18 +333,14 @@ class TestReconciliationUnderLoad:
 
 
 class TestPressureStaging:
-    def test_trim_order_cache_then_donation_then_depth(self):
+    def test_trim_order_cache_then_depth(self):
         """One evaluation with an un-trimmable hold big enough that no
         stage relieves it: the cache gives its bytes back first, then
-        donation retention caps, then the effective depth clamps — and
-        relief releases everything."""
-        import jax.numpy as jnp
-
+        the effective depth clamps — and relief releases everything."""
         led = ledger()
         cc = device_chunk_cache()
         old_max = cc.max_bytes
         pin = None
-        pool = DonationPool(cap=2)
         try:
             cc.configure(max_bytes=1 << 20)
             cc.put("press/obj", 0, 1, np.zeros(64 << 10, dtype=np.uint8))
@@ -433,32 +355,25 @@ class TestPressureStaging:
             untrimmable = (
                 led.total_device_bytes()
                 - led.current_bytes("device_cache")
-                - led.current_bytes("ec_donation")
             )
             led.configure(target_bytes=max(1, untrimmable // 2))
             st = led.check_pressure()
-            assert st["pressure"] and st["stage"] == 3, st
+            assert st["pressure"] and st["stage"] == 2, st
+            assert st["stage_name"] == "depth-clamp", st
             # stage 1 ran: the cache was trimmed to relieve first
             assert st["actions"]["cache_trimmed_bytes"] >= cache_before
             assert cc.perf_dump()["resident_bytes"] == 0
-            # stage 2: retention capped — a put no longer pools
-            assert led.donation_capped
-            pool.put((512,), jnp.zeros(512, dtype=jnp.uint8))
-            assert len(pool) == 0
-            # stage 3: depth clamped
+            # stage 2: depth clamped
             assert led.depth_clamped
             # relief: free the hold, next evaluation clears everything
             pin.free()
             pin = None
             st = led.check_pressure()
             assert not st["pressure"] and st["stage"] == 0, st
-            assert not led.donation_capped and not led.depth_clamped
-            pool.put((512,), jnp.zeros(512, dtype=jnp.uint8))
-            assert len(pool) == 1
+            assert not led.depth_clamped
         finally:
             if pin is not None:
                 pin.free()
-            pool.drop_free()
             led.configure(target_bytes=0)
             led.check_pressure()
             cc.configure(max_bytes=old_max)
@@ -481,9 +396,9 @@ class TestPressureStaging:
 
     def test_concurrent_evaluations_never_strand_the_caps(self):
         """Racing check_pressure calls must serialize: an evaluation
-        arming the caps interleaved with one clearing the raised state
-        would leave donation retention silently disabled with no health
-        check raised.  Invariant on every snapshot: caps armed implies
+        arming the clamp interleaved with one clearing the raised state
+        would leave the pipeline depth silently clamped with no health
+        check raised.  Invariant on every snapshot: clamp armed implies
         pressure raised."""
         led = MempoolLedger(target_bytes=1000)
         stop = threading.Event()
@@ -501,9 +416,7 @@ class TestPressureStaging:
                 h.free()                        # ratio 0.0: clear
                 led.check_pressure()
                 st = led.pressure_status()
-                assert st["pressure"] or not (
-                    st["donation_capped"] or st["depth_clamped"]
-                ), st
+                assert st["pressure"] or not st["depth_clamped"], st
         finally:
             stop.set()
             t.join()
@@ -544,7 +457,6 @@ class TestPressureHealthPipeline:
                 untrimmable = (
                     led.total_device_bytes()
                     - led.current_bytes("device_cache")
-                    - led.current_bytes("ec_donation")
                 )
                 # the runtime-observer path IS under test: the config
                 # set must reach the live ledger through the OSD's
@@ -566,8 +478,7 @@ class TestPressureHealthPipeline:
                 )
                 assert "TPU_HBM_PRESSURE" in mgr.health_checks()
                 # the staged response engaged all the way (the hold is
-                # un-trimmable, so cache trim + donation cap could not
-                # relieve it)
+                # un-trimmable, so the cache trim could not relieve it)
                 assert led.depth_clamped
                 # relief: free the hold; the next beacons re-evaluate
                 # and both surfaces clear
@@ -579,7 +490,7 @@ class TestPressureHealthPipeline:
                     return "TPU_HBM_PRESSURE" not in checks
                 await wait_until(cleared, 10.0, "TPU_HBM_PRESSURE cleared")
                 assert "TPU_HBM_PRESSURE" not in mgr.health_checks()
-                assert not led.depth_clamped and not led.donation_capped
+                assert not led.depth_clamped
             finally:
                 if pin is not None:
                     pin.free()
@@ -642,8 +553,8 @@ class TestSurfacing:
         flight_recorder().reset()
 
     def test_dump_mempools_reconciles_with_holders(self):
-        """The acceptance equality on the asok payload: with cache,
-        donation, and scratch holders live, dump_mempools pool totals
+        """The acceptance equality on the asok payload: with cache and
+        scratch holders live, dump_mempools pool totals
         equal the holders' own live-buffer nbytes."""
         import jax.numpy as jnp
 

@@ -1,9 +1,10 @@
 """Device-offload runtime (ISSUE 20): the service registry that names
-every LaunchAggregator-backed offload service, the refactor guard
-pinning `codec/matrix_codec` to its `ops/offload_runtime` re-exports
-(the promotion must be a pure move — same objects, same behavior), and
-the device crc32c service's byte-identity against `utils/crc32c` across
-block sizes, ragged tails, fault injection and the DEGRADED bypass."""
+every LaunchAggregator-backed offload service, the runtime's one public
+home for the aggregation engine (`ops/offload_runtime`; the codec keeps
+no re-export shim), the one launch path's static pad buckets and
+in-flight pool drains, and the device crc32c service's byte-identity
+against `utils/crc32c` across block sizes, ragged tails, fault
+injection and the DEGRADED bypass."""
 
 import numpy as np
 import pytest
@@ -91,20 +92,39 @@ class TestServiceRegistry:
 
 
 class TestRefactorGuard:
-    def test_matrix_codec_reexports_are_the_runtime_objects(self):
-        """The promotion to ops/offload_runtime was a pure move: every
-        name matrix_codec still exports must BE the runtime's object,
-        not a copy — two class objects would mean two donation pools,
-        two aggregator registries, two drain scopes."""
+    def test_engine_names_import_from_the_runtime(self):
+        """The aggregation engine has one home: every name its callers
+        use imports from ops/offload_runtime, and the codec module does
+        not re-export the runtime-wide names (two import paths invite two
+        registries)."""
         from ceph_tpu.codec import matrix_codec as mc
-        from ceph_tpu.ops import offload_runtime as rt
+        from ceph_tpu.ops.offload_runtime import (
+            _AGGREGATORS,
+            AggTicket,
+            LaunchAggregator,
+            OffloadService,
+            _AggGroup,
+            drain_all_aggregators,
+            offload_perf_dump,
+            offload_services,
+            register_service,
+            service,
+            service_aggregator,
+        )
 
-        assert mc.LaunchAggregator is rt.LaunchAggregator
-        assert mc.AggTicket is rt.AggTicket
-        assert mc.DonationPool is rt.DonationPool
-        assert mc._AggGroup is rt._AggGroup
-        assert mc.drain_all_aggregators is rt.drain_all_aggregators
-        assert mc.drop_donation_retention is rt.drop_donation_retention
+        assert issubclass(mc.EncodeAggregator, LaunchAggregator)
+        assert isinstance(service("encode"), OffloadService)
+        assert service_aggregator("encode") in _AGGREGATORS
+        assert "encode" in offload_services()
+        assert "encode.launches" in offload_perf_dump()
+        for name in (AggTicket, _AggGroup, drain_all_aggregators,
+                     register_service):
+            assert callable(name)
+        # the codec imports only what its aggregators subclass and
+        # annotate with; the runtime-wide names stay in the runtime
+        for name in ("_AGGREGATORS", "drain_all_aggregators",
+                     "offload_perf_dump", "service_aggregator"):
+            assert name not in vars(mc), name
 
     def test_every_service_aggregator_subclasses_the_runtime_base(self):
         from ceph_tpu.codec.matrix_codec import (
@@ -130,6 +150,113 @@ class TestRefactorGuard:
         assert np.array_equal(
             np.asarray(ticket.result()), crc32c_host_rows(blocks)
         )
+
+
+def _rs(k=4, m=2):
+    from ceph_tpu.codec import ErasureCodeTpuRs
+
+    ec = ErasureCodeTpuRs()
+    ec.init({"k": str(k), "m": str(m)})
+    return ec
+
+
+def _split(n: int) -> list[int]:
+    """Ticket sizes summing to `n`: two tickets (one window) when n > 1."""
+    return [n] if n == 1 else [n // 2, n - n // 2]
+
+
+class TestOneLaunchPath:
+    """The runtime's single launch path: windowed groups pad to the
+    static `_pad_target` bucket and stay byte-identical to the host
+    oracle on both sides of every bucket edge, and every service's
+    in-flight HBM pool reads zero after `drain()` in both modes."""
+
+    @pytest.mark.parametrize("stripes", [1, 3, 63, 64, 65, 129])
+    def test_windowed_encode_static_bucket_byte_identical(self, stripes):
+        from ceph_tpu.codec.matrix_codec import EncodeAggregator
+
+        ec = _rs()
+        rng = np.random.default_rng(stripes)
+        agg = EncodeAggregator(window=2, max_bytes=1 << 30)
+        batches = [
+            rng.integers(0, 256, (s, 4, 256), dtype=np.uint8)
+            for s in _split(stripes)
+        ]
+        tickets = [agg.submit(ec, b) for b in batches]
+        agg.flush()
+        for b, t in zip(batches, tickets):
+            assert np.array_equal(np.asarray(t), ec.encode_array_host(b))
+        assert agg.perf.get("launches") == 1
+        assert agg.perf.get("pad_stripes") == (
+            agg._pad_target(stripes) - stripes
+        )
+
+    @pytest.mark.parametrize("stripes", [1, 3, 63, 64, 65, 129])
+    def test_windowed_decode_static_bucket_byte_identical(self, stripes):
+        from ceph_tpu.codec.matrix_codec import DecodeAggregator
+
+        ec = _rs()
+        rng = np.random.default_rng(100 + stripes)
+        erasures = [1, 4]
+        idx = ec.decode_index(erasures)
+        agg = DecodeAggregator(window=2, max_bytes=1 << 30)
+        fulls = []
+        for s in _split(stripes):
+            data = rng.integers(0, 256, (s, 4, 256), dtype=np.uint8)
+            fulls.append(
+                np.concatenate([data, ec.encode_array_host(data)], axis=1)
+            )
+        tickets = [agg.submit(ec, erasures, f[:, idx, :]) for f in fulls]
+        agg.flush()
+        for f, t in zip(fulls, tickets):
+            assert np.array_equal(np.asarray(t), f[:, erasures, :])
+            assert np.array_equal(
+                np.asarray(t), ec.decode_array_host(erasures, f[:, idx, :])
+            )
+        assert agg.perf.get("launches") == 1
+        assert agg.perf.get("pad_stripes") == (
+            agg._pad_target(stripes) - stripes
+        )
+
+    @pytest.mark.parametrize("window", [0, 4], ids=["immediate", "windowed"])
+    @pytest.mark.parametrize("name", ["encode", "decode", "verify", "csum"])
+    def test_inflight_pool_reads_zero_after_drain(self, name, window):
+        from ceph_tpu.codec.matrix_codec import (
+            DecodeAggregator,
+            EncodeAggregator,
+            VerifyAggregator,
+        )
+        from ceph_tpu.common.mempool import ledger
+
+        ec = _rs()
+        rng = np.random.default_rng(7)
+        data = rng.integers(0, 256, (2, 4, 512), dtype=np.uint8)
+        full = np.concatenate([data, ec.encode_array_host(data)], axis=1)
+        erasures = [0, 5]
+        cls, submit = {
+            "encode": (EncodeAggregator, lambda a: a.submit(ec, data)),
+            "decode": (
+                DecodeAggregator,
+                lambda a: a.submit(
+                    ec, erasures, full[:, ec.decode_index(erasures), :]
+                ),
+            ),
+            "verify": (VerifyAggregator, lambda a: a.submit(ec, full)),
+            "csum": (
+                ChecksumAggregator,
+                lambda a: a.submit_blocks(data.reshape(2, -1)),
+            ),
+        }[name]
+        agg = cls(window=window, pipeline_depth=8)
+        led = ledger()
+        tickets = [submit(agg) for _ in range(4)]
+        # launched and unsettled: the pool holds the outputs in flight
+        assert led.current_bytes(agg.MEM_POOL) > 0, name
+        agg.drain()
+        assert led.current_bytes(agg.MEM_POOL) == 0, led.reconcile()
+        assert not agg._live and agg.pending() == 0
+        for t in tickets:
+            t.result()
 
 
 class TestDeviceCrc32c:

@@ -15,7 +15,7 @@ from ceph_tpu.codec import ErasureCodeTpuRs
 from ceph_tpu.gf import isa_decode_matrix, isa_rs_vandermonde_matrix
 from ceph_tpu.gf.bitslice import expand_matrix, xor_matmul_host
 from ceph_tpu.ops.dispatch import LAUNCHES
-from ceph_tpu.ops.packed_gf import PackedPlan, _packed_code_into, plane_schedule
+from ceph_tpu.ops.packed_gf import PackedPlan, plane_schedule
 from ceph_tpu.ops.xor_mm import xor_matmul
 
 
@@ -68,29 +68,6 @@ class TestParityEquivalence:
         sched = plane_schedule(gfm)
         assert sched[0] == ((0, 0), (1, 1))  # 1 -> bit 0; 2 -> bit 1
         assert sched[1] == tuple((1, b) for b in range(8))  # 255 -> all bits
-
-    def test_donating_variant_identical_bytes(self):
-        import jax.numpy as jnp
-
-        gfm = rs_matrix(4, 2)
-        plan = PackedPlan(gfm)
-        data = rand_data((3, 4, 128), seed=5)
-        plain = np.asarray(plan(data))
-        dead = jnp.zeros((3, 2, 128), jnp.uint8)
-        donated = np.asarray(
-            _packed_code_into(dead, jnp.asarray(data), sched=plan.sched, k=4, m=2)
-        )
-        assert np.array_equal(plain, donated)
-
-    def test_plan_out_kwarg_shape_mismatch_ignored(self):
-        import jax.numpy as jnp
-
-        gfm = rs_matrix(2, 1)
-        plan = PackedPlan(gfm)
-        data = rand_data((2, 128), seed=6)
-        wrong = jnp.zeros((4, 4), jnp.uint8)
-        got = np.asarray(plan(data, out=wrong))
-        assert np.array_equal(got, xor_matmul_host(expand_matrix(gfm), data))
 
     def test_launch_counter_one_dispatch_per_batch(self):
         gfm = rs_matrix(4, 2)

@@ -194,64 +194,6 @@ class TestShardedCounters:
         assert sum(n * v for n, v in occ.items()) == dump["device_launches"]
 
 
-class TestFusionBacklog:
-    """Super-launch fusion perf contract (ISSUE 18 satellite): under a
-    4-submitter backlog the aggregator must fuse ring-full window trips
-    instead of queueing per-window launches — fused_launches >= 1 and
-    strictly fewer device launches than windows dispatched."""
-
-    def test_fusion_fires_under_four_submitter_backlog(self):
-        import threading
-
-        from ceph_tpu.codec.matrix_codec import EncodeAggregator
-
-        ec = make_rs()
-        rng = np.random.default_rng(17)
-        agg = EncodeAggregator(
-            window=4,
-            max_bytes=1 << 30,
-            inflight_max_bytes=1 << 30,
-            pipeline_depth=1,
-            fuse_max_windows=4,
-        )
-        threads, per_thread = 4, 8
-        l0 = agg.perf.get("launches")
-        f0 = agg.perf.get("fused_launches")
-        results, errs = [[] for _ in range(threads)], []
-
-        def worker(t):
-            try:
-                for i in range(per_thread):
-                    h = rng.integers(0, 256, (1, 4, 2048), dtype=np.uint8)
-                    results[t].append((h, agg.submit(ec, h)))
-            except Exception as e:  # surfaced below; a thread must not die silently
-                errs.append(e)
-
-        ths = [
-            threading.Thread(target=worker, args=(t,)) for t in range(threads)
-        ]
-        for th in ths:
-            th.start()
-        for th in ths:
-            th.join()
-        agg.flush()
-        assert not errs, errs
-        for bucket in results:
-            for h, ticket in bucket:
-                assert np.array_equal(
-                    np.asarray(ticket), ec.encode_array_host(h)
-                )
-        launches = agg.perf.get("launches") - l0
-        windows_dispatched = threads * per_thread // 4
-        assert agg.perf.get("fused_launches") - f0 >= 1, (
-            "a 4-submitter backlog never produced a fused launch"
-        )
-        assert launches < windows_dispatched, (
-            f"{launches} launches for {windows_dispatched} windows: "
-            "fusion is not reducing dispatch count under backlog"
-        )
-
-
 class TestRmwDeltaSmoke:
     """RMW delta-path perf contract (ISSUE 18 satellite): a cache-hit
     partial overwrite commits a delta flight record that moved zero

@@ -137,9 +137,9 @@ class TestLaunchFallback:
         direct = stripe_mod.encode(self.sinfo, self.ec, data)
         real = self.ec.encode_array
 
-        def wedge(arr, out=None):
+        def wedge(arr):
             time.sleep(0.5)  # well past the 50 ms deadline below
-            return real(arr, out=out)
+            return real(arr)
 
         device_guard().configure(timeout_ms=50)
         self.ec.encode_array = wedge

@@ -162,18 +162,6 @@ class TestShardedAggregatorPath:
         for d, out in zip(datas, outs):
             assert np.array_equal(out, _host_parity(ec, d))
 
-    def test_encode_aggregator_donation_pool_recycles_sharded_buffers(self):
-        """Two same-geometry flush cycles: the second consumes the pooled
-        sharded output buffer; bytes stay exact either way."""
-        ec = make_rs()
-        shard_dispatch.configure(min_batch=16)
-        agg = EncodeAggregator(window=4, max_bytes=1 << 30)
-        for round_ in range(2):
-            datas = [_batch(16, 8, 4096, seed=20 + 4 * round_ + i) for i in range(4)]
-            tickets = [agg.submit(ec, d) for d in datas]
-            for d, t in zip(datas, tickets):
-                assert np.array_equal(np.asarray(t), _host_parity(ec, d))
-
     def test_decode_aggregator_flush_shards(self):
         """Recovery-shaped decodes (one erasure signature, many objects)
         coalesce into one sharded DECODE launch, reconstructions exact."""
